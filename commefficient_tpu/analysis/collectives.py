@@ -18,8 +18,8 @@ Flagged:
     axis argument of a known collective — ``psum``/``pmean``/``pmax``/
     ``pmin``/``psum_scatter``/``all_gather``/``all_to_all``/
     ``ppermute``/``pshuffle``/``axis_index``/``pbroadcast``/``pcast``
-    (final-name match, so ``jax.lax.psum`` and the ``jax_compat``
-    shims both count); the axis argument is the first positional for
+    (final-name match, so ``jax.lax.psum`` and a bare imported
+    ``psum`` both count); the axis argument is the first positional for
     ``axis_index``, the second otherwise, or the ``axis_name=`` kwarg;
   * a string literal passed as an ``axis_name=`` keyword to ANY call —
     the kwarg name is distinctive enough that ``partial(ring_attention,

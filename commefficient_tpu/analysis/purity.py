@@ -14,9 +14,8 @@ constant-fold at worst.
 Mechanically: the analyzer builds a package-local call graph —
 
   * **roots**: functions decorated with / passed to ``jit`` / ``pjit`` /
-    ``shard_map`` / ``pallas_call`` (final-name match, so the
-    ``utils.jax_compat.shard_map`` shim and ``pl.pallas_call`` both
-    count), including ``functools.partial(...)``-wrapped and lambda
+    ``shard_map`` / ``pallas_call`` (final-name match, so
+    ``jax.shard_map`` and ``pl.pallas_call`` both count), including ``functools.partial(...)``-wrapped and lambda
     arguments;
   * **edges**: a function *referencing* another package function (call,
     argument, closure) links to it — reference, not just call, so
@@ -64,8 +63,8 @@ DESCRIPTION = (
     "reachable from jit/shard_map/pallas_call roots"
 )
 
-# final-name match: covers jax.jit, jax.experimental.pjit.pjit, the
-# utils.jax_compat shard_map shim, and pl.pallas_call alike
+# final-name match: covers jax.jit, jax.experimental.pjit.pjit,
+# jax.shard_map and pl.pallas_call alike
 TRACER_NAMES = frozenset({"jit", "pjit", "shard_map", "pallas_call"})
 
 # builtin collection/str/array method names excluded from the
@@ -239,8 +238,7 @@ class CallGraph:
                 elif isinstance(child, (ast.If, ast.Try, ast.With,
                                         ast.For, ast.While, ast.AsyncWith,
                                         ast.AsyncFor, ast.ExceptHandler)):
-                    # defs nested under control flow (jax_compat's
-                    # version-gated shard_map/pcast) register in the SAME
+                    # defs nested under control flow register in the SAME
                     # scope — recurse with unchanged context
                     visit(child, parent_func, in_class)
                 else:

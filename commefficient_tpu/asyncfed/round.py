@@ -56,7 +56,6 @@ from commefficient_tpu.parallel.round import (
     server_phase,
 )
 from commefficient_tpu.utils.config import Config
-from commefficient_tpu.utils.jax_compat import pcast, shard_map
 
 P = jax.sharding.PartitionSpec
 
@@ -97,7 +96,7 @@ def build_async_round_fns(
     f32 = jnp.float32
     lm = cfg.local_momentum
     use_fedsim = bool(cfg.fedsim_enabled)
-    grad_one = make_grad_one(cfg, loss_fn, unravel, mesh)
+    grad_one = make_grad_one(cfg, loss_fn, unravel)
     # multihost meshes: every collective and shard spec below rides the
     # (HOSTS, WORKERS) tuple, same resolution as the synchronous round
     axes = worker_axes(mesh)
@@ -113,7 +112,7 @@ def build_async_round_fns(
                      lr, *fs):
         # same vma discipline as the synchronous worker shard: varying
         # params keep AD shard-local so each client sees its own gradient
-        params_vec = pcast(params_vec, axes, to="varying")
+        params_vec = jax.lax.pcast(params_vec, axes, to="varying")
         return jax.vmap(
             lambda b, cid, vel, err, *fs_: per_client(
                 params_vec, b, cid, vel, err, rng, lr, *fs_
@@ -124,7 +123,7 @@ def build_async_round_fns(
     in_specs = (P(), shard_spec, shard_spec, shard_spec, shard_spec, P(), P())
     if use_fedsim:
         in_specs = in_specs + (shard_spec, shard_spec)  # live mask, corrupt
-    launch_mapped = shard_map(
+    launch_mapped = jax.shard_map(
         launch_shard,
         mesh=mesh,
         in_specs=in_specs,
@@ -189,7 +188,7 @@ def build_async_round_fns(
         local = comp.device_encode(local)
         return aggregate_tail(local, loss_local, aux, w_loc)
 
-    apply_mapped = shard_map(
+    apply_mapped = jax.shard_map(
         apply_shard,
         mesh=mesh,
         in_specs=(shard_spec, shard_spec, shard_spec, shard_spec),

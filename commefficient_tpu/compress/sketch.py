@@ -192,7 +192,7 @@ class SketchCompressor(Compressor):
             hh_gidx = jnp.minimum(my * S + loc_d, d - 1)
             m_at_hh = jnp.where(
                 upd_val != 0,
-                self._shard_estimate_at()(spec, m, hh_gidx), 0.0,
+                estimate_at(spec, m, hh_gidx), 0.0,
             )
             if self._ride_pair_exchange:
                 g_i, g_v = all_gather_pairs(hh_gidx, m_at_hh, axis_name,
@@ -239,10 +239,9 @@ class SketchCompressor(Compressor):
         so sel==upd there; no-error applies lr at extraction), ``upd`` the
         unscaled selection whose support drives momentum dampening."""
         cfg, spec = self.cfg, self.spec
-        est_at = self._shard_estimate_at()
         if cfg.error_type == "virtual":
             e = error + lr * m
-            est = est_at(spec, e, idx_c) * in_range
+            est = estimate_at(spec, e, idx_c) * in_range
             upd = topk_threshold_sharded(est, cfg.k, axis_name)
             # zero-HH feedback at k-scale: compact the <= k selected
             # entries before the slice sketch — scatter is the TPU slow
@@ -272,22 +271,9 @@ class SketchCompressor(Compressor):
             if cfg.error_decay != 1.0:
                 e = cfg.error_decay * e
             return upd, upd, e
-        est = est_at(spec, m, idx_c) * in_range
+        est = estimate_at(spec, m, idx_c) * in_range
         upd = topk_threshold_sharded(est, cfg.k, axis_name)
         return lr * upd, upd, error
-
-    def _shard_estimate_at(self):
-        """Point-estimate kernel for the sharded decode: the fused Pallas
-        realization when the spec dials ``backend='pallas'`` (in-kernel
-        hashes + gather + median, table VMEM-resident — see
-        ops/pallas/decode_kernels.py, which falls back to the plain
-        gather path itself when the table exceeds its VMEM guard), else
-        the backend-agnostic ``estimate_at`` gather path."""
-        if self.spec is not None and self.spec.backend == "pallas":
-            from commefficient_tpu.ops.pallas import estimate_at_pallas
-
-            return estimate_at_pallas
-        return estimate_at
 
     def fsdp_update(self, p_sh, m_in, e_in, local, lr, *, axis_name, W,
                     d, dp, S):
@@ -299,8 +285,7 @@ class SketchCompressor(Compressor):
         # offset-indexed global hashes; the shared ``_slice_coords`` /
         # ``_slice_extract`` helpers (also the replicated engine's
         # sharded decode) own the slice geometry + scalar-collective
-        # threshold + zero-HH error feedback, through the fused Pallas
-        # estimate kernel when backend='pallas'
+        # threshold + zero-HH error feedback
         _, idx_c, in_range = self._slice_coords(axis_name, S, d)
         m_in, e_in = self._up(m_in), self._up(e_in)
         m = rho * m_in + agg if rho > 0 else agg

@@ -9,14 +9,21 @@ call, so under the sampler's prefetch thread the host batch assembly
 overlaps the TPU round.
 
 The library is compiled on first use with the baked-in ``g++`` (no
-pip/pybind11 — plain ``-shared -fPIC``, see ENVIRONMENT constraints) and
-cached next to the source; every entry point has a pure-numpy fallback, so
-the framework runs unchanged where a toolchain is missing.
+pip/pybind11 — plain ``-shared -fPIC``, see ENVIRONMENT constraints) for
+the generic target CPU (no ``-march=native``: the checkout is copied
+between machines as it stands) and cached next to the source under a name
+keyed to the source's content, so an artefact built from other source is
+never loaded. Every entry point has a pure-numpy fallback, so the
+framework runs unchanged where a toolchain is missing — and
+``describe()`` says in-band which of the two a run used (the train
+entries print it).
 """
 
 from __future__ import annotations
 
 import ctypes
+import functools
+import hashlib
 import os
 import subprocess
 import threading
@@ -26,11 +33,19 @@ import numpy as np
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_DIR, "fedloader.cc")
-_LIB_PATH = os.path.join(_DIR, "libfedloader.so")
 
 _lock = threading.Lock()
 _lib = None
 _build_failed = False
+_why_numpy = ""  # set when the build or the dlopen failed
+
+
+@functools.cache
+def _lib_path() -> str:
+    """The artefact's path, keyed to the source it was built from."""
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:12]
+    return os.path.join(_DIR, f"libfedloader-{digest}.so")
 
 _F32P = ctypes.POINTER(ctypes.c_float)
 _I64P = ctypes.POINTER(ctypes.c_int64)
@@ -38,28 +53,30 @@ _I32P = ctypes.POINTER(ctypes.c_int32)
 _U8P = ctypes.POINTER(ctypes.c_uint8)
 
 
-def _compile() -> bool:
+def _compile(lib_path: str) -> str:
+    """Build ``lib_path``; returns "" on success, else why it failed."""
     # Build to a per-process temp path and os.replace() into place: a second
     # process (multi-host launch, parallel pytest) dlopening a partially
     # written .so would fail or crash; rename on the same filesystem is
     # atomic (ADVICE r2).
-    tmp = f"{_LIB_PATH}.{os.getpid()}.tmp"
+    tmp = f"{lib_path}.{os.getpid()}.tmp"
     flag_sets = [
-        ["-O3", "-march=native", "-fopenmp"],
         ["-O3", "-fopenmp"],
         ["-O3"],
     ]
+    why = "no flag set tried"
     try:
         for flags in flag_sets:
             cmd = ["g++", *flags, "-shared", "-fPIC", "-o", tmp, _SRC]
             try:
                 r = subprocess.run(cmd, capture_output=True, timeout=120)
-            except (FileNotFoundError, subprocess.TimeoutExpired):
-                return False
+            except (FileNotFoundError, subprocess.TimeoutExpired) as e:
+                return f"g++ did not run: {type(e).__name__}"
             if r.returncode == 0:
-                os.replace(tmp, _LIB_PATH)
-                return True
-        return False
+                os.replace(tmp, lib_path)
+                return ""
+            why = "g++ failed: " + r.stderr.decode(errors="replace")[-200:]
+        return why
     finally:
         if os.path.exists(tmp):
             os.remove(tmp)
@@ -99,7 +116,7 @@ def _bind(path: str):
 
 def load() -> Optional[ctypes.CDLL]:
     """The bound library, building it if needed; None when unavailable."""
-    global _lib, _build_failed
+    global _lib, _build_failed, _why_numpy
     if _lib is not None:
         return _lib
     if _build_failed:
@@ -107,15 +124,16 @@ def load() -> Optional[ctypes.CDLL]:
     with _lock:
         if _lib is not None or _build_failed:
             return _lib
-        stale = not os.path.exists(_LIB_PATH) or (
-            os.path.getmtime(_LIB_PATH) < os.path.getmtime(_SRC)
-        )
-        if stale and not _compile():
-            _build_failed = True
-            return None
+        lib_path = _lib_path()
+        if not os.path.exists(lib_path):
+            _why_numpy = _compile(lib_path)
+            if _why_numpy:
+                _build_failed = True
+                return None
         try:
-            _lib = _bind(_LIB_PATH)
-        except OSError:
+            _lib = _bind(lib_path)
+        except OSError as e:
+            _why_numpy = f"dlopen failed: {e}"
             _build_failed = True
             return None
         return _lib
@@ -123,6 +141,14 @@ def load() -> Optional[ctypes.CDLL]:
 
 def available() -> bool:
     return load() is not None
+
+
+def describe() -> str:
+    """Which host loader this process runs, for the run's own output:
+    the native library by file name, or numpy and why."""
+    if load() is not None:
+        return f"native ({os.path.basename(_lib_path())})"
+    return f"numpy ({_why_numpy})"
 
 
 def gather_augment(

@@ -11,13 +11,14 @@ pairs, rebuild the sum by scatter-add.  All functions run INSIDE
 
 Two exchange schedules:
 
-* ``sparse_allreduce`` — one ``all_gather`` of every shard's pair buffer,
-  then a local scatter-add.  The output is REPLICATED (axis-invariant),
-  which is what ``shard_map`` ``out_specs=P()`` demands: on
-  varying-manual-axes JAX only psum/all_gather outputs are invariant, so
-  round paths that keep a replicated server MUST consume this form (a
-  ``ppermute`` output is varying and cannot leave the shard_map as
-  ``P()``).  Per-chip receive volume: W·k pairs — the O(W·k) bound the
+* ``sparse_allreduce`` — one invariant gather of every shard's pair
+  buffer (``all_gather_invariant``), then a local scatter-add.  The
+  output is REPLICATED (axis-invariant), which is what ``shard_map``
+  ``out_specs=P()`` demands: under varying-manual-axes typing only a
+  reduction (psum/pmax) output is invariant — ``jax.lax.all_gather`` and
+  ``ppermute`` outputs are varying and cannot leave the shard_map as
+  ``P()`` — so round paths that keep a replicated server MUST consume
+  this form.  Per-chip receive volume: W·k pairs — the O(W·k) bound the
   XLA collective audit enforces.
 
 * ``sparse_allreduce_sharded`` — balanced index-range partitioning +
@@ -82,29 +83,48 @@ def compact_pairs(v: Array, capacity: int) -> Tuple[Array, Array]:
     return compact_nonzero(v, capacity)
 
 
+def all_gather_invariant(x: Array, axis_name) -> Array:
+    """[N, *x.shape] stack of every shard's ``x`` in axis order (N = axis
+    size), typed INVARIANT over ``axis_name`` — legal to return from
+    ``shard_map`` under ``out_specs=P()`` with ``check_vma`` on.
+
+    ``jax.lax.all_gather`` is Varying -> Varying, so its output cannot
+    prove replication.  A psum is Varying -> Invariant: each shard places
+    its block at its own row of a zeros [N, ...] buffer and the sum over
+    the axis reassembles the stack — every slot is one value plus zeros,
+    so the result carries the gathered values exactly (NaN included; only
+    the sign of a -0.0 is lost).  It lowers to ONE all-reduce of the
+    gathered size, the same N·size receive volume the audit bounds."""
+    n = jax.lax.axis_size(axis_name)
+    rows = jax.lax.broadcasted_iota(jnp.int32, (n,) + (1,) * x.ndim, 0)
+    mine = rows == jax.lax.axis_index(axis_name)
+    return jax.lax.psum(jnp.where(mine, x[None], jnp.zeros((), x.dtype)),
+                        axis_name)
+
+
 def all_gather_pairs(idx: Array, val: Array, axis_name: str,
                      segments=None) -> Tuple[Array, Array]:
     """Concatenate every shard's [kb] pair buffer into replicated
     [N·kb] buffers (N = axis size).  Invariant output — legal to return
-    from ``shard_map`` under ``out_specs=P()``.
+    from ``shard_map`` under ``out_specs=P()`` (``all_gather_invariant``).
 
     ``segments=S`` (layerwise overlap) splits the [kb] payload into up
-    to S contiguous chunks, each exchanged by its own ``all_gather``;
+    to S contiguous chunks, each exchanged by its own collective;
     concatenating the [N, kb_s] gathers along the pair axis rebuilds the
     exact monolithic [N, kb] layout, so the flattened output — and
     everything scatter-added from it — is BIT-equal to ``segments=None``
-    (pure data movement, no arithmetic).  ``None`` (default) traces the
-    single-gather program byte-identically to pre-overlap builds."""
+    (each slot is its one value whichever collective carries it).
+    ``None`` (default) traces the single-gather program."""
     if segments is None or int(segments) <= 1 or idx.shape[0] <= 1:
-        g_idx = jax.lax.all_gather(idx, axis_name).reshape(-1)
-        g_val = jax.lax.all_gather(val, axis_name).reshape(-1)
+        g_idx = all_gather_invariant(idx, axis_name).reshape(-1)
+        g_val = all_gather_invariant(val, axis_name).reshape(-1)
         return g_idx, g_val
     bounds = _segment_bounds(idx.shape[0], segments)
     g_idx = jnp.concatenate(
-        [jax.lax.all_gather(idx[a:b], axis_name) for a, b in bounds], axis=1
+        [all_gather_invariant(idx[a:b], axis_name) for a, b in bounds], axis=1
     ).reshape(-1)
     g_val = jnp.concatenate(
-        [jax.lax.all_gather(val[a:b], axis_name) for a, b in bounds], axis=1
+        [all_gather_invariant(val[a:b], axis_name) for a, b in bounds], axis=1
     ).reshape(-1)
     return g_idx, g_val
 

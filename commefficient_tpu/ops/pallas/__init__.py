@@ -7,14 +7,14 @@ light: importing the subpackage must not trigger any pallas_call tracing
 
 from commefficient_tpu.ops.pallas.countsketch_kernels import (
     estimate_all_pallas,
+    kernels_interpreted,
     median_rows_pallas,
     sketch_vec_pallas,
 )
-from commefficient_tpu.ops.pallas.decode_kernels import estimate_at_pallas
 
 __all__ = [
     "estimate_all_pallas",
-    "estimate_at_pallas",
+    "kernels_interpreted",
     "median_rows_pallas",
     "sketch_vec_pallas",
 ]

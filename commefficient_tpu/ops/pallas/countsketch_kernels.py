@@ -39,8 +39,18 @@ backends agree to fp32 rounding (not bit-exactly). Layout permutations
 transposes and keeping them shared guarantees the two backends use one
 geometry.
 
-On CPU (tier-1 tests) every kernel runs under Pallas interpret mode; on a
-TPU backend the same calls compile through Mosaic.
+Mosaic shapes the kernels: blocks are 3-D ``[tile, TC+u-1, s]`` (the last
+two dims equal the array's, so no (8, 128) divisibility is asked of the
+geometry-given stride ``s``); the ``[TC, V]`` window accumulator is kept as
+``u`` per-shift ``[TC, s]`` accumulators in VMEM scratch (a lane-dimension
+``reshape(TC, u, s)`` is not expressible when ``s`` is not a multiple of
+128), and the band overlap-add is ``u`` static sublane-offset adds; integer
+iotas are int32 and the sign bit goes through int32 on its way to f32
+(Mosaic has no uint32 iota and no uint32 -> float cast).
+
+On the ``cpu`` backend (tier-1 tests) every kernel runs under Pallas
+interpret mode; on ``tpu`` the same calls compile through Mosaic; any other
+backend is an error (``_interpret``).
 """
 
 from __future__ import annotations
@@ -50,6 +60,7 @@ import functools as _functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from commefficient_tpu.ops.countsketch import (
     _GOLDEN,
@@ -64,11 +75,41 @@ from commefficient_tpu.ops.countsketch import (
 )
 
 
-def _interpret() -> bool:
-    """Interpret Pallas kernels everywhere but a real TPU backend (the
-    tier-1 suite runs JAX_PLATFORMS=cpu; the kernels must stay testable
-    there). Evaluated at trace time — static per compilation."""
-    return jax.default_backend() != "tpu"
+def kernels_interpreted() -> bool:
+    """Where the kernels run: Mosaic-compiled on ``tpu`` (False),
+    interpreted on ``cpu`` (True — the tier-1 suite runs JAX_PLATFORMS=cpu
+    and the kernels must stay testable there); every other backend is
+    refused — nothing on an accelerator may run interpreted without saying
+    so. Evaluated at trace time — static per compilation."""
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"the Pallas sketch kernels compile for 'tpu' and interpret on "
+        f"'cpu'; backend {backend!r} has neither — use "
+        "sketch_backend='einsum' there"
+    )
+
+
+def _interpret(like):
+    """The ``interpret=`` argument of a ``pallas_call`` whose input is
+    ``like``. Which interpreter, on cpu, follows that input: inside the
+    round's shard_map (varying input) only the TPU interpreter
+    (``pltpu.InterpretParams``) types its grid-loop buffers as varying;
+    under a plain multi-device jit only the generic one (True) partitions
+    — the TPU interpreter's host callbacks cannot be replicated."""
+    if not kernels_interpreted():
+        return False
+    return pltpu.InterpretParams() if jax.typeof(like).vma else True
+
+
+def _out_struct(shape, like) -> jax.ShapeDtypeStruct:
+    """f32 ``out_shape`` typed varying over the manual mesh axes ``like``
+    varies over — inside the round's shard_map ``pallas_call`` refuses an
+    ``out_shape`` whose ``vma`` is unset."""
+    return jax.ShapeDtypeStruct(shape, jnp.float32, vma=jax.typeof(like).vma)
 
 
 # ---------------------------------------------------------------------------
@@ -80,21 +121,21 @@ def _interpret() -> bool:
 def _row_geom(spec, row: int):
     """Static tile plan for one row. Returns a dict of python ints.
 
-    MT: offset-tile width (lane-dim of the generated one-hot — MT*V*4 B of
-    VMEM). TC: chunk-tile height, sized so the [TC, m_pad] input block
-    stays ~2 MB, floored at the band width u so the body/tail
+    MT: offset-tile width (the generated per-shift one-hot is [MT, s]).
+    TC: chunk-tile height (a multiple of 8), sized so the [TC, m_pad] input
+    block stays ~2 MB, floored at the band width u so the body/tail
     recombination below stays a single shifted add."""
     m = spec.chunk_m
     u, s = spec.u_row(row), spec.s_row(row)
     MT = min(256, _ceil_mult(m, 8))
     m_pad = _ceil_mult(m, MT)
     TC = max(8, min(64, (2 << 20) // (m_pad * 4) // 8 * 8))
-    TC = max(TC, u)
+    TC = _ceil_mult(max(TC, u), 8)
     nc = spec._nc_row(row)
     nc_pad = _ceil_mult(nc, TC)
     return dict(
         m=m, m_pad=m_pad, MT=MT, TC=TC, nc=nc, nc_pad=nc_pad,
-        nt=nc_pad // TC, u=u, s=s, V=u * s, TB=(TC + u - 1) * s,
+        nt=nc_pad // TC, u=u, s=s, V=u * s,
         f=spec._factor(row), L=spec._L_row(row),
     )
 
@@ -130,7 +171,9 @@ def _row_hashes(spec, row: int):
             spos = (pos % jnp.uint32(f)) * jnp.uint32(G) + pos // jnp.uint32(f)
         else:
             spos = pos
-        return 1.0 - 2.0 * sign_bits(spos).astype(jnp.float32)
+        # via int32: Mosaic has no uint32 -> float32 cast
+        bit = sign_bits(spos).astype(jnp.int32).astype(jnp.float32)
+        return 1.0 - 2.0 * bit
 
     return slot_fn, sign_fn
 
@@ -151,13 +194,20 @@ def _check_poly4_field(spec) -> None:
         )
 
 
+def _offsets(shape, dim, MT, j):
+    """uint32 within-chunk offsets j*MT..j*MT+MT laid along ``dim`` (int32
+    iota: Mosaic has no unsigned one)."""
+    return (jax.lax.broadcasted_iota(jnp.int32, shape, dim) + MT * j).astype(
+        jnp.uint32
+    )
+
+
 def _sign_tile(sign_fn, base, m, TC, MT, j):
     """[TC, MT] signs for chunk rows base..base+TC, offset cols j*MT..+MT."""
-    q = jax.lax.broadcasted_iota(jnp.uint32, (TC, MT), 0) + jnp.uint32(base)
-    o = jax.lax.broadcasted_iota(jnp.uint32, (TC, MT), 1) + (
-        jnp.uint32(MT) * j.astype(jnp.uint32)
+    q = (jax.lax.broadcasted_iota(jnp.int32, (TC, MT), 0) + base).astype(
+        jnp.uint32
     )
-    return sign_fn(q * jnp.uint32(m) + o)
+    return sign_fn(q * jnp.uint32(m) + _offsets((TC, MT), 1, MT, j))
 
 
 # ---------------------------------------------------------------------------
@@ -170,71 +220,62 @@ def _sketch_row(spec, v_s: jnp.ndarray, row: int) -> jnp.ndarray:
     hash + sign + one-hot contraction + fused overlap-add."""
     g = _row_geom(spec, row)
     TC, MT, m, m_pad = g["TC"], g["MT"], g["m"], g["m_pad"]
-    u, s, V, TB, nt = g["u"], g["s"], g["V"], g["TB"], g["nt"]
+    u, s, nt = g["u"], g["s"], g["nt"]
     slot_fn, sign_fn = _row_hashes(spec, row)
     nj = m_pad // MT
 
     sv = _to_layout(spec, v_s, row)  # [nc, m], unsigned (signs in-kernel)
     sv = jnp.pad(sv, ((0, g["nc_pad"] - g["nc"]), (0, m_pad - m)))
 
-    def kernel(sv_ref, out_ref):
+    def kernel(sv_ref, out_ref, acc_ref):
         base = pl.program_id(0) * TC
-        col_ids = jax.lax.broadcasted_iota(jnp.int32, (MT, V), 1)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        col_ids = jax.lax.broadcasted_iota(jnp.int32, (MT, s), 1)
 
-        def body(j, acc):
-            o = jax.lax.broadcasted_iota(jnp.uint32, (MT, 1), 0) + (
-                jnp.uint32(MT) * j.astype(jnp.uint32)
-            )
-            onehot = (slot_fn(o) == col_ids).astype(spec.dtype)
-            vals = sv_ref[:, pl.ds(j * MT, MT)]
+        def body(j, carry):
+            slot = slot_fn(_offsets((MT, 1), 0, MT, j))  # [MT, 1] in [0, V)
+            vals = sv_ref[:, pl.ds(pl.multiple_of(j * MT, MT), MT)]
             signed = (vals * _sign_tile(sign_fn, base, m, TC, MT, j)).astype(
                 spec.dtype
             )
-            return acc + jax.lax.dot_general(
-                signed,
-                onehot,
-                (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
+            # window slice sh holds buckets [sh*s, (sh+1)*s) of the [MT, V]
+            # one-hot: u [MT, s] compares cost what one [MT, V] would
+            for sh in range(u):
+                onehot = (slot - sh * s == col_ids).astype(spec.dtype)
+                acc_ref[sh] += jax.lax.dot_general(
+                    signed,
+                    onehot,
+                    (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                )
+            return carry
 
-        acc = jax.lax.fori_loop(0, nj, body, jnp.zeros((TC, V), jnp.float32))
-        # fused band overlap-add: [TC, u, s] windows -> [(TC+u-1), s], each
-        # shift realized as a tiny static one-hot matmul (iota-generated —
-        # no pad/concat primitives inside the kernel)
-        if u == 1:
-            out_ref[0, :] = acc.reshape(TB)
-            return
-        a3 = acc.reshape(TC, u, s)
-        rows_out = jax.lax.broadcasted_iota(jnp.int32, (TC + u - 1, TC), 0)
-        rows_in = jax.lax.broadcasted_iota(jnp.int32, (TC + u - 1, TC), 1)
-        out2d = jnp.zeros((TC + u - 1, s), jnp.float32)
+        jax.lax.fori_loop(0, nj, body, 0)
+        # fused band overlap-add: window slice sh of chunk row q lands on
+        # output row q + sh — u static sublane-offset adds
+        out_ref[0] = jnp.zeros((TC + u - 1, s), jnp.float32)
         for sh in range(u):
-            shift = (rows_out == rows_in + sh).astype(jnp.float32)
-            out2d = out2d + jax.lax.dot_general(
-                shift,
-                a3[:, sh, :],
-                (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-        out_ref[0, :] = out2d.reshape(TB)
+            out_ref[0, pl.ds(sh, TC), :] += acc_ref[sh]
 
     tiles = pl.pallas_call(
         kernel,
         grid=(nt,),
         in_specs=[pl.BlockSpec((TC, m_pad), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((1, TB), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((nt, TB), jnp.float32),
-        interpret=_interpret(),
+        out_specs=pl.BlockSpec((1, TC + u - 1, s), lambda i: (i, 0, 0)),
+        out_shape=_out_struct((nt, TC + u - 1, s), sv),
+        scratch_shapes=[pltpu.VMEM((u, TC, s), jnp.float32)],
+        interpret=_interpret(sv),
     )(sv)
 
-    # recombine: tile i covers row positions [i*TC*s, i*TC*s + TB); only the
-    # (u-1)*s tail overlaps the next tile's body (TC >= u by construction),
-    # so the whole stitch is ONE shifted add + concat.
-    bodies = tiles[:, : TC * s]
+    # recombine: tile i covers table rows [i*TC, i*TC + TC+u-1) of the
+    # [*, s] row view; only the u-1 tail rows overlap the next tile's body
+    # (TC >= u by construction), so the whole stitch is ONE shifted add +
+    # concat.
+    bodies = tiles[:, :TC]
     if u > 1:
-        tails = tiles[:, TC * s:]
-        bodies = bodies.at[1:, : (u - 1) * s].add(tails[:-1])
-        flat = jnp.concatenate([bodies.reshape(-1), tails[-1]])
+        tails = tiles[:, TC:]
+        bodies = bodies.at[1:, : u - 1].add(tails[:-1])
+        flat = jnp.concatenate([bodies.reshape(-1), tails[-1].reshape(-1)])
     else:
         flat = bodies.reshape(-1)
     n = min(flat.shape[0], spec.c_actual)
@@ -261,55 +302,53 @@ def _estimate_row(spec, table_row: jnp.ndarray, row: int) -> jnp.ndarray:
     """Per-coordinate estimates of one row in chunk layout [nc, m]."""
     g = _row_geom(spec, row)
     TC, MT, m, m_pad = g["TC"], g["MT"], g["m"], g["m_pad"]
-    u, s, TB, nt = g["u"], g["s"], g["TB"], g["nt"]
+    u, s, nt = g["u"], g["s"], g["nt"]
     slot_fn, sign_fn = _row_hashes(spec, row)
     nj = m_pad // MT
 
-    # windows stack: tile i reads row positions [i*TC*s, i*TC*s + TB) — the
-    # only overlapping-window view; one small gather outside the kernel
-    # keeps every BlockSpec plainly blocked.
+    # windows stack: tile i reads rows [i*TC, i*TC + TC+u-1) of the [*, s]
+    # row view — the only overlapping-window view; one small gather outside
+    # the kernel keeps every BlockSpec plainly blocked.
     table_row = table_row.astype(jnp.float32)  # bf16-stored tables read f32
-    row_len = (g["nc_pad"] + u - 1) * s
+    n_rows = g["nc_pad"] + u - 1
+    row_len = n_rows * s
     row_p = jnp.pad(table_row[: min(table_row.shape[0], row_len)],
                     (0, max(0, row_len - table_row.shape[0])))
+    rows2d = row_p.reshape(n_rows, s)
     win = jax.vmap(
-        lambda i: jax.lax.dynamic_slice(row_p, (i * TC * s,), (TB,))
+        lambda i: jax.lax.dynamic_slice(rows2d, (i * TC, 0), (TC + u - 1, s))
     )(jnp.arange(nt))
 
     def kernel(in_ref, out_ref):
         base = pl.program_id(0) * TC
-        blk = in_ref[0, :].reshape(TC + u - 1, s)
+        v_ids = jax.lax.broadcasted_iota(jnp.int32, (s, MT), 0)
 
-        def body(j, _):
-            o = jax.lax.broadcasted_iota(jnp.uint32, (1, MT), 1) + (
-                jnp.uint32(MT) * j.astype(jnp.uint32)
-            )
-            h = slot_fn(o)  # [1, MT] in-window buckets
+        def body(j, carry):
+            h = slot_fn(_offsets((1, MT), 1, MT, j))  # [1, MT] window buckets
             est = jnp.zeros((TC, MT), jnp.float32)
             for sh in range(u):
                 # transposed one-hot for window slice sh: [s, MT]
-                v_ids = jax.lax.broadcasted_iota(jnp.int32, (s, MT), 0) + sh * s
-                ohT = (v_ids == h).astype(spec.dtype)
+                ohT = (v_ids + sh * s == h).astype(spec.dtype)
                 est = est + jax.lax.dot_general(
-                    blk[sh : sh + TC, :].astype(spec.dtype),
+                    in_ref[0, pl.ds(sh, TC), :].astype(spec.dtype),
                     ohT,
                     (((1,), (0,)), ((), ())),
                     preferred_element_type=jnp.float32,
                 )
-            out_ref[:, pl.ds(j * MT, MT)] = est * _sign_tile(
-                sign_fn, base, m, TC, MT, j
+            out_ref[:, pl.ds(pl.multiple_of(j * MT, MT), MT)] = (
+                est * _sign_tile(sign_fn, base, m, TC, MT, j)
             )
-            return 0
+            return carry
 
         jax.lax.fori_loop(0, nj, body, 0)
 
     est = pl.pallas_call(
         kernel,
         grid=(nt,),
-        in_specs=[pl.BlockSpec((1, TB), lambda i: (i, 0))],
+        in_specs=[pl.BlockSpec((1, TC + u - 1, s), lambda i: (i, 0, 0))],
         out_specs=pl.BlockSpec((TC, m_pad), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((g["nc_pad"], m_pad), jnp.float32),
-        interpret=_interpret(),
+        out_shape=_out_struct((g["nc_pad"], m_pad), win),
+        interpret=_interpret(win),
     )(win)
     return est[: g["nc"], :m]
 
@@ -343,8 +382,8 @@ def median_rows_pallas(ests: jnp.ndarray) -> jnp.ndarray:
         grid=(n_pad // TD,),
         in_specs=[pl.BlockSpec((r, TD), lambda i: (0, i))],
         out_specs=pl.BlockSpec((1, TD), lambda i: (0, i)),
-        out_shape=jax.ShapeDtypeStruct((1, n_pad), jnp.float32),
-        interpret=_interpret(),
+        out_shape=_out_struct((1, n_pad), x),
+        interpret=_interpret(x),
     )(x)
     return med[0, :n]
 

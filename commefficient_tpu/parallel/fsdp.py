@@ -87,7 +87,6 @@ from commefficient_tpu.parallel.round import (
 )
 from commefficient_tpu.telemetry import nonfinite_sentinel, table_sqnorm_estimate
 from commefficient_tpu.utils.config import Config
-from commefficient_tpu.utils.jax_compat import shard_map
 
 P = jax.sharding.PartitionSpec
 
@@ -236,7 +235,7 @@ def build_fsdp_round_fn(
     f32 = jnp.float32
     m_kind, e_kind = _state_kinds(comp)
     has_m, has_e = m_kind is not None, e_kind is not None
-    grad_one = make_grad_one(cfg, loss_fn, unravel, mesh)
+    grad_one = make_grad_one(cfg, loss_fn, unravel)
     # fedsim masking is per-client, so it forces the vmap path (round.py)
     use_fedsim = bool(cfg.fedsim_enabled)
     fused = (
@@ -349,7 +348,7 @@ def build_fsdp_round_fn(
     in_specs = (shard, m_spec, e_spec, shard, shard, P(), P())
     if use_fedsim:
         in_specs = in_specs + (shard, shard, P())  # live, corrupt, count
-    mapped = shard_map(
+    mapped = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=in_specs,

@@ -26,7 +26,6 @@ import jax
 import jax.numpy as jnp
 
 from commefficient_tpu.parallel.mesh import SEQ
-from commefficient_tpu.utils.jax_compat import shard_map
 
 _NEG_INF = -1e30
 
@@ -107,7 +106,7 @@ def ring_attention_sharded(mesh, q, k, v, *, causal: bool = True):
     """
     P = jax.sharding.PartitionSpec
     spec = P(None, None, SEQ, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         partial(ring_attention, axis_name=SEQ, causal=causal),
         mesh=mesh,
         in_specs=(spec, spec, spec),

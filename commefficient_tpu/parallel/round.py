@@ -88,11 +88,6 @@ from commefficient_tpu.telemetry import (
     round_diagnostics_sparse,
 )
 from commefficient_tpu.utils.config import Config
-from commefficient_tpu.utils.jax_compat import (
-    grad_extra_axes_psum,
-    pcast,
-    shard_map,
-)
 
 P = jax.sharding.PartitionSpec
 
@@ -178,19 +173,16 @@ def init_state(cfg: Config, params_vec: jnp.ndarray, spec: Optional[CountSketch]
     )
 
 
-def make_grad_one(cfg: Config, loss_fn: Callable, unravel: Callable, mesh=None):
+def make_grad_one(cfg: Config, loss_fn: Callable, unravel: Callable):
     """Per-client gradient closure (the fed_worker forward_grad analog):
     ``(params_vec, batch, noise_rng) -> (flat grad [D], loss, aux)`` with
     weight decay, global-norm clip, and worker-side DP noise applied.
     Shared by the replicated round (build_round_fn) and the FSDP round
-    (parallel/fsdp.py) so the gradient semantics can never drift.
-
-    ``mesh``: pass the round's mesh when the loss may shard its compute
-    over model/seq axes (tensor.build_tp_flat_loss) — on pre-vma JAX the
-    raw gradient is then explicitly psummed over those axes (see
-    utils.jax_compat.grad_extra_axes_psum; no-op on current JAX)."""
+    (parallel/fsdp.py) so the gradient semantics can never drift. When the
+    loss shards its compute over model/seq axes
+    (tensor.build_tp_flat_loss), the vma transpose totals the gradient
+    over those axes by itself."""
     f32 = jnp.float32
-    data_axes = worker_axes(mesh) if mesh is not None else WORKERS
 
     def grad_one(params_vec, batch, noise_rng):
         params = unravel(params_vec)
@@ -202,7 +194,6 @@ def make_grad_one(cfg: Config, loss_fn: Callable, unravel: Callable, mesh=None):
         with jax.named_scope("flat_grad_concat"):
             g, _ = ravel_pytree(grads)
         g = g.astype(f32)
-        g = grad_extra_axes_psum(g, mesh, data_axes)
         if cfg.weight_decay:
             g = g + cfg.weight_decay * params_vec
         g = clip_by_global_norm(g, cfg.max_grad_norm)
@@ -241,8 +232,17 @@ def leaf_groups(sizes, segments):
     return bounds
 
 
+def _varying_like(x, ref):
+    """``x`` marked varying over the manual mesh axes ``ref`` varies over
+    (identity outside shard_map). A custom_vjp cotangent must carry its
+    primal's varying type: the fused backward's dummy zeros table receives
+    sketches of shard-local cotangents, so it is typed like the params."""
+    axes = tuple(sorted(jax.typeof(ref).vma))
+    return jax.lax.pcast(x, axes, to="varying") if axes else x
+
+
 def make_sketch_grad_one(cfg: Config, loss_fn: Callable, unravel: Callable,
-                         mesh, spec: CountSketch, *, d: int,
+                         spec: CountSketch, *, d: int,
                          overlap_segments: Optional[int] = None):
     """Sketch-FUSED twin of ``make_grad_one`` for the fused flattened-batch
     path: ``(params_vec, batch, noise_rng) -> (grad TABLE [r, c_actual]
@@ -294,7 +294,6 @@ def make_sketch_grad_one(cfg: Config, loss_fn: Callable, unravel: Callable,
     groups = (
         leaf_groups(sizes, overlap_segments) if overlap_segments else None
     )
-    data_axes = worker_axes(mesh) if mesh is not None else WORKERS
 
     def grad_one_table(params_vec, batch, noise_rng):
         del noise_rng  # DP noise is a [D]-vector draw — gated off this path
@@ -308,12 +307,9 @@ def make_sketch_grad_one(cfg: Config, loss_fn: Callable, unravel: Callable,
             ]
             return loss_fn(jax.tree.unflatten(treedef, tapped_leaves), batch)
 
-        zeros = jnp.zeros(spec.table_shape, jnp.float32)
+        zeros = _varying_like(jnp.zeros(spec.table_shape, jnp.float32),
+                              params_vec)
         (loss, aux), table = jax.value_and_grad(tapped, has_aux=True)(zeros)
-        # TP/SP meshes on pre-vma JAX: the explicit total over the extra
-        # axes commutes with the (linear) sketch, so totaling the TABLE
-        # is totaling the gradient (no-op on vma JAX / workers-only mesh)
-        table = grad_extra_axes_psum(table, mesh, data_axes)
         if cfg.weight_decay:
             # sketch(g + wd*p) = sketch(g) + wd * sketch(p); the [D]
             # params vector already exists as state, so its sketch takes
@@ -344,12 +340,11 @@ def make_sketch_grad_one(cfg: Config, loss_fn: Callable, unravel: Callable,
             return loss_fn(jax.tree.unflatten(treedef, tapped_leaves), batch)
 
         zeros = tuple(
-            jnp.zeros(spec.table_shape, jnp.float32) for _ in groups
+            _varying_like(jnp.zeros(spec.table_shape, jnp.float32),
+                          params_vec)
+            for _ in groups
         )
         (loss, aux), tables = jax.value_and_grad(tapped, has_aux=True)(zeros)
-        tables = tuple(
-            grad_extra_axes_psum(t, mesh, data_axes) for t in tables
-        )
         if cfg.weight_decay:
             # wd rides the FIRST group's table (the one whose cotangent
             # completes last, so no overlap window shrinks): the group
@@ -591,7 +586,7 @@ def make_decode_mapped(cfg: Config, comp, mesh, plan: AggregationPlan, *,
     e_spec = (
         P(axes) if plan.sparse_state and e_kind == KIND_DENSE else P()
     )
-    return shard_map(
+    return jax.shard_map(
         decode_shard,
         mesh=mesh,
         in_specs=(st_spec, e_spec, P(), st_spec, P(), P()),
@@ -774,7 +769,7 @@ def build_round_fn(
     f32 = jnp.float32
 
     # ---- per-client gradient (the fed_worker forward_grad analog) --------
-    grad_one = make_grad_one(cfg, loss_fn, unravel, mesh)
+    grad_one = make_grad_one(cfg, loss_fn, unravel)
 
     lm = cfg.local_momentum
 
@@ -819,7 +814,7 @@ def build_round_fn(
     overlap_layerwise = cfg.overlap_collectives == "layerwise"
     grad_table_one = (
         make_sketch_grad_one(
-            cfg, loss_fn, unravel, mesh, spec, d=d,
+            cfg, loss_fn, unravel, spec, d=d,
             overlap_segments=OVERLAP_SEGMENTS if overlap_layerwise else None,
         )
         if sketch_fused
@@ -866,7 +861,7 @@ def build_round_fn(
         # varying keeps AD shard-local, so per-client momentum/error/
         # compression below see each client's own gradient; aggregation then
         # happens exactly once, at the explicit psum.
-        params_vec = pcast(params_vec, axes, to="varying")
+        params_vec = jax.lax.pcast(params_vec, axes, to="varying")
 
         w_loc = client_ids.shape[0]
         if fused and sketch_fused:
@@ -924,7 +919,7 @@ def build_round_fn(
     in_specs = (P(), shard_spec, shard_spec, shard_spec, shard_spec, P(), P())
     if use_fedsim:
         in_specs = in_specs + (shard_spec, shard_spec)  # live mask, corrupt
-    worker_mapped = shard_map(
+    worker_mapped = jax.shard_map(
         worker_shard,
         mesh=mesh,
         in_specs=in_specs,

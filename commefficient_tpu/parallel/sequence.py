@@ -32,7 +32,6 @@ import jax.numpy as jnp
 from commefficient_tpu.models.gpt2 import GPT2Backbone
 from commefficient_tpu.parallel.mesh import SEQ
 from commefficient_tpu.parallel.ring_attention import ring_attention
-from commefficient_tpu.utils.jax_compat import shard_map
 
 P = jax.sharding.PartitionSpec
 
@@ -65,7 +64,7 @@ def sp_gpt2_apply(mesh, model, params, input_ids, token_type_ids=None,
     if shape[-1] % seq_size != 0:
         raise ValueError(f"T={shape[-1]} must divide by seq axis {seq_size}")
     tspec = P(None, SEQ)
-    h = shard_map(
+    h = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P(), tspec, tspec if tt is not None else None),

@@ -54,10 +54,6 @@ from commefficient_tpu.models.losses import (
     softmax_cross_entropy_sum,
 )
 from commefficient_tpu.parallel.mesh import MODEL, SEQ, WORKERS
-from commefficient_tpu.utils.jax_compat import (
-    grads_unreplicated_pmean,
-    shard_map,
-)
 from commefficient_tpu.parallel.ring_attention import ring_attention
 
 P = jax.sharding.PartitionSpec
@@ -262,7 +258,7 @@ def tp_gpt2_apply(mesh, model, tp_params, input_ids, token_type_ids=None,
         _, lm, mc_logits = _forward_local(tp, ids, tt, mc, cfg, seq_size)
         return lm, (jnp.zeros((1,), jnp.float32) if mc_logits is None else mc_logits)
 
-    lm, mc_out = shard_map(
+    lm, mc_out = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(specs, tspec, tspec if tt is not None else None,
@@ -449,7 +445,7 @@ def build_tp_eval_fn(cfg: GPT2Config, mesh, unravel, lm_coef: float = 1.0,
             # hold the full-batch sums on every shard (no collective).
             return jax.lax.psum(sums, WORKERS) if shard_rows else sums
 
-        sums = shard_map(
+        sums = jax.shard_map(
             body, mesh=mesh, in_specs=(P(), bspec), out_specs=P()
         )(params, batch)
         lm_sum, tok, mc_sum, cnt, correct = sums
@@ -542,10 +538,9 @@ def build_tp3d_train_step(mesh, model, lm_coef: float = 1.0,
 
     def local_step(tp, batch, lr):
         (loss, aux), grads = jax.value_and_grad(local_loss, has_aux=True)(tp, batch)
-        # the update happens HERE, inside the shard_map, so each param's
-        # grad must first be totaled over every axis it is replicated on
-        # (pre-vma JAX only; the vma transpose does this automatically)
-        grads = grads_unreplicated_pmean(grads, tp_param_specs(tp), mesh)
+        # the update happens HERE, inside the shard_map: the vma transpose
+        # already totaled each param's grad over every axis it is
+        # replicated on
         new_tp = jax.tree.map(lambda p, g: p - lr * g.astype(p.dtype), tp, grads)
         return new_tp, {"loss": loss, **aux}
 
@@ -564,7 +559,7 @@ def build_tp3d_train_step(mesh, model, lm_coef: float = 1.0,
             "mc_token_ids": P(WORKERS),
             "mc_labels": P(WORKERS),
         }
-        return shard_map(
+        return jax.shard_map(
             local_step,
             mesh=mesh,
             in_specs=(specs, bspec, P()),

@@ -32,24 +32,22 @@ def run_metadata(cfg=None, extra: Optional[dict] = None) -> dict:
     header, flight records, and the comm ledger: config snapshot, jax +
     device identity, wall-clock start. ``cfg`` is duck-typed (a
     ``utils.config.Config`` dataclass normally; any mapping-convertible
-    object otherwise)."""
+    object otherwise). The device identity is not optional: every record
+    of a run names the platform, kind and count it ran on, and a backend
+    that cannot say fails the run here rather than leaving an anonymous
+    header behind."""
+    import jax
+
+    devs = jax.devices()
     meta: dict = {
         "time": time.time(),
         "start_time": time.strftime("%Y-%m-%dT%H:%M:%S%z"),
+        "jax_version": jax.__version__,
+        "platform": devs[0].platform,
+        "device_kind": devs[0].device_kind,
+        "device_count": len(devs),
+        "backend": jax.default_backend(),
     }
-    try:
-        import jax
-
-        devs = jax.devices()
-        meta["jax_version"] = jax.__version__
-        meta["device_kind"] = devs[0].device_kind
-        meta["device_count"] = len(devs)
-        meta["backend"] = jax.default_backend()
-    # a missing/broken jax backend leaves the identity fields absent
-    # rather than killing the run this metadata merely describes
-    # lint: allow[exception-hygiene] metadata is best-effort
-    except Exception:
-        pass
     if cfg is not None:
         if dataclasses.is_dataclass(cfg):
             meta["config"] = dataclasses.asdict(cfg)

@@ -56,9 +56,8 @@ from typing import Any, Dict, Optional
 
 # Peak dense-matmul throughput (bf16 FLOP/s) of the chips we bench on —
 # the MFU denominator (moved from bench.py r3 so every consumer shares it).
-# A chip we don't recognize falls back to v5e's figure, flagged `assumed`.
+# A chip that is not in the table is an error, not a default.
 PEAK_FLOPS = {"TPU v5 lite": 197e12, "TPU v5": 459e12, "TPU v4": 275e12}
-_FALLBACK_PEAK = 197e12
 
 # scalar-collective slop for the ledger-vs-HLO cross-check: loss/aux/diag
 # psums and the sharded threshold's bisection collectives are all scalars,
@@ -68,17 +67,21 @@ SCALAR_COLLECTIVE_SLOP_BYTES = 4096
 
 
 def chip_peak_flops() -> tuple:
-    """(peak bf16 FLOP/s, device_kind, fallback_used). ADVICE r4: an
-    unrecognized chip must not silently get v5e's peak — the kind and any
-    fallback are reported in-band."""
+    """(peak bf16 FLOP/s, device_kind) of the first device. Raises
+    ValueError for a ``device_kind`` outside ``PEAK_FLOPS``: a utilization
+    computed against another chip's peak is a wrong number, not a
+    degraded one."""
     import jax
 
     kind = jax.devices()[0].device_kind
     # longest key first: "TPU v5" must not shadow "TPU v5 lite" (v5e)
     for name in sorted(PEAK_FLOPS, key=len, reverse=True):
         if name in kind:
-            return PEAK_FLOPS[name], kind, False
-    return _FALLBACK_PEAK, kind, True
+            return PEAK_FLOPS[name], kind
+    raise ValueError(
+        f"no peak-FLOP/s entry for device_kind {kind!r} (known: "
+        f"{sorted(PEAK_FLOPS)}); add the chip to PEAK_FLOPS with its source"
+    )
 
 
 def audited_mfu(flops_per_round: float, sec_per_round: float,
@@ -134,12 +137,16 @@ def collective_audit(hlo_text: str) -> Dict[str, Any]:
     "max_all_gather_elems", "max_all_reduce_elems"}`` — bytes are the
     per-chip RESULT bytes of each collective (variadic/tuple-shaped
     all-reduces sum their components), counted once per static HLO
-    occurrence; ``max_all_gather_elems`` is the largest single all-gather
-    result (None when the program has none) — the quantity the PR-6
-    ``<= W*k`` discipline bounds — and ``max_all_reduce_elems`` its
-    all-reduce twin, which the sparse-aggregate discipline bounds (a
+    occurrence; ``max_all_gather_elems`` is the largest single BUFFER any
+    all-gather returns (None when the program has none) — the quantity
+    the PR-6 ``<= W*k`` discipline bounds — and ``max_all_reduce_elems``
+    its all-reduce twin, which the sparse-aggregate discipline bounds (a
     reduce-scatter of [D] is ALLOWED there: it moves O(D/W) per link and
-    lands sharded, unlike an all-reduce's replicated [D] result).
+    lands sharded, unlike an all-reduce's replicated [D] result). The
+    maxima are per tuple component: XLA's combiner packs independent
+    small reductions (the idx and val pair exchanges, the loss scalars)
+    into one variadic op, and the discipline is about any one replicated
+    buffer being d-sized, not about how many ride one launch.
     """
     ops: Dict[str, Dict[str, int]] = {
         op: {"count": 0, "bytes": 0} for op in COLLECTIVE_OPS
@@ -170,7 +177,7 @@ def collective_audit(hlo_text: str) -> Dict[str, Any]:
             # call of their own, so the pair is counted once here
             shapes = (shapes[1:2] if op == "collective-permute"
                       else shapes[1:])
-        line_elems = sum(n for n, _ in shapes)
+        line_elems = max((n for n, _ in shapes), default=0)
         line_bytes = sum(b for _, b in shapes)
         ops[op]["count"] += 1
         ops[op]["bytes"] += line_bytes
@@ -396,17 +403,17 @@ class CompiledRoundAudit:
         from commefficient_tpu.telemetry import SCHEMA_VERSION, jsonable_tree
         from commefficient_tpu.telemetry.ledger import run_metadata
 
-        peak, kind, assumed = (None, None, None)
+        import jax
+
+        # the device is always named; a chip outside the peak table (the
+        # CPU test mesh) gets NO roofline floor rather than another chip's
+        kind = jax.devices()[0].device_kind
         try:
-            peak, kind, assumed = chip_peak_flops()
-        # degraded blocks carry nulls + unavailable_reason downstream;
-        # an exotic backend must not fail the run being audited
-        # lint: allow[exception-hygiene] roofline metadata is best-effort
-        except Exception:
-            pass
+            peak, _ = chip_peak_flops()
+        except ValueError:
+            peak = None
         predicted: Dict[str, Any] = {
             "peak_flops": peak, "device_kind": kind,
-            "peak_flops_assumed": assumed,
             # compute-bound roofline floor: the round can never beat its
             # audited FLOPs over the chip peak (bandwidth may bound it
             # higher — bytes_accessed / HBM BW — but peak BW varies per

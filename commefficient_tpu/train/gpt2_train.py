@@ -270,9 +270,12 @@ def evaluate_ppl(session: FederatedSession, test_ds, batch_size: int):
 
 
 def main(argv=None, **overrides):
+    from commefficient_tpu import native
     from commefficient_tpu.multihost import initialize_multihost
     from commefficient_tpu.parallel.mesh import initialize_distributed
+    from commefficient_tpu.utils.platform import configure_compile_cache
 
+    configure_compile_cache()
     cfg = parse_args(
         argv,
         defaults=dict(
@@ -296,7 +299,8 @@ def main(argv=None, **overrides):
         f"dataset=personachat (real={real}) model={cfg.model} "
         f"(V={gcfg.vocab_size}, L={gcfg.n_layer}, E={gcfg.n_embd}, "
         f"hf_weights={hf_loaded}) mode={cfg.mode} "
-        f"clients={train.num_clients} workers={cfg.num_workers}"
+        f"clients={train.num_clients} workers={cfg.num_workers} "
+        f"host_loader={native.describe()}"
     )
     if not real:
         print("WARNING: personachat json not found — synthetic stand-in "

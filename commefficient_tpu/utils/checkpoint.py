@@ -371,7 +371,9 @@ class FedCheckpointer:
                 )
             except ValueError as e:
                 msg = str(e)
-                if "Dict key mismatch" not in msg:
+                # the installed orbax names each differing key under this
+                # headline ("<key>: - Source: MISSING ...")
+                if "tree structures do not match" not in msg:
                     raise
                 if "blacklist" in msg:
                     if "blacklist" in template:

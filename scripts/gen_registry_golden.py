@@ -9,11 +9,14 @@ losses of a short multi-round run on the standard 8-device virtual CPU mesh
 (the same harness tier-1 uses). tests/test_compress_parity.py replays the
 identical configs and compares against the recording.
 
-The committed tests/golden/registry_parity.npz was generated at the LAST
-pre-refactor commit (PR 1, 644a056), so it encodes the legacy dispatch's
-behavior, not the registry's. Regenerate ONLY when a deliberate,
-documented semantic change to a mode's algebra lands (record why in the
-commit), with:
+The recording was first taken at the LAST pre-refactor commit (PR 1,
+644a056), so the registry was pinned to the legacy dispatch's behavior.
+It was re-recorded once at PR 21 under the installed stack (jax/jaxlib
+0.9.0): another JAX's RNG and XLA gave every config a different round-0
+loss (fedavg 1.5036 vs the recorded 1.7450), so the old file pinned
+nothing. Regenerate ONLY when the installation changes or a deliberate,
+documented semantic change to a mode's algebra lands (record why in
+CHANGES.md), with:
 
     JAX_PLATFORMS=cpu python scripts/gen_registry_golden.py
 """

@@ -186,9 +186,9 @@ def test_purity_pallas_root_and_method_resolution(tmp_path):
 
 
 def test_purity_resolves_defs_under_control_flow(tmp_path):
-    """Version-gated definitions (the utils/jax_compat.py shape: ``if
-    HAS_VMA: def f ... else: def f ...``) register in the enclosing
-    scope, so the call graph follows them."""
+    """Definitions gated under control flow (``if cond: def f ... else:
+    def f ...``) register in the enclosing scope, so the call graph
+    follows them."""
     findings = _lint_dir(tmp_path, {"mod.py": (
         "import time\n"
         "import jax\n"
@@ -756,7 +756,8 @@ def test_package_pragmas_all_carry_reasons():
     (the clean gate implies this, but assert it directly so a pragma
     regression fails with a pointed message), and the known intentional
     exemptions are present — the trace-time sketch constants and the
-    best-effort telemetry swallows."""
+    flight recorder's best-effort swallows (the run header's device
+    identity is no longer one: telemetry/ledger.py fails without it)."""
     from commefficient_tpu.analysis import PackageIndex, analyzer_registry
     from commefficient_tpu.analysis.core import PACKAGE_ROOT
 
@@ -770,4 +771,5 @@ def test_package_pragmas_all_carry_reasons():
         assert p.reason, f"{rel}:{p.lineno}: pragma without a reason"
     by_file = {rel for rel, _ in all_pragmas}
     assert "ops/countsketch.py" in by_file  # seed-derived trace constants
-    assert "telemetry/ledger.py" in by_file  # best-effort metadata
+    assert "telemetry/flight.py" in by_file  # best-effort crash dumps
+    assert "telemetry/ledger.py" not in by_file  # identity is mandatory

@@ -1,13 +1,15 @@
-"""Registry-port parity: the compress/ refactor changed NO round output.
+"""Round-output parity: no refactor may change what a round computes.
 
-tests/golden/registry_parity.npz was recorded at the last pre-refactor
-commit (scripts/gen_registry_golden.py documents how and when to
-regenerate): final params vector + per-round losses for one representative
-config per legacy mode on the standard 8-device virtual CPU mesh. The
-registry port was a mechanical extraction, so outputs must be bit-identical
-on this platform; the assertions allow only fp32-noise headroom (1e-6
-relative) for the paths whose op ORDER the legacy round never pinned
-(XLA may re-fuse across the extracted function boundaries).
+tests/golden/registry_parity.npz holds the final params vector + per-round
+losses for one representative config per legacy mode on the standard
+8-device virtual CPU mesh. First recorded at the last commit before the
+compress/ registry port (a mechanical extraction), re-recorded once at
+PR 21 under the installed jax 0.9.0, whose RNG and XLA differ from the
+recording JAX's (scripts/gen_registry_golden.py documents how and when to
+regenerate). Outputs must match the recording on this platform; the
+assertions allow only fp32-noise headroom (1e-6 relative) for the paths
+whose op ORDER the round never pinned (XLA may re-fuse across function
+boundaries).
 """
 
 import os

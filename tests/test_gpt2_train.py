@@ -22,6 +22,7 @@ def test_gpt2_train_e2e_uncompressed(tmp_path):
         num_candidates=2,
         mode="uncompressed",
         checkpoint_dir=str(tmp_path / "ck"),
+        logdir=str(tmp_path / "runs"),
     )
     assert np.isfinite(val["nll"]) and val["ppl"] > 0
     assert 0.0 <= val["mc_accuracy"] <= 1.0

@@ -194,7 +194,6 @@ from commefficient_tpu.parallel.mesh import (  # noqa: E402
     worker_axis_size,
 )
 from commefficient_tpu.utils.config import Config  # noqa: E402
-from commefficient_tpu.utils.jax_compat import shard_map  # noqa: E402
 
 from tests.test_round import BASE, _setup  # noqa: E402
 
@@ -410,7 +409,7 @@ def test_butterfly_two_level_hop_count_and_equivalence():
         sup = rng.choice(d, size=k, replace=False)
         dense[w, sup] = rng.normal(size=k).astype(np.float32)
     mesh = make_mesh(W, hosts=H)
-    f = jax.jit(shard_map(
+    f = jax.jit(jax.shard_map(
         lambda v: sparse_allreduce_sharded(
             v[0], k, (HOSTS, WORKERS), axis_size=W,
             axis_sizes=(H, W // H))[None],

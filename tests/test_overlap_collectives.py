@@ -47,7 +47,6 @@ from commefficient_tpu.parallel import FederatedSession
 from commefficient_tpu.parallel.mesh import WORKERS, make_mesh
 from commefficient_tpu.parallel.round import leaf_groups
 from commefficient_tpu.utils.config import Config
-from commefficient_tpu.utils.jax_compat import shard_map
 
 P = jax.sharding.PartitionSpec
 Wd = 8
@@ -104,7 +103,7 @@ def test_psum_segments_bit_equal_to_fused_psum_on_mesh():
         b = psum_segments_fused(segs, WORKERS)
         return tuple(x[None] for x in a), tuple(x[None] for x in b)
 
-    f = shard_map(body, mesh=mesh,
+    f = jax.shard_map(body, mesh=mesh,
                   in_specs=tuple(P(WORKERS) for _ in xs),
                   out_specs=(tuple(P(WORKERS) for _ in xs),
                              tuple(P(WORKERS) for _ in xs)))
@@ -128,7 +127,7 @@ def test_all_gather_pairs_chunked_rebuilds_monolithic(kb, segments):
         gi_s, gv_s = all_gather_pairs(i, v, WORKERS, segments=segments)
         return gi_m[None], gv_m[None], gi_s[None], gv_s[None]
 
-    f = shard_map(body, mesh=mesh, in_specs=(P(WORKERS), P(WORKERS)),
+    f = jax.shard_map(body, mesh=mesh, in_specs=(P(WORKERS), P(WORKERS)),
                   out_specs=(P(WORKERS),) * 4)
     gi_m, gv_m, gi_s, gv_s = jax.jit(f)(idx, val)
     np.testing.assert_array_equal(np.asarray(gi_m), np.asarray(gi_s))

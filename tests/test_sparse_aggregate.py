@@ -49,7 +49,6 @@ from commefficient_tpu.ops.topk import compact_nonzero
 from commefficient_tpu.parallel import FederatedSession
 from commefficient_tpu.parallel.mesh import WORKERS, make_mesh
 from commefficient_tpu.utils.config import Config
-from commefficient_tpu.utils.jax_compat import shard_map
 
 P = jax.sharding.PartitionSpec
 
@@ -282,7 +281,9 @@ def _compiled_round_text(cfg):
 def _collective_shapes(text, op):
     """(elems, line) per static ``op`` occurrence, skipping -done halves
     (the -start line carries an (operand, output, ...) tuple — take the
-    transferred second component, as telemetry/xla_audit.py does)."""
+    transferred second component, as telemetry/xla_audit.py does).
+    ``elems`` is the largest buffer of a variadic op: XLA's combiner packs
+    the idx/val pair exchanges and the loss scalars into one launch."""
     out = []
     for ln in text.splitlines():
         m = re.search(r"=\s*([^=]*?)\s*" + op + r"(-start)?\(", ln)
@@ -294,7 +295,7 @@ def _collective_shapes(text, op):
                       m.group(1))]
         if m.group(2) and len(shapes) > 1:
             shapes = shapes[1:]
-        out.append((sum(shapes), ln))
+        out.append((max(shapes), ln))
     return out
 
 
@@ -433,7 +434,7 @@ def test_sparse_allreduce_matches_dense_sum():
         sup = rng.choice(d // 2, size=k, replace=False)  # forced overlap
         dense[w, sup] = rng.normal(size=k).astype(np.float32)
     mesh = make_mesh(Wd)
-    f = shard_map(
+    f = jax.shard_map(
         lambda v: sparse_allreduce(v[0], k, WORKERS)[None],
         mesh=mesh, in_specs=(P(WORKERS),), out_specs=P(WORKERS),
     )
@@ -454,7 +455,7 @@ def test_sparse_allreduce_sharded_matches_sum_then_slice():
         sup = rng.choice(d, size=k, replace=False)
         dense[w, sup] = rng.normal(size=k).astype(np.float32)
     mesh = make_mesh(Wd)
-    f = shard_map(
+    f = jax.shard_map(
         lambda v: sparse_allreduce_sharded(
             v[0], k, WORKERS, axis_size=Wd)[None],
         mesh=mesh, in_specs=(P(WORKERS),), out_specs=P(WORKERS),
@@ -468,7 +469,7 @@ def test_sparse_allreduce_sharded_lowers_to_ppermute_only():
     all-reduce, no all-gather, nothing replicated."""
     d, k, Wd = 512, 5, 8
     mesh = make_mesh(Wd)
-    f = shard_map(
+    f = jax.shard_map(
         lambda v: sparse_allreduce_sharded(
             v[0], k, WORKERS, axis_size=Wd)[None],
         mesh=mesh, in_specs=(P(WORKERS),), out_specs=P(WORKERS),
@@ -491,7 +492,7 @@ def test_all_gather_pairs_and_scatter_add_contracts():
     (0, 0.0) padding as a no-op."""
     Wd = 8
     mesh = make_mesh(Wd)
-    f = shard_map(
+    f = jax.shard_map(
         lambda i, v: tuple(
             a[None] for a in all_gather_pairs(i[0], v[0], WORKERS)),
         mesh=mesh, in_specs=(P(WORKERS), P(WORKERS)),
@@ -544,7 +545,7 @@ def test_sparse_allreduce_capacity_overflow_drops_by_position():
     Wd = 8
     mesh = make_mesh(Wd)
     v = jnp.ones((Wd, 16), jnp.float32)  # 16 nonzeros, capacity 4
-    f = shard_map(
+    f = jax.shard_map(
         lambda x: sparse_allreduce(x[0], 4, WORKERS)[None],
         mesh=mesh, in_specs=(P(WORKERS),), out_specs=P(WORKERS),
     )

@@ -168,8 +168,7 @@ def test_federated_tp_sp_round_matches_dp_oracle(compute_dtype):
     # params: strict tolerance for the bulk, but a FEW isolated
     # selection-boundary flips are fp-rounding lottery, not error — the
     # rank-k boundary of the unsketch extraction flips under any
-    # perturbation of summation order (e.g. pre-vma JAX realizes the
-    # model/seq grad total as an explicit psum, utils/jax_compat), and a
+    # perturbation of summation order, and a
     # flipped coordinate differs by the full extracted value. A systematic
     # gradient error flips thousands of coordinates AND breaks the loss
     # trajectory pinned above.
