@@ -55,7 +55,6 @@ trade every practical async system makes on cold restart.
 from __future__ import annotations
 
 import time
-from contextlib import nullcontext
 from typing import Any, Dict, Optional
 
 import jax
@@ -64,6 +63,7 @@ import numpy as np
 
 from commefficient_tpu.asyncfed.schedule import AsyncSchedule, UpdateSpec
 from commefficient_tpu.pipeline.cohorts import CohortScheduler
+from commefficient_tpu.telemetry.spans import span_of
 
 
 class AsyncFederation:
@@ -239,9 +239,8 @@ class AsyncFederation:
     # -- launch ------------------------------------------------------------
     def _span(self, name: str, collective: bool = False, trace_id=None,
               parent=None):
-        return self.spans.span(name, collective=collective,
-                               trace_id=trace_id, parent=parent) if (
-            self.spans is not None) else nullcontext()
+        return span_of(self.spans, name, collective=collective,
+                       trace_id=trace_id, parent=parent)
 
     def _drain_deferred(self) -> None:
         """Fence the PREVIOUS update's parked apply (double-buffer mode).

@@ -129,7 +129,9 @@ class SketchCompressor(Compressor):
             # zero HH (linearity); the interior re-sketch accumulates at
             # f32 regardless of the storage dtype (_spec_acc) so the EF
             # bank's algebra never pays a bf16 round-trip mid-round
-            e = e - sketch_vec(self._spec_acc, update)
+            # (ef_resketch: telemetry.trace.ROUND_SCOPES, op metadata only)
+            with jax.named_scope("ef_resketch"):
+                e = e - sketch_vec(self._spec_acc, update)
             if cfg.error_decay != 1.0:
                 e = cfg.error_decay * e  # d/c-envelope mitigation
             delta = update
@@ -147,9 +149,10 @@ class SketchCompressor(Compressor):
             # sketch_sparse is the same hash mapping; pinned by
             # tests/test_sketch_decode.py's dampening regression).
             hh_idx, hh_val = compact_nonzero(update, cfg.k)
-            m_at_hh = jnp.where(hh_val != 0,
-                                estimate_at(spec, m, hh_idx), 0.0)
-            m = m - sketch_sparse(spec, hh_idx, m_at_hh)
+            with jax.named_scope("ef_resketch"):
+                m_at_hh = jnp.where(hh_val != 0,
+                                    estimate_at(spec, m, hh_idx), 0.0)
+                m = m - sketch_sparse(spec, hh_idx, m_at_hh)
         new_m = m if rho > 0 else momentum
         return delta, self._down(new_m), self._down(e), extra
 
@@ -189,21 +192,24 @@ class SketchCompressor(Compressor):
             # the dense branch's `update != 0` — `sel != 0` would differ
             # at lr == 0.
             loc_d, upd_val = compact_nonzero(upd, cfg.k)
-            hh_gidx = jnp.minimum(my * S + loc_d, d - 1)
-            m_at_hh = jnp.where(
-                upd_val != 0,
-                estimate_at(spec, m, hh_gidx), 0.0,
-            )
-            if self._ride_pair_exchange:
-                g_i, g_v = all_gather_pairs(hh_gidx, m_at_hh, axis_name,
-                                            segments=self.overlap_segments)
-                m = m - sketch_sparse(spec, g_i, g_v).astype(spec.table_dtype)
-            else:
-                m = m - jax.lax.psum(
-                    sketch_sparse(spec, hh_gidx,
-                                  m_at_hh).astype(spec.table_dtype),
-                    axis_name,
+            with jax.named_scope("ef_resketch"):
+                hh_gidx = jnp.minimum(my * S + loc_d, d - 1)
+                m_at_hh = jnp.where(
+                    upd_val != 0,
+                    estimate_at(spec, m, hh_gidx), 0.0,
                 )
+                if self._ride_pair_exchange:
+                    g_i, g_v = all_gather_pairs(
+                        hh_gidx, m_at_hh, axis_name,
+                        segments=self.overlap_segments)
+                    m = m - sketch_sparse(spec, g_i, g_v).astype(
+                        spec.table_dtype)
+                else:
+                    m = m - jax.lax.psum(
+                        sketch_sparse(spec, hh_gidx,
+                                      m_at_hh).astype(spec.table_dtype),
+                        axis_name,
+                    )
         new_m = m if rho > 0 else momentum
         # compact this shard's <= k selected entries into a fixed-size
         # candidate buffer and exchange ~Wd*kb pairs — the ONLY vector
@@ -255,19 +261,22 @@ class SketchCompressor(Compressor):
             # bytes under bf16 tables — and what keeps the xla_audit
             # ledger-vs-HLO tolerance arithmetic exact); the subtraction
             # promotes back to e's f32
-            if self._ride_pair_exchange:
-                # aggregate='sparse': the table psum becomes a <= Wd*k
-                # pair all_gather + ONE local re-sketch of all pairs
-                # (linearity — same table up to f32 summation order)
-                g_i, g_v = all_gather_pairs(idx_c[loc], val, axis_name,
-                                            segments=self.overlap_segments)
-                e = e - sketch_sparse(spec, g_i, g_v).astype(spec.table_dtype)
-            else:
-                e = e - jax.lax.psum(
-                    sketch_sparse(spec, idx_c[loc],
-                                  val).astype(spec.table_dtype),
-                    axis_name,
-                )
+            with jax.named_scope("ef_resketch"):
+                if self._ride_pair_exchange:
+                    # aggregate='sparse': the table psum becomes a <= Wd*k
+                    # pair all_gather + ONE local re-sketch of all pairs
+                    # (linearity — same table up to f32 summation order)
+                    g_i, g_v = all_gather_pairs(
+                        idx_c[loc], val, axis_name,
+                        segments=self.overlap_segments)
+                    e = e - sketch_sparse(spec, g_i, g_v).astype(
+                        spec.table_dtype)
+                else:
+                    e = e - jax.lax.psum(
+                        sketch_sparse(spec, idx_c[loc],
+                                      val).astype(spec.table_dtype),
+                        axis_name,
+                    )
             if cfg.error_decay != 1.0:
                 e = cfg.error_decay * e
             return upd, upd, e

@@ -952,11 +952,12 @@ def unsketch_sparse(
     ``lax.approx_max_k`` (TPU-native, faster, ~0.95 recall) — callers opt in.
     """
     est = estimate_all(spec, table)
-    if approx:
-        _, hh_idx = jax.lax.approx_max_k(jnp.abs(est), k)
-    else:
-        _, hh_idx = jax.lax.top_k(jnp.abs(est), k)
-    return hh_idx, est[hh_idx]
+    with jax.named_scope("topk_select"):  # telemetry.trace.ROUND_SCOPES
+        if approx:
+            _, hh_idx = jax.lax.approx_max_k(jnp.abs(est), k)
+        else:
+            _, hh_idx = jax.lax.top_k(jnp.abs(est), k)
+        return hh_idx, est[hh_idx]
 
 
 def unsketch(
@@ -964,7 +965,8 @@ def unsketch(
 ) -> jnp.ndarray:
     """``unsketch_sparse`` materialized as a dense [d] vector, k nonzeros."""
     hh_idx, vals = unsketch_sparse(spec, table, k, approx=approx)
-    return jnp.zeros(spec.d, dtype=vals.dtype).at[hh_idx].set(vals)
+    with jax.named_scope("topk_select"):
+        return jnp.zeros(spec.d, dtype=vals.dtype).at[hh_idx].set(vals)
 
 
 def unsketch_dense(spec: CountSketch, table: jnp.ndarray, k: int) -> jnp.ndarray:

@@ -13,7 +13,13 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+# Every selection below runs under the ``topk_select`` scope
+# (telemetry.trace.ROUND_SCOPES: a name in the op metadata, no op), so a
+# device trace tells the selection from the estimate before it and the
+# re-sketch after it.
 
+
+@jax.named_scope("topk_select")
 def topk_sparsify(v: jnp.ndarray, k: int, *, approx: bool = False):
     """Return (values [k], indices [k]) of the k largest-|.| entries of flat v."""
     mag = jnp.abs(v)
@@ -27,9 +33,11 @@ def topk_sparsify(v: jnp.ndarray, k: int, *, approx: bool = False):
 def topk_dense(v: jnp.ndarray, k: int, *, approx: bool = False) -> jnp.ndarray:
     """Dense [d] vector keeping only the top-k entries of v by magnitude."""
     vals, idx = topk_sparsify(v, k, approx=approx)
-    return jnp.zeros_like(v).at[idx].set(vals)
+    with jax.named_scope("topk_select"):
+        return jnp.zeros_like(v).at[idx].set(vals)
 
 
+@jax.named_scope("topk_select")
 def topk_threshold_dense(v: jnp.ndarray, k: int, iters: int = 32) -> jnp.ndarray:
     """Dense top-≤k by magnitude via binary-searched threshold — the TPU
     fast path: no sort (lax.top_k is ~40 ms at d=6.5M on v5e) and no
@@ -69,6 +77,7 @@ def topk_threshold_dense(v: jnp.ndarray, k: int, iters: int = 32) -> jnp.ndarray
     return v * ((mag >= hi) & (mag > 0))
 
 
+@jax.named_scope("topk_select")
 def topk_threshold_sharded(v_local: jnp.ndarray, k: int, axis_name: str,
                            iters: int = 32) -> jnp.ndarray:
     """``topk_threshold_dense`` over a vector SHARDED along ``axis_name`` —
@@ -97,6 +106,7 @@ def topk_threshold_sharded(v_local: jnp.ndarray, k: int, axis_name: str,
     return v_local * ((mag >= hi) & (mag > 0))
 
 
+@jax.named_scope("topk_select")
 def compact_nonzero(v: jnp.ndarray, k: int):
     """Compact a ≤k-sparse dense vector into fixed-size ``(idx [kb], val
     [kb])`` buffers (``kb = min(k, len(v))``), positions ascending, padded

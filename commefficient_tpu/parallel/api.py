@@ -19,6 +19,7 @@ the reference's API contract either — workers and server state are opaque).
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Callable, Dict, Iterable, Optional
 
 import jax
@@ -46,7 +47,25 @@ from commefficient_tpu.parallel.round import (
     needs_client_err,
     needs_client_vel,
 )
+from commefficient_tpu.telemetry.spans import span_of
 from commefficient_tpu.utils.config import Config
+
+
+def _round_step(train_round):
+    """Each call of a session's ``train_round*`` is one ``fed/round`` step
+    in whatever profiler trace is open, numbered by the round clock (the
+    ``r<step>`` of telemetry/trace.py): the parent of that round's
+    ``fed/*`` host spans, and the step marker the profiler's tools group
+    device ops by. A flag test when no trace is open; nothing here
+    touches a device value."""
+
+    @functools.wraps(train_round)
+    def stepped(self, *args, **kwargs):
+        with jax.profiler.StepTraceAnnotation("fed/round",
+                                              step_num=self._round_clock):
+            return train_round(self, *args, **kwargs)
+
+    return stepped
 
 
 def _rung_hook_name(label: str, base: str = "round_fn") -> str:
@@ -996,18 +1015,21 @@ class FederatedSession:
 
         def round_idx_fn(state, data, client_ids, idx, plan, lr, env=()):
             W, B = idx.shape
-            flat = idx.reshape(-1)
-            batch = {}
-            for k, v in data.items():
-                g = v[flat]
-                if k == "x" and has_aug:
-                    g = augment.device_apply(g, *plan)
-                batch[k] = g.reshape((W, B) + g.shape[1:])
-            if L:  # fedavg microbatch convention ([W, L, B/L, ...]), any L
-                batch = {
-                    k: v.reshape(v.shape[0], L, v.shape[1] // L, *v.shape[2:])
-                    for k, v in batch.items()
-                }
+            # telemetry.trace.ROUND_SCOPES: a name in the op metadata, no op
+            with jax.named_scope("data_gather"):
+                flat = idx.reshape(-1)
+                batch = {}
+                for k, v in data.items():
+                    g = v[flat]
+                    if k == "x" and has_aug:
+                        g = augment.device_apply(g, *plan)
+                    batch[k] = g.reshape((W, B) + g.shape[1:])
+                if L:  # fedavg microbatch convention ([W, L, B/L, ...])
+                    batch = {
+                        k: v.reshape(v.shape[0], L, v.shape[1] // L,
+                                     *v.shape[2:])
+                        for k, v in batch.items()
+                    }
             return raw_round(state, client_ids, batch, lr, env=env)
 
         return round_idx_fn
@@ -1188,18 +1210,17 @@ class FederatedSession:
 
     def _span(self, name: str, fence=None, collective: bool = False,
               trace_id=None):
-        """Phase-span context (telemetry/spans.py) — a nullcontext yielding
-        None unless a train loop attached a recorder (level >= 1).
+        """Phase-span context (telemetry/spans.py): a ``fed/<name>``
+        annotation in whatever profiler trace is open, at every telemetry
+        level, and recorded in the ring where a train loop attached a
+        recorder (level >= 1; yields None otherwise).
         ``collective=True`` tags the span for the exposed-collective
         accounting (the round-dispatch spans: their fence waits on the
         program's aggregation collectives); ``trace_id=`` stamps the
         owning round's id (schema v11)."""
-        if self.spans is None:
-            from contextlib import nullcontext
-
-            return nullcontext()
-        return self.spans.span(name, fence=fence, collective=collective,
-                               trace_id=trace_id)
+        return span_of(self.spans, name,
+                       self._round_clock if self.spans is None else None,
+                       fence=fence, collective=collective, trace_id=trace_id)
 
     def _host_round_stats(self, fs_stats: dict) -> dict:
         """Host scalars riding this round's metric dict: the fedsim stats,
@@ -1279,6 +1300,7 @@ class FederatedSession:
         if self.controller is not None:
             self.controller.on_round_start(self._round_clock, fs_stats)
 
+    @_round_step
     def train_round_indices(self, client_ids, idx, plan, lr: float, env=None):
         """Run one round from device-resident data (see ``attach_data``)."""
         from commefficient_tpu.telemetry.trace import round_trace_id
@@ -1328,6 +1350,7 @@ class FederatedSession:
         return self._streamer.gather(np.asarray(client_ids),
                                      trace_id=trace_id)
 
+    @_round_step
     def train_round(self, client_ids: np.ndarray, batch: Dict[str, np.ndarray],
                     lr: float, env=None, cohort=None):
         from commefficient_tpu.telemetry.trace import round_trace_id

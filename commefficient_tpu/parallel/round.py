@@ -185,22 +185,25 @@ def make_grad_one(cfg: Config, loss_fn: Callable, unravel: Callable):
     f32 = jnp.float32
 
     def grad_one(params_vec, batch, noise_rng):
-        params = unravel(params_vec)
-        (loss, aux), grads = jax.value_and_grad(loss_fn, has_aux=True)(params, batch)
-        # named_scope marker (no ops added): the scope name survives into
-        # the compiled HLO's op metadata, so the sketch-fused-backward
-        # tests can pin that THEIR lowered round contains no flat [D]
-        # gradient concat (tests/test_sketch_fused_bwd.py)
-        with jax.named_scope("flat_grad_concat"):
-            g, _ = ravel_pytree(grads)
-        g = g.astype(f32)
-        if cfg.weight_decay:
-            g = g + cfg.weight_decay * params_vec
-        g = clip_by_global_norm(g, cfg.max_grad_norm)
-        if cfg.dp_noise_multiplier > 0 and cfg.max_grad_norm is not None:
-            # worker-side DP: clip (above) + gaussian noise, fed_worker ~L380-420
-            sigma = cfg.dp_noise_multiplier * cfg.max_grad_norm
-            g = g + sigma * jax.random.normal(noise_rng, g.shape, f32)
+        # the scopes here and below are telemetry.trace.ROUND_SCOPES: op
+        # metadata only (no ops added), read back from the device trace
+        with jax.named_scope("client_grad"):
+            params = unravel(params_vec)
+            (loss, aux), grads = jax.value_and_grad(loss_fn, has_aux=True)(params, batch)
+            # marker: the sketch-fused-backward tests pin that THEIR
+            # lowered round contains no flat [D] gradient concat
+            # (tests/test_sketch_fused_bwd.py)
+            with jax.named_scope("flat_grad_concat"):
+                g, _ = ravel_pytree(grads)
+            g = g.astype(f32)
+        with jax.named_scope("client_clip"):
+            if cfg.weight_decay:
+                g = g + cfg.weight_decay * params_vec
+            g = clip_by_global_norm(g, cfg.max_grad_norm)
+            if cfg.dp_noise_multiplier > 0 and cfg.max_grad_norm is not None:
+                # worker-side DP: clip (above) + gaussian noise, fed_worker ~L380-420
+                sigma = cfg.dp_noise_multiplier * cfg.max_grad_norm
+                g = g + sigma * jax.random.normal(noise_rng, g.shape, f32)
         return g, loss, aux
 
     return grad_one
@@ -307,17 +310,20 @@ def make_sketch_grad_one(cfg: Config, loss_fn: Callable, unravel: Callable,
             ]
             return loss_fn(jax.tree.unflatten(treedef, tapped_leaves), batch)
 
-        zeros = _varying_like(jnp.zeros(spec.table_shape, jnp.float32),
-                              params_vec)
-        (loss, aux), table = jax.value_and_grad(tapped, has_aux=True)(zeros)
+        with jax.named_scope("client_grad"):
+            zeros = _varying_like(jnp.zeros(spec.table_shape, jnp.float32),
+                                  params_vec)
+            (loss, aux), table = jax.value_and_grad(
+                tapped, has_aux=True)(zeros)
         if cfg.weight_decay:
             # sketch(g + wd*p) = sketch(g) + wd * sketch(p); the [D]
             # params vector already exists as state, so its sketch takes
             # the matmul path (f32 accumulation — _replace keeps interior
             # algebra f32 under bf16 table storage)
-            table = table + cfg.weight_decay * sketch_vec(
-                spec._replace(table_dtype=jnp.float32), params_vec
-            )
+            with jax.named_scope("client_clip"):
+                table = table + cfg.weight_decay * sketch_vec(
+                    spec._replace(table_dtype=jnp.float32), params_vec
+                )
         return table, loss, aux
 
     def grad_group_tables(params_vec, batch, noise_rng):
@@ -339,20 +345,23 @@ def make_sketch_grad_one(cfg: Config, loss_fn: Callable, unravel: Callable,
                     )
             return loss_fn(jax.tree.unflatten(treedef, tapped_leaves), batch)
 
-        zeros = tuple(
-            _varying_like(jnp.zeros(spec.table_shape, jnp.float32),
-                          params_vec)
-            for _ in groups
-        )
-        (loss, aux), tables = jax.value_and_grad(tapped, has_aux=True)(zeros)
+        with jax.named_scope("client_grad"):
+            zeros = tuple(
+                _varying_like(jnp.zeros(spec.table_shape, jnp.float32),
+                              params_vec)
+                for _ in groups
+            )
+            (loss, aux), tables = jax.value_and_grad(
+                tapped, has_aux=True)(zeros)
         if cfg.weight_decay:
             # wd rides the FIRST group's table (the one whose cotangent
             # completes last, so no overlap window shrinks): the group
             # tables only ever matter through their sum
-            wd = cfg.weight_decay * sketch_vec(
-                spec._replace(table_dtype=jnp.float32), params_vec
-            )
-            tables = (tables[0] + wd,) + tables[1:]
+            with jax.named_scope("client_clip"):
+                wd = cfg.weight_decay * sketch_vec(
+                    spec._replace(table_dtype=jnp.float32), params_vec
+                )
+                tables = (tables[0] + wd,) + tables[1:]
         return tables, loss, aux
 
     return grad_group_tables if groups is not None else grad_one_table
@@ -379,27 +388,31 @@ def sum_client_grads(grad_one, params_vec, batch, client_ids, rng, *,
             batch,
         )
         g, loss_flat, aux = grad_one(params_vec, flat, rng)
-        return w_loc * g, w_loc * loss_flat, aux
+        with jax.named_scope("client_sum"):
+            return w_loc * g, w_loc * loss_flat, aux
 
     def per_client(b, cid):
         return grad_one(params_vec, b, jax.random.fold_in(rng, cid))
 
     gs, losses, auxes = jax.vmap(per_client)(batch, client_ids)
-    if live is not None:
-        ext = lambda m, a: m.reshape(m.shape + (1,) * (a.ndim - 1))  # noqa: E731
-        # corruption first, mask second: a zero mask blocks even a
-        # corrupted payload's NaN (same ordering as worker_shard's
-        # per_client — only a LIVE corrupted client poisons the aggregate)
-        if corrupt is not None:
-            gs = jnp.where(ext(corrupt, gs) > 0, jnp.float32(jnp.nan), gs)
-        gs = jnp.where(ext(live, gs) > 0, gs, 0.0)
-        losses = losses * live
-        auxes = jax.tree.map(lambda a: a * ext(live, a), auxes)
-    return (
-        jnp.sum(gs, axis=0),
-        jnp.sum(losses),
-        jax.tree.map(lambda a: jnp.sum(a, 0), auxes),
-    )
+    with jax.named_scope("client_sum"):
+        if live is not None:
+            ext = lambda m, a: m.reshape(m.shape + (1,) * (a.ndim - 1))  # noqa: E731
+            # corruption first, mask second: a zero mask blocks even a
+            # corrupted payload's NaN (same ordering as worker_shard's
+            # per_client — only a LIVE corrupted client poisons the
+            # aggregate)
+            if corrupt is not None:
+                gs = jnp.where(ext(corrupt, gs) > 0, jnp.float32(jnp.nan),
+                               gs)
+            gs = jnp.where(ext(live, gs) > 0, gs, 0.0)
+            losses = losses * live
+            auxes = jax.tree.map(lambda a: a * ext(live, a), auxes)
+        return (
+            jnp.sum(gs, axis=0),
+            jnp.sum(losses),
+            jax.tree.map(lambda a: jnp.sum(a, 0), auxes),
+        )
 
 
 def make_per_client(cfg: Config, comp, grad_one, *, use_fedsim: bool):
@@ -417,33 +430,34 @@ def make_per_client(cfg: Config, comp, grad_one, *, use_fedsim: bool):
     def per_client(params_vec, b, cid, vel, err, rng, lr, m=None, c=None):
         noise_rng = jax.random.fold_in(rng, cid)
         g, loss, aux = comp.client_grad(grad_one, params_vec, b, noise_rng, lr)
-        u = lm * vel + g if lm > 0 else g
-        # the compressor's per-client transmit rule (local_topk: local
-        # error feedback + top-k + momentum masking). Dense-transmit
-        # modes return u itself: by linearity of device_encode,
-        # encode(sum of local clients' u) == sum of their encodings, so
-        # each device encodes ONCE downstream instead of per client (8x
-        # fewer sketches per chip; ICI still carries only the encoding).
-        transmit, new_vel, new_err = comp.client_transmit(u, err, lr)
-        if use_fedsim:
-            # masked aggregation (fedsim/): chaos corruption NaNs a
-            # client's payload FIRST (so the flight-recorder/
-            # DivergenceError path is exercised end-to-end), then the
-            # live mask zeroes every non-participant's transmit —
-            # jnp.where, not multiply, so a zero mask blocks even a
-            # corrupted payload's NaN (0 * nan == nan): only a LIVE
-            # corrupted client can poison the aggregate. A masked-out
-            # client's local momentum/error rows carry forward
-            # unmodified (it never participated; reference per-client-
-            # state semantics).
-            transmit = jnp.where(c > 0, jnp.float32(jnp.nan), transmit)
-            transmit = jnp.where(m > 0, transmit, 0.0)
-            loss = loss * m
-            aux = jax.tree.map(lambda a: a * m, aux)
-            if lm > 0:
-                new_vel = jnp.where(m > 0, new_vel, vel)
-            if cfg.error_type == "local":
-                new_err = jnp.where(m > 0, new_err, err)
+        with jax.named_scope("client_transmit"):
+            u = lm * vel + g if lm > 0 else g
+            # the compressor's per-client transmit rule (local_topk: local
+            # error feedback + top-k + momentum masking). Dense-transmit
+            # modes return u itself: by linearity of device_encode,
+            # encode(sum of local clients' u) == sum of their encodings, so
+            # each device encodes ONCE downstream instead of per client (8x
+            # fewer sketches per chip; ICI still carries only the encoding).
+            transmit, new_vel, new_err = comp.client_transmit(u, err, lr)
+            if use_fedsim:
+                # masked aggregation (fedsim/): chaos corruption NaNs a
+                # client's payload FIRST (so the flight-recorder/
+                # DivergenceError path is exercised end-to-end), then the
+                # live mask zeroes every non-participant's transmit —
+                # jnp.where, not multiply, so a zero mask blocks even a
+                # corrupted payload's NaN (0 * nan == nan): only a LIVE
+                # corrupted client can poison the aggregate. A masked-out
+                # client's local momentum/error rows carry forward
+                # unmodified (it never participated; reference per-client-
+                # state semantics).
+                transmit = jnp.where(c > 0, jnp.float32(jnp.nan), transmit)
+                transmit = jnp.where(m > 0, transmit, 0.0)
+                loss = loss * m
+                aux = jax.tree.map(lambda a: a * m, aux)
+                if lm > 0:
+                    new_vel = jnp.where(m > 0, new_vel, vel)
+                if cfg.error_type == "local":
+                    new_err = jnp.where(m > 0, new_err, err)
         return transmit, new_vel, new_err, loss, aux
 
     return per_client
@@ -552,7 +566,7 @@ def make_aggregate_tail(cfg: Config, comp, plan: AggregationPlan, *,
         aux_sum = jax.tree.unflatten(aux_def, summed[1:])
         return agg, loss_mean, aux_sum
 
-    return aggregate_tail
+    return jax.named_scope("aggregate_tail")(aggregate_tail)
 
 
 def make_decode_mapped(cfg: Config, comp, mesh, plan: AggregationPlan, *,
@@ -619,9 +633,10 @@ def server_phase(cfg: Config, comp, plan: AggregationPlan, decode_mapped,
         # over exactly S (tests/test_fedsim.py). The max(count, 1)
         # guard keeps an all-dropped round finite; its whole server
         # update is frozen below.
-        scale = W / jnp.maximum(count, 1.0)
-        agg = agg * scale
-        loss = loss * scale  # loss becomes the mean over LIVE clients
+        with jax.named_scope("aggregate_tail"):  # the mean, over LIVE
+            scale = W / jnp.maximum(count, 1.0)
+            agg = agg * scale
+            loss = loss * scale  # loss becomes the mean over LIVE clients
     if plan.sparse_apply:
         # sparse apply: each chip extracts its D/W slice inside the
         # shard_map; the replicated outputs are the gathered ~Wd*k
@@ -661,31 +676,32 @@ def server_phase(cfg: Config, comp, plan: AggregationPlan, decode_mapped,
             # rank-r factored — a full-[D] selection there would be a
             # pure waste).
             delta = comp.topk(delta, cfg.k)
-    if count is not None:
-        # all-clients-dropped guard: nothing arrived, so nothing may
-        # move — params freeze (the dense delta, or the sharded
-        # candidate VALUES whose scatter then adds 0.0, zero out) and
-        # every server-state leaf (momentum/error/compressor-private)
-        # carries forward; the host-side fedsim/all_dropped sentinel
-        # rides the metrics instead of a 0/0 poisoning the run
-        ok = count > 0
+    with jax.named_scope("apply_update"):
+        if count is not None:
+            # all-clients-dropped guard: nothing arrived, so nothing may
+            # move — params freeze (the dense delta, or the sharded
+            # candidate VALUES whose scatter then adds 0.0, zero out) and
+            # every server-state leaf (momentum/error/compressor-private)
+            # carries forward; the host-side fedsim/all_dropped sentinel
+            # rides the metrics instead of a 0/0 poisoning the run
+            ok = count > 0
 
-        def keep(new, old):
-            return jax.tree.map(lambda n, o: jnp.where(ok, n, o),
-                                new, old)
+            def keep(new, old):
+                return jax.tree.map(lambda n, o: jnp.where(ok, n, o),
+                                    new, old)
 
-        if plan.sparse_apply:
-            g_val = jnp.where(ok, g_val, 0.0)
-        else:
-            delta = jnp.where(ok, delta, 0.0)
-        new_m = keep(new_m, state.momentum)
-        new_e = keep(new_e, state.error)
-        new_comp = keep(new_comp, state.comp)
-    new_params = (
-        state.params_vec.at[g_idx].add(-g_val)
-        if plan.sparse_apply
-        else state.params_vec - delta
-    )
+            if plan.sparse_apply:
+                g_val = jnp.where(ok, g_val, 0.0)
+            else:
+                delta = jnp.where(ok, delta, 0.0)
+            new_m = keep(new_m, state.momentum)
+            new_e = keep(new_e, state.error)
+            new_comp = keep(new_comp, state.comp)
+        new_params = (
+            state.params_vec.at[g_idx].add(-g_val)
+            if plan.sparse_apply
+            else state.params_vec - delta
+        )
     metrics = {"loss": loss, **aux}
     if cfg.telemetry_level >= 1:
         # in-graph health diagnostics (telemetry/diagnostics.py): ride
@@ -877,14 +893,15 @@ def build_round_fn(
             )
             with jax.named_scope("sketch_fused_bwd"):
                 table, loss_flat, aux = grad_table_one(params_vec, flat, rng)
-            if overlap_layerwise:
-                # per-leaf-group tables (a tuple): encode each group —
-                # the aggregate tail psums them segment-by-segment
-                local = tuple(
-                    comp.encode_grad_table(w_loc * t) for t in table
-                )
-            else:
-                local = comp.encode_grad_table(w_loc * table)
+            with jax.named_scope("encode"):
+                if overlap_layerwise:
+                    # per-leaf-group tables (a tuple): encode each group —
+                    # the aggregate tail psums them segment-by-segment
+                    local = tuple(
+                        comp.encode_grad_table(w_loc * t) for t in table
+                    )
+                else:
+                    local = comp.encode_grad_table(w_loc * table)
             loss_local = w_loc * loss_flat
             new_vel = jnp.zeros((w_loc, 1), f32)
             new_err = jnp.zeros((w_loc, 1), f32)
@@ -906,11 +923,13 @@ def build_round_fn(
                     params_vec, b, cid, vel, err, rng, lr, *fs_
                 )
             )(batch, client_ids, vels, errs, *fs)
-            local = jnp.sum(transmit, axis=0)
-            loss_local = jnp.sum(loss)
-            aux = jax.tree.map(lambda a: jnp.sum(a, 0), aux)
+            with jax.named_scope("client_sum"):
+                local = jnp.sum(transmit, axis=0)
+                loss_local = jnp.sum(loss)
+                aux = jax.tree.map(lambda a: jnp.sum(a, 0), aux)
         if not (fused and sketch_fused):  # fused-bwd already encoded above
-            local = comp.device_encode(local)  # linear -> psum is exact
+            with jax.named_scope("encode"):
+                local = comp.device_encode(local)  # linear -> psum is exact
         agg, loss_mean, aux_sum = aggregate_tail(local, loss_local, aux,
                                                  w_loc)
         return agg, loss_mean, aux_sum, new_vel, new_err
@@ -989,14 +1008,16 @@ def build_round_fn(
                 new_params, new_m, new_e, (), (), state.step + 1, new_comp
             )
             return new_state, metrics, new_vel, new_err
-        client_vel = (
-            state.client_vel.at[client_ids].set(new_vel) if lm > 0 else state.client_vel
-        )
-        client_err = (
-            state.client_err.at[client_ids].set(new_err)
-            if cfg.error_type == "local"
-            else state.client_err
-        )
+        with jax.named_scope("apply_update"):
+            client_vel = (
+                state.client_vel.at[client_ids].set(new_vel)
+                if lm > 0 else state.client_vel
+            )
+            client_err = (
+                state.client_err.at[client_ids].set(new_err)
+                if cfg.error_type == "local"
+                else state.client_err
+            )
         return (
             FedState(new_params, new_m, new_e, client_vel, client_err,
                      state.step + 1, new_comp),

@@ -45,6 +45,8 @@ import threading
 import time
 from typing import Any, NamedTuple, Optional
 
+from commefficient_tpu.telemetry.spans import span_of
+
 
 class RoundWork(NamedTuple):
     """One round's fully realized, staged inputs.
@@ -126,17 +128,13 @@ class RoundPrefetcher:
 
     # -- worker side -------------------------------------------------------
     def _span(self, name: str, step: int):
-        if self.spans is None:
-            from contextlib import nullcontext
-
-            return nullcontext()
         from commefficient_tpu.telemetry.trace import round_trace_id
 
         # every prefetch span names the round it is REALIZING (schema
         # v11) — the Perfetto tree links this lane's work to the
         # dispatch-lane spans of the same round
-        return self.spans.span(name, step=step,
-                               trace_id=round_trace_id(step))
+        return span_of(self.spans, name, step,
+                       trace_id=round_trace_id(step))
 
     def _realize(self, step: int) -> RoundWork:
         t0 = time.perf_counter()

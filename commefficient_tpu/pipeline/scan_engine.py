@@ -53,6 +53,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from commefficient_tpu.telemetry.spans import span_of
+
 
 class ScanRounds:
     """One per train loop when ``cfg.scan_rounds > 1`` (train/runner.py).
@@ -286,11 +288,7 @@ class ScanRounds:
                 )
 
     def _span(self, name: str, step: int):
-        if self.spans is None:
-            from contextlib import nullcontext
-
-            return nullcontext()
-        return self.spans.span(name, step=int(step))
+        return span_of(self.spans, name, int(step))
 
     # -- aggregate stats (runner info line / bench) ------------------------
     def stats(self) -> dict:

@@ -58,6 +58,19 @@ stage that bound it. ``span_at`` records a span RETROACTIVELY from
 explicit perf_counter endpoints — the async engine only knows a
 cohort's buffer-residency interval when the cohort retires.
 
+The profiler's clock: every span recorded here, and every session phase
+when no recorder is attached (telemetry level 0), is also opened as a
+``jax.profiler.TraceAnnotation`` named ``fed/<name>`` with the round as
+its ``round`` argument (``host_span``). While a profiler trace is open
+(``--profile_dir``/``--profile_rounds``, the benchmark's ``--trace 1``)
+the spans land on its host plane, on the one clock the device ops are on,
+so a device gap can be laid against what the host was doing; with no trace
+open an annotation costs a flag test. Every optional-span site goes
+through ``span_of``, so the engines' lanes are annotated at level 0 too.
+``span_at`` records after the fact and so cannot be annotated. The ring,
+the fencing window and the dump below stay as they are, at level >= 1
+only.
+
 Format: ``{"schema_version", "kind": "spans", "displayTimeUnit",
 "exposed_collective_ms", "traceEvents": [{"name", "ph": "X", "ts",
 "dur", "pid", "tid", "args": {"step", "fenced"[, "collective"]
@@ -80,12 +93,47 @@ from collections import deque
 from contextlib import contextmanager
 from typing import Optional
 
+import jax
+
 from commefficient_tpu.utils.profiling import MIN_WARMUP_STEPS
 
 # ring bound on recorded events: a long run records ~4-6 events per round;
 # the most recent ~1.3k rounds of host phases are plenty for a post-mortem
 # and keep the dump a few hundred KB at worst
 MAX_EVENTS = 8192
+
+
+@contextmanager
+def host_span(name: str, step: Optional[int] = None):
+    """One host phase as the profiler sees it: ``fed/<name>`` on the host
+    plane of whatever trace is open, stamped with the round where the site
+    knows it. Yields None, like a disabled recorder's ``span``."""
+    args = {} if step is None else {"round": int(step)}
+    with jax.profiler.TraceAnnotation(f"fed/{name}", **args):
+        yield None
+
+
+def span_of(spans, name: str, step: Optional[int] = None, **kw):
+    """The one shape of every optional-span site (session, engines,
+    runner): the recorder's span where a train loop attached one, the bare
+    annotation where it did not (telemetry level 0)."""
+    if spans is None:
+        return host_span(name, step)
+    return spans.span(name, step=step, **kw)
+
+
+def wrap_iter(it, name: str = "data_load", span=host_span):
+    """Yield from ``it`` with each ``next()`` (the wait for the round
+    source) inside ``span(name)``: a recorder's ``span``, or the bare
+    annotation for a loop that was handed none."""
+    it = iter(it)
+    while True:
+        with span(name):
+            try:
+                item = next(it)
+            except StopIteration:
+                return
+        yield item
 
 
 class _SpanHandle:
@@ -189,26 +237,31 @@ class PhaseSpans:
         charges any part of it not covered by another span as exposed
         (un-overlapped) collective time. ``trace_id=``/``parent=`` stamp
         the owning round/cohort ids (schema v11; telemetry/trace.py mints
-        them). Yields None when disabled."""
-        if not self.enabled:
-            yield None
-            return
-        h = _SpanHandle()
-        h.fence_target = fence
-        t0 = time.perf_counter()
-        fenced = False
-        try:
-            yield h
-            if h.fence_target is not None and self.in_window:
-                from commefficient_tpu.utils.profiling import fence as _fence
+        them). Yields None when disabled. Either way the phase is also
+        one ``fed/<name>`` annotation in the profiler's trace
+        (``host_span``)."""
+        with host_span(name, self._step if step is None else step):
+            if not self.enabled:
+                yield None
+                return
+            h = _SpanHandle()
+            h.fence_target = fence
+            t0 = time.perf_counter()
+            fenced = False
+            try:
+                yield h
+                if h.fence_target is not None and self.in_window:
+                    from commefficient_tpu.utils.profiling import (
+                        fence as _fence,
+                    )
 
-                _fence(h.fence_target)
-                fenced = True
-        finally:
-            t1 = time.perf_counter()
-            self._record(name, t0, t1, step=step, fenced=fenced,
-                         collective=collective, trace_id=trace_id,
-                         parent=parent)
+                    _fence(h.fence_target)
+                    fenced = True
+            finally:
+                t1 = time.perf_counter()
+                self._record(name, t0, t1, step=step, fenced=fenced,
+                             collective=collective, trace_id=trace_id,
+                             parent=parent)
 
     def span_at(self, name: str, t0_s: float, t1_s: float,
                 step: Optional[int] = None, collective: bool = False,
@@ -251,18 +304,9 @@ class PhaseSpans:
         charges only the CONSUMING thread's wait to this span — which is
         the honest reading; the producer's own work lands on its own lane
         (``register_lane``) instead of being conflated into this track.
-        Transparent when disabled."""
-        if not self.enabled:
-            yield from it
-            return
-        it = iter(it)
-        while True:
-            with self.span(name):
-                try:
-                    item = next(it)
-                except StopIteration:
-                    return
-            yield item
+        A disabled recorder records nothing and still annotates, as
+        ``span`` does."""
+        return wrap_iter(it, name, self.span)
 
     # -- collective exposure -----------------------------------------------
     def collective_exposure_ms(self) -> float:

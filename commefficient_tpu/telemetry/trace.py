@@ -70,6 +70,60 @@ STAGES: Tuple[str, ...] = (
     "data", "h2d", "dispatch", "collective", "drain", "writeback", "idle",
 )
 
+# The one vocabulary of layer boundaries INSIDE the compiled round: every
+# ``jax.named_scope`` the round's code opens, with what it bounds. The
+# names are op metadata only (no operation added); a device trace carries
+# them wrapped by the transformation they were traced under
+# (``vmap(jvp(client_grad))`` forward, ``vmap(transpose(jvp(client_grad)))``
+# backward). tests/test_round_scopes.py holds the lowered rounds, the
+# source's ``named_scope`` literals and the benchmark's patterns to this
+# list; no name contains another, so a substring pattern stays exact. The
+# top-level scopes (``data_gather``, ``client_*``, ``encode``,
+# ``aggregate_tail``, the three decode markers, ``apply_update``) do not
+# overlap; ``estimate_all``/``topk_select``/``ef_resketch`` nest under
+# whichever decode marker is traced (the decode's remainder, the momentum
+# and error algebra on the banks, carries the marker's name alone).
+ROUND_SCOPES: Tuple[Tuple[str, str], ...] = (
+    ("data_gather", "in-graph gather of the round's batch from the "
+                    "device-resident dataset, and the device-side "
+                    "augmentation (parallel/api.py)"),
+    ("client_grad", "value_and_grad of the loss and the flat-gradient "
+                    "concat (parallel/round.py)"),
+    ("flat_grad_concat", "the [D] concat of the gradient leaves, inside "
+                         "client_grad; absent under sketch_fused_bwd"),
+    ("sketch_fused_bwd", "the sketch-fused backward: the gradient produced "
+                         "as a table by per-leaf taps"),
+    ("client_clip", "weight decay, clip_by_global_norm and DP noise on the "
+                    "flat [D] gradient"),
+    ("client_transmit", "local momentum and the compressor's per-client "
+                        "transmit rule"),
+    ("client_sum", "sum of the [w_loc, D] transmits, losses and aux over "
+                   "the shard's clients"),
+    ("encode", "comp.device_encode / encode_grad_table: the sketch "
+               "accumulate (identity for dense modes)"),
+    ("aggregate_tail", "the cross-worker psum / sparse all-reduce and the "
+                       "mean (make_aggregate_tail)"),
+    ("overlap_layerwise_psum", "the per-leaf-group psums of the layerwise "
+                               "overlap, inside aggregate_tail"),
+    ("sparse_allreduce", "the W*k-pair exchange of local_topk's sparse "
+                         "aggregation, inside aggregate_tail"),
+    ("server_decode_dense", "the dense server update: comp.server_update"),
+    ("sketch_decode_sharded", "the sketch's sharded server decode"),
+    ("sparse_aggregate_decode", "true_topk's server update on sharded "
+                                "state"),
+    ("estimate_all", "median-of-rows estimate of all D coordinates "
+                     "(ops/countsketch.py)"),
+    ("topk_select", "the selection: threshold bisection, lax.top_k / "
+                    "approx_max_k, compact_nonzero (ops/topk.py, unsketch*)"),
+    ("ef_resketch", "zeroing the heavy hitters out of the banks: "
+                    "sketch_vec(update), the dampening's estimate_at + "
+                    "sketch_sparse (compress/sketch.py)"),
+    ("apply_update", "params_vec - delta / the k-sparse scatter, and the "
+                     "client-state row scatter"),
+    ("telemetry_diag", "in-graph diagnostics; traced at telemetry level "
+                       ">= 1 only"),
+)
+
 # Priority order for exclusive assignment (idle is always the remainder).
 # Exposed collective first — it is the scarce signal the overlap work
 # (PR 16) exists to shrink; then the post-dispatch phases, then the

@@ -39,11 +39,11 @@ recovery").
 
 from __future__ import annotations
 
-from contextlib import nullcontext
 from functools import partial
 from typing import Optional
 
 from commefficient_tpu.data import prefetch
+from commefficient_tpu.telemetry.spans import span_of, wrap_iter
 from commefficient_tpu.utils import TableLogger, Timer, piecewise_linear_lr
 from commefficient_tpu.utils.logging import drain_round_metrics
 
@@ -90,9 +90,11 @@ def _sync_epoch_rounds(cfg, session, sampler, lr_fn, spans, profiler,
         if use_idx
         else prefetch(sampler.epoch(epoch))
     )
-    if spans is not None:
-        # times each next() — the data-load/prefetch-wait phase
-        rounds = spans.wrap_iter(rounds, "data_load")
+    # each next() is the data-load/prefetch-wait phase: recorded by the
+    # recorder where there is one, and either way a fed/data_load
+    # annotation in whatever profiler trace is open
+    rounds = (spans.wrap_iter(rounds, "data_load") if spans is not None
+              else wrap_iter(rounds, "data_load"))
     for round_idx, item in enumerate(rounds):
         s = epoch * steps_per_epoch + round_idx
         if s < start_step:
@@ -304,9 +306,8 @@ def run_train_loop(cfg, session, sampler, hooks: WorkloadHooks,
 
     def span(name, trace_id=None):
         # one shape for every optional-span site (drain / checkpoint /
-        # snapshot) — no-op context when spans are off
-        return (spans.span(name, trace_id=trace_id)
-                if spans is not None else nullcontext())
+        # snapshot) — the bare fed/<name> annotation when spans are off
+        return span_of(spans, name, trace_id=trace_id)
 
     def ckpt_save(force=False):
         with span("checkpoint"):

@@ -36,12 +36,25 @@ def configure_compile_cache() -> str:
     JAX reads the variable itself and nothing is set in code. Otherwise
     the cache is the fixed in-checkout ``COMPILE_CACHE_DIR``, and every
     compile is kept (JAX's default skips those under one second, which
-    would make "the warm run wrote nothing" depend on timing noise)."""
+    would make "the warm run wrote nothing" depend on timing noise).
+
+    Either way the ops' names are part of the cache key, and nothing else
+    of their metadata is. JAX's default key leaves all metadata out, so a
+    round whose ``named_scope``s changed and whose computation did not
+    would load the executable another commit compiled, and a profiler
+    trace would show that commit's names: the per-layer metrics read those
+    names (telemetry.trace.ROUND_SCOPES). With the metadata in the key as
+    JAX writes it, every edit that moves a traced line would recompile the
+    round instead; so the Python tracebacks are left out of the lowered
+    locations (an op's metadata is then its ``op_name`` alone: a device
+    trace shows the scope path of an op and no longer its source line)."""
+    import jax
+
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+    jax.config.update("jax_traceback_in_locations_limit", 0)
     placed = os.environ.get(COMPILE_CACHE_ENV)
     if placed:
         return placed
-    import jax
-
     jax.config.update("jax_compilation_cache_dir", COMPILE_CACHE_DIR)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)

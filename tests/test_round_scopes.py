@@ -1,0 +1,137 @@
+"""The names the compiled round carries (telemetry.trace.ROUND_SCOPES).
+
+The round's layer boundaries are ``jax.named_scope``s: op metadata, no
+operation. The benchmark's per-layer metrics read them back from a device
+trace by pattern, so what is pinned here, on the program
+``train_round_indices`` dispatches at telemetry level 0, is: every name a
+mode traces is in its compiled round, every name it does not trace is not,
+no name contains another (a substring pattern stays exact), the source
+opens no scope the list does not hold, and forward and backward are told
+apart by the wrapping alone.
+"""
+
+import os
+import re
+
+import jax.numpy as jnp
+import pytest
+from test_device_data import _mlp_loss, _toy_ds, augment_batch
+
+import commefficient_tpu
+from commefficient_tpu.data import FedSampler
+from commefficient_tpu.parallel import FederatedSession, make_mesh
+from commefficient_tpu.telemetry.trace import ROUND_SCOPES
+from commefficient_tpu.utils.config import Config
+
+NAMES = tuple(name for name, _ in ROUND_SCOPES)
+BASE = dict(num_clients=16, num_workers=8, num_devices=1, local_batch_size=4,
+            weight_decay=5e-4, max_grad_norm=1.0, seed=1)
+MODES = {
+    "sketch": dict(mode="sketch", error_type="virtual", virtual_momentum=0.9,
+                   k=64, num_rows=3, num_cols=2048, topk_method="threshold"),
+    "uncompressed": dict(mode="uncompressed"),
+    # per-client state: the transmit rule and the client-row scatter
+    "local_topk": dict(mode="local_topk", error_type="local", k=64,
+                       local_momentum=0.9, virtual_momentum=0.9,
+                       topk_method="threshold"),
+}
+EVERY_ROUND = {"data_gather", "client_grad", "flat_grad_concat", "client_clip",
+               "client_sum", "aggregate_tail", "server_decode_dense",
+               "apply_update"}
+TRACED = {
+    "sketch": EVERY_ROUND | {"encode", "estimate_all", "topk_select",
+                             "ef_resketch"},
+    # device_encode is the identity and the server keeps no bank to decode
+    "uncompressed": EVERY_ROUND,
+    "local_topk": EVERY_ROUND | {"client_transmit", "topk_select"},
+}
+
+
+@pytest.fixture(scope="module")
+def op_names():
+    """mode -> the ``op_name`` metadata of its compiled index round."""
+    out = {}
+    for mode, kw in MODES.items():
+        cfg = Config(**kw, **BASE)
+        assert cfg.telemetry_level == 0
+        params, loss_fn = _mlp_loss()
+        ds = _toy_ds(num_clients=16)
+        session = FederatedSession(cfg, params, loss_fn, mesh=make_mesh(1))
+        sampler = FedSampler(ds, num_workers=8, local_batch_size=4, seed=1,
+                             augment=augment_batch)
+        session.attach_data(ds.data, augment_batch)
+        ids, idx, plan = sampler.sample_round_indices(0)
+        cids, idxd, pl = session.stage_round_indices(ids, idx, plan)
+        text = session._round_idx_fn.lower(
+            session.state, session._dev_data, jnp.asarray(cids), idxd, pl,
+            jnp.float32(0.1), env=(),
+        ).compile().as_text()
+        out[mode] = set(re.findall(r'op_name="([^"]*)"', text))
+    return out
+
+
+def _carries(names, scope):
+    rx = re.compile(r"\b" + scope + r"\b")
+    return any(rx.search(n) for n in names)
+
+
+@pytest.mark.parametrize("scope", NAMES)
+@pytest.mark.parametrize("mode", sorted(MODES))
+def test_round_carries_exactly_the_scopes_it_traces(op_names, mode, scope):
+    assert _carries(op_names[mode], scope) == (scope in TRACED[mode]), (
+        f"{mode}: {scope!r} is "
+        f"{'missing from' if scope in TRACED[mode] else 'present in'} "
+        "the compiled round")
+
+
+@pytest.mark.parametrize("mode", sorted(MODES))
+def test_forward_and_backward_are_told_apart_by_the_wrapping(op_names, mode):
+    # benchmark/layers/model.bwd_s_per_round.json reads the second pattern
+    assert any(re.search(r"client_grad\)?/jvp\(", n) for n in op_names[mode])
+    assert any(re.search(r"client_grad\)?/transpose\(", n)
+               for n in op_names[mode])
+
+
+@pytest.mark.parametrize("mode", sorted(MODES))
+def test_nothing_but_the_step_counter_is_left_unnamed(op_names, mode):
+    """Every op the program traces sits under a name of the list, but for
+    ``state.step + 1`` and, with per-client state, the gather of the
+    participants' rows at the top of the round (the list has no name for
+    it; ``round.unscoped_s_per_round`` is where a cell would read it).
+    (Parameters and the reducers' own computations carry no ``jit(...)``
+    path and are not the program's.)"""
+    rx = re.compile("|".join(NAMES))
+    bare = {n for n in op_names[mode]
+            if n.startswith("jit(") and not rx.search(n)}
+    allowed = {"jit(wrapped)/add"}
+    if mode == "local_topk":
+        allowed |= {"jit(wrapped)/gather", "jit(wrapped)/lt",
+                    "jit(wrapped)/select_n"}
+    assert bare <= allowed, bare
+
+
+def test_decode_scopes_nest_under_the_decode_marker(op_names):
+    inner = ("estimate_all", "topk_select", "ef_resketch")
+    for n in op_names["sketch"]:
+        if any(re.search(r"\b" + s + r"\b", n) for s in inner):
+            assert "server_decode_dense/" in n, n
+
+
+@pytest.mark.parametrize("name", NAMES)
+def test_no_name_contains_another(name):
+    assert re.fullmatch(r"[a-z][a-z0-9_]*", name)
+    assert [o for o in NAMES if o != name and name in o] == []
+
+
+def test_source_opens_only_scopes_of_the_list():
+    root = os.path.dirname(commefficient_tpu.__file__)
+    opened = set()
+    for dirpath, _dirs, files in os.walk(root):
+        for f in files:
+            if f.endswith(".py"):
+                with open(os.path.join(dirpath, f)) as fh:
+                    opened |= set(re.findall(
+                        r'named_scope\(\s*"([^"]+)"\s*\)', fh.read()))
+    opened |= {"sketch_decode_sharded", "sparse_aggregate_decode"}  # round.py
+    # picks one of the two by the plan, through a variable
+    assert opened == set(NAMES)
