@@ -21,7 +21,11 @@ from commefficient_tpu.ops.countsketch import (
     sketch_vec,
     table_sqnorm_estimate,
 )
-from commefficient_tpu.ops.topk import compact_nonzero, topk_threshold_sharded
+from commefficient_tpu.ops.topk import (
+    compact_nonzero,
+    compact_nonzero_tree,
+    topk_threshold_sharded,
+)
 
 
 @register("sketch")
@@ -123,15 +127,18 @@ class SketchCompressor(Compressor):
         rho = cfg.virtual_momentum
         agg, momentum, error = map(self._up, (agg, momentum, error))
         m = rho * momentum + agg if rho > 0 else agg
+        hh = None  # the update's <= k (idx, val) pairs, compacted once
         if cfg.error_type == "virtual":
             e = error + lr * m
             update = self.unsketch(spec, e, cfg.k)  # dense, <= k nonzeros
-            # zero HH (linearity); the interior re-sketch accumulates at
-            # f32 regardless of the storage dtype (_spec_acc) so the EF
-            # bank's algebra never pays a bf16 round-trip mid-round
-            # (ef_resketch: telemetry.trace.ROUND_SCOPES, op metadata only)
+            # zero HH (linearity) at k scale: the <= k pairs the selection
+            # kept, scatter-added into a fresh f32 table (_spec_acc: the
+            # EF bank's algebra never pays a bf16 round-trip mid-round),
+            # not one more [D] sketch_vec pass over a vector that is zero
+            # everywhere else (ef_resketch: telemetry.trace.ROUND_SCOPES)
             with jax.named_scope("ef_resketch"):
-                e = e - sketch_vec(self._spec_acc, update)
+                hh = compact_nonzero_tree(update, cfg.k)
+                e = e - sketch_sparse(self._spec_acc, *hh)
             if cfg.error_decay != 1.0:
                 e = cfg.error_decay * e  # d/c-envelope mitigation
             delta = update
@@ -142,14 +149,13 @@ class SketchCompressor(Compressor):
         if dampen and rho > 0:
             # zero the momentum sketch at HH coords (fed_aggregator
             # ~L380-440): estimate m at the update's <= k-coordinate
-            # support and subtract the sketch of those point values.
-            # estimate_at + sketch_sparse replace the former full-[D]
-            # estimate_all + dense sketch_vec (identical semantics — the
-            # gather estimate is bit-equal to the matmul path on CPU and
+            # support (the error feedback's pairs, compacted once) and
+            # subtract the sketch of those point values — the gather
+            # estimate is bit-equal to the matmul path on CPU and
             # sketch_sparse is the same hash mapping; pinned by
-            # tests/test_sketch_decode.py's dampening regression).
-            hh_idx, hh_val = compact_nonzero(update, cfg.k)
+            # tests/test_sketch_decode.py's dampening regression.
             with jax.named_scope("ef_resketch"):
+                hh_idx, hh_val = hh or compact_nonzero_tree(update, cfg.k)
                 m_at_hh = jnp.where(hh_val != 0,
                                     estimate_at(spec, m, hh_idx), 0.0)
                 m = m - sketch_sparse(spec, hh_idx, m_at_hh)

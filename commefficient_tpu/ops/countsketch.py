@@ -9,8 +9,12 @@ than translated from CUDA.
 Why not the classic layout: the reference scatters each coordinate to a
 random bucket (``scatter_add``) and gathers random buckets back — on GPUs
 those are atomic-add/gather at memory bandwidth, but the TPU is a
-contiguous-vector machine with no fast random access (measured on v5e:
-a 50k-element scatter into 6.5M costs ~24 ms — microseconds of matmul).
+contiguous-vector machine with no fast random access at ``[D]`` scale: a
+scatter or gather of every coordinate is the slow path. (A k-scale one is
+not, on the installed JAX 0.9.0: five 50k-update scatter-adds into
+5M-column rows, hashes included, measured 5.4 ms on a v5e, 22 ns an
+update — scripts/resketch_probe.py, PR 27. The round-3 figure this
+docstring carried, ~24 ms for one 50k scatter into 6.5M, no longer holds.)
 
 Layout (this module, v5 — "banded"):
   * Coordinates are split into CHUNKS of ``m``. Chunk q hashes its
@@ -881,10 +885,14 @@ def sketch_sparse(spec: CountSketch, idx: jnp.ndarray, vals: jnp.ndarray) -> jnp
     Same hash mapping as ``sketch_vec`` of the dense materialization (see
     ``_row_cols_signs``) via O(r·k) scatter-adds — bit-identical on CPU;
     on TPU the dense path's matmul carries ~2^-8 relative rounding (module
-    docstring precision caveat). NB on
-    TPU a dense ``sketch_vec`` matmul often beats this for k ≳ 10^4 —
-    scatter is the slow path on this hardware; this exists for small-k and
-    host-side uses. Coordinates may repeat; repeats accumulate.
+    docstring precision caveat) while this is exact float32 (2.4e-7 from
+    the float64 sums on table entries up to 4.8). Measured on a v5e at
+    d=124M, 5 x 5M, k=50k (scripts/resketch_probe.py, PR 27): 5.4 ms, the
+    hashes 2.2 of it, against 100.6 ms for ``sketch_vec`` of the same
+    k-sparse vector and 18.0 ms for the same table as five one-hot
+    ``[c/2048, k] x [k, 2048]`` MXU matmuls — so a top-k's pairs are
+    sketched here, and ``sketch_vec`` is for vectors dense at ``[d]``
+    scale. Coordinates may repeat; repeats accumulate.
     """
     vals = vals.astype(jnp.float32)
 

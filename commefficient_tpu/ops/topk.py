@@ -41,8 +41,8 @@ def topk_dense(v: jnp.ndarray, k: int, *, approx: bool = False) -> jnp.ndarray:
 def topk_threshold_dense(v: jnp.ndarray, k: int, iters: int = 32) -> jnp.ndarray:
     """Dense top-≤k by magnitude via binary-searched threshold — the TPU
     fast path: no sort (lax.top_k is ~40 ms at d=6.5M on v5e) and no
-    scatter (~24 ms for 50k updates), just ``iters`` vectorized passes over
-    |v| (~33 µs each at d=6.5M).
+    [d]-sized scatter, just ``iters`` vectorized passes over |v| (~33 µs
+    each at d=6.5M).
 
     Selects ``|v| >= t`` for the smallest tested ``t`` whose selection count
     is ≤ k, so the result has AT MOST k nonzeros; exact ties at the
@@ -114,10 +114,12 @@ def compact_nonzero(v: jnp.ndarray, k: int):
     decode and the sparse telemetry paths are built on.
 
     No sort and no len(v)-sized scatter (both are the TPU slow paths —
-    ``lax.top_k`` measures ~40 ms at d=6.5M, a 50k scatter ~24 ms): one
-    ``cumsum`` pass over the mask gives each selected element its output
-    slot, and ``searchsorted`` over that monotone prefix-count inverts the
-    mapping with kb vectorized binary searches (gathers, not scatters).
+    ``lax.top_k`` measures ~40 ms at d=6.5M): one ``cumsum`` pass over the
+    mask gives each selected element its output slot, and ``searchsorted``
+    over that monotone prefix-count inverts the mapping with kb vectorized
+    binary searches (gathers, not scatters). At len(v)=124M, k=50k that is
+    51.3 ms on a v5e (PR 27: the cumsum 31.1, the searchsorted 23.4);
+    ``compact_nonzero_tree`` below gives the same buffers in 4.1.
     Consumers rely on the padding contract: padded entries carry val==0.0
     so a downstream ``.at[idx].add(val)`` / ``sketch_sparse`` treats them
     as no-ops, and masks derived from ``val != 0`` drop them from norms.
@@ -140,6 +142,73 @@ def compact_nonzero(v: jnp.ndarray, k: int):
     idx = jnp.minimum(idx, n - 1).astype(jnp.int32)
     valid = jnp.arange(kb, dtype=jnp.int32) < total
     return jnp.where(valid, idx, 0), jnp.where(valid, v[idx], 0.0)
+
+
+_LANES = 128  # fan-out of compact_nonzero_tree: one TPU vector row a node
+
+
+def compact_nonzero_tree(v: jnp.ndarray, k: int):
+    """``compact_nonzero``'s contract and values, bit for bit, for a
+    ``[D]``-scale vector: the dense decode's zero-HH re-sketch compacts
+    the 124M-coordinate ``update`` with it every round. Carries no scope
+    of its own; the caller names it (``ef_resketch``).
+
+    ``compact_nonzero`` pays a ``[D]`` int32 ``cumsum`` and 27 dependent
+    gathers of k probes over it. Here the nonzero counts of 128-wide rows
+    are summed up a 128-ary tree (one pass over ``v`` at bandwidth, then
+    arrays of D/128, D/128^2, ... counts, each row holding the running
+    count of its children), and slot j walks down it: at every level one
+    ``[k, 128]`` row gather and a compare-and-count pick the child that
+    holds the (j+1)-th nonzero; the last level reads the row of ``v``
+    itself. Measured on a v5e at D = 124,444,417, k = 50,000
+    (scripts/resketch_probe.py, PR 27): 4.1 ms against 51.3 ms (31.1 the
+    cumsum, 23.4 the searchsorted).
+    """
+    n, L = v.shape[0], _LANES
+    # lint: allow[traced-purity] k is a static Python int by contract
+    kb = min(int(k), n)
+    lane = jnp.arange(L, dtype=jnp.int32)
+    nrows = -(-n // L)
+    rows = jnp.pad(v, (0, nrows * L - n)).reshape(nrows, L)
+    # bottom-up: per level, in each row of L children, the inclusive
+    # running count of their nonzeros
+    cnt = jnp.sum(rows != 0, axis=1, dtype=jnp.int32)
+    levels = []
+    while True:
+        m = cnt.shape[0]
+        mr = -(-m // L)
+        incl = jnp.cumsum(jnp.pad(cnt, (0, mr * L - m)).reshape(mr, L), axis=1)
+        levels.append(incl)
+        cnt = incl[:, -1]
+        if mr == 1:
+            break
+    total = cnt[0]
+    def enter(incl, rank):
+        """The lane whose running count first exceeds ``rank``, and the
+        rank left inside it."""
+        at = jnp.minimum(jnp.sum(incl <= rank[:, None], axis=1), L - 1)
+        before = jnp.sum(jnp.where(lane == at[:, None] - 1, incl, 0), axis=1)
+        return at, rank - before
+
+    # top-down: slot j wants the nonzero of rank j
+    rank = jnp.arange(kb, dtype=jnp.int32)
+    node = jnp.zeros((kb,), jnp.int32)
+    below = [t.shape[0] for t in levels[-2::-1]] + [nrows]
+    for incl, rows_below in zip(reversed(levels), below):
+        child, rank = enter(incl[node], rank)
+        # a slot past the last nonzero walks off the tree: kept in bounds
+        # here, masked below
+        node = jnp.minimum(node * L + child, rows_below - 1)
+    row = rows[node]
+    # running count inside the row on the MXU: 0/1 operands and sums <= 128
+    # are exact in bfloat16 x bfloat16 -> float32
+    tri = (lane[:, None] <= lane[None, :]).astype(jnp.bfloat16)
+    incl = jnp.dot((row != 0).astype(jnp.bfloat16), tri,
+                   preferred_element_type=jnp.float32).astype(jnp.int32)
+    at, _ = enter(incl, rank)
+    val = jnp.sum(jnp.where(lane == at[:, None], row, 0), axis=1)
+    valid = jnp.arange(kb, dtype=jnp.int32) < total
+    return jnp.where(valid, node * L + at, 0), jnp.where(valid, val, 0)
 
 
 def mask_out_indices(v: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:

@@ -115,9 +115,10 @@ ROUND_SCOPES: Tuple[Tuple[str, str], ...] = (
                      "(ops/countsketch.py)"),
     ("topk_select", "the selection: threshold bisection, lax.top_k / "
                     "approx_max_k, compact_nonzero (ops/topk.py, unsketch*)"),
-    ("ef_resketch", "zeroing the heavy hitters out of the banks: "
-                    "sketch_vec(update), the dampening's estimate_at + "
-                    "sketch_sparse (compress/sketch.py)"),
+    ("ef_resketch", "zeroing the heavy hitters out of the banks: the "
+                    "update's <= k pairs (compact_nonzero_tree on the dense "
+                    "decode) through sketch_sparse, the dampening's "
+                    "estimate_at + sketch_sparse (compress/sketch.py)"),
     ("apply_update", "params_vec - delta / the k-sparse scatter, and the "
                      "client-state row scatter"),
     ("telemetry_diag", "in-graph diagnostics; traced at telemetry level "

@@ -117,6 +117,18 @@ def test_decode_scopes_nest_under_the_decode_marker(op_names):
             assert "server_decode_dense/" in n, n
 
 
+def test_resketch_compaction_is_named_ef_resketch_and_nothing_else(op_names):
+    """The dense decode compacts the update's pairs inside ``ef_resketch``
+    (``ops/topk.py::compact_nonzero_tree`` opens no scope of its own), so
+    ``compress.resketch_s_per_round`` reads the compaction (its row
+    counts, row gathers and in-row dot) with the scatter-add, and
+    ``compress.topk_s_per_round`` reads none of it."""
+    under = {n for n in op_names["sketch"] if re.search(r"\bef_resketch\b", n)}
+    assert not [n for n in under if "topk_select" in n]
+    for prim in ("reduce_sum", "gather", "dot_general", "scatter-add"):
+        assert any(n.endswith("ef_resketch/" + prim) for n in under), prim
+
+
 @pytest.mark.parametrize("name", NAMES)
 def test_no_name_contains_another(name):
     assert re.fullmatch(r"[a-z][a-z0-9_]*", name)
