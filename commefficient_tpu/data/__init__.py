@@ -14,6 +14,7 @@ from commefficient_tpu.data.cifar import (
 )
 from commefficient_tpu.data.emnist import load_fed_emnist
 from commefficient_tpu.data.imagenet import load_fed_imagenet
+from commefficient_tpu.data.fedtext import load_fed_text
 from commefficient_tpu.data.personachat import (
     load_fed_personachat,
     build_input_from_segments,
@@ -31,6 +32,7 @@ __all__ = [
     "load_fed_emnist",
     "load_fed_imagenet",
     "load_fed_personachat",
+    "load_fed_text",
     "build_input_from_segments",
     "special_ids",
     "vocab_with_specials",

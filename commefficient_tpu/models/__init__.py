@@ -1,4 +1,4 @@
-"""Model zoo (L1): ResNet-9, FixupResNet, GPT-2 — all flax, all pure params.
+"""Model zoo (L1): ResNet-9, FixupResNet, GPT-2, Laguna — all flax, all pure params.
 
 The reference's models are plain ``nn.Module`` classes driven by a
 ``compute_loss(model, batch)`` convention (SURVEY.md §1 L1). Here every model
@@ -20,7 +20,21 @@ from commefficient_tpu.models.losses import (
     softmax_cross_entropy,
     classification_loss,
     gpt2_double_heads_loss,
+    causal_lm_loss,
 )
+
+_LAGUNA = ("LagunaConfig", "LagunaLM", "laguna_tiny", "laguna_xs2")
+
+
+def __getattr__(name):
+    # Laguna brings the Pallas library's attention and grouped-matmul modules
+    # with it: imported when first asked for, so the other entries never load them
+    if name in _LAGUNA:
+        from commefficient_tpu.models import laguna
+
+        return getattr(laguna, name)
+    raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
+
 
 __all__ = [
     "ResNet9",
@@ -29,7 +43,12 @@ __all__ = [
     "GPT2Config",
     "GPT2DoubleHeads",
     "gpt2_tiny_config",
+    "LagunaConfig",
+    "LagunaLM",
+    "laguna_tiny",
+    "laguna_xs2",
     "softmax_cross_entropy",
     "classification_loss",
     "gpt2_double_heads_loss",
+    "causal_lm_loss",
 ]

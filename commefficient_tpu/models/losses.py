@@ -7,6 +7,20 @@ convention (SURVEY.md §1 L1): cv workloads return (loss, #correct)
 convention is a pure function ``loss_fn(params, batch, rng) -> (loss,
 metrics_dict)`` so it sits directly under ``jax.grad`` inside the jitted
 round.
+
+``compute_dtype`` by model (ROADMAP R0): ``mixed`` is what each model's
+modules make of ``dtype=bfloat16``, and it is not the same thing in each.
+ResNet-9 and GPT-2 keep bfloat16 activations between layers. The Laguna
+decoder (``models/laguna.py``) rounds only the operands of its products to
+bfloat16 and accumulates in float32: the residual stream, RMSNorm, the
+router (its product at ``Precision.HIGHEST``, scores, top-k, weights),
+attention's softmax statistics, the output gate, the logits and this loss
+stay float32, and each weight's gradient is rounded to bfloat16 once, where
+it leaves its product. On the chip at the cell's size that read
+``grad_1_diff`` 0.0201-0.0210 on each of the first eight seeds against the float32
+reference, most of it top-8 boundaries that a rounded product upstream moves,
+where the reference with fp8 operands reads 0.204 or more (PERF.md section
+2, ``laguna_uncompressed``; my chip runs, PR 29).
 """
 
 from __future__ import annotations
@@ -163,5 +177,26 @@ def gpt2_double_heads_loss(apply_fn, lm_coef: float = 1.0, mc_coef: float = 1.0,
             "lm_loss_sum": lm_sum,
             "token_count": tok_count,
         }
+
+    return loss_fn
+
+
+def causal_lm_loss(apply_fn, compute_dtype=None):
+    """Build the causal-LM loss: batch = {"input_ids": [B,T], "lm_labels":
+    [B,T] (-100 masked)}; mean next-token NLL over the labels kept.
+    ``apply_fn(params, input_ids, lm_labels) -> ((nll sum, labels kept),
+    counters)``: the model takes the labels so that it can rematerialize its
+    head with the cross-entropy (``softmax_cross_entropy_sum``, shifted by
+    one). The model's counters (``moe/*``) ride in the aux beside the
+    token-weighted pair ``evaluate()`` sums."""
+    cd = _resolve_compute_dtype(compute_dtype)
+
+    def loss_fn(params, batch, rng=None):
+        if cd is not None:
+            params = _cast_floats(params, cd)
+        (lm_sum, tok_count), counters = apply_fn(params, batch["input_ids"], batch["lm_labels"])
+        loss = lm_sum / jnp.maximum(tok_count, 1.0)
+        return loss, {"lm_loss": loss, "lm_loss_sum": lm_sum,
+                      "token_count": tok_count, **counters}
 
     return loss_fn

@@ -125,6 +125,35 @@ ROUND_SCOPES: Tuple[Tuple[str, str], ...] = (
                        ">= 1 only"),
 )
 
+# The scopes a model opens inside ``client_grad`` (models/laguna.py, and the
+# loss beside it): the same vocabulary and the same tests as ROUND_SCOPES
+# (no name contains another, here or across the two lists; the source opens
+# no scope outside them), kept apart because every one nests under
+# ``client_grad``: the benchmark's ``round.unscoped_s_per_round`` pattern is
+# ROUND_SCOPES and needs none of these. A trace carries them wrapped like
+# ``client_grad`` itself, and again by ``checkpoint`` / ``rematted_computation``
+# where a block is recomputed in the backward pass.
+MODEL_SCOPES: Tuple[Tuple[str, str], ...] = (
+    ("moe_route", "the router: float32 product, sigmoid, top-k, weights"),
+    ("moe_dispatch", "the sort of a client's assignments by held slot, "
+                     "the held slots' counts, and the gather of the held "
+                     "experts' rows"),
+    ("moe_experts", "the grouped products of the held experts and the "
+                    "activation between them"),
+    ("moe_combine", "the routing weight on each row and the scatter-add "
+                    "back to the tokens"),
+    ("attn_full", "the block-sparse attention kernels of a full causal "
+                  "layer"),
+    ("attn_window", "the block-sparse attention kernels of a "
+                    "sliding-window layer"),
+    ("lm_head", "the final norm, the logits over the vocabulary held and "
+                "the cross-entropy"),
+    ("attn_proj", "the attention's dense products: query, key, value, the "
+                  "output gate and the output projection"),
+    ("mlp_dense", "the dense feed-forward of the leading layer and the "
+                  "shared expert of a routed one (SwiGLU)"),
+)
+
 # Priority order for exclusive assignment (idle is always the remainder).
 # Exposed collective first — it is the scarce signal the overlap work
 # (PR 16) exists to shrink; then the post-dispatch phases, then the

@@ -1,0 +1,183 @@
+"""lm_train — causal language modelling on a packed federated text set.
+
+The third workload entry, beside ``cv_train`` and ``gpt2_train``, over the
+same runner, session and sampler: a decoder-only LM (``--model laguna_xs2``:
+one chip's share of Laguna-XS.2, ``models/laguna.py``; ``laguna_tiny`` for
+the CPU) trained on ``--dataset_name fedtext`` (``data/fedtext.py``: packed
+documents, one shard of rows per client), next-token loss, eval reporting
+nll -> perplexity.
+
+  python -m commefficient_tpu.train.lm_train --mode uncompressed \
+      --num_workers 4 --local_batch_size 2 --max_seq_len 2048   # the chip
+  python -m commefficient_tpu.train.lm_train --model laguna_tiny \
+      --max_seq_len 128 --num_clients 8 --num_workers 2 --num_epochs 1  # CPU
+
+``--max_seq_len`` is a multiple of 128 (the attention kernel's lanes).
+"""
+
+from __future__ import annotations
+
+from typing import Optional
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from commefficient_tpu.data import FedSampler, load_fed_text
+from commefficient_tpu.models import causal_lm_loss
+from commefficient_tpu.models.laguna import PRESETS, LagunaLM
+from commefficient_tpu.models.losses import IGNORE_INDEX, model_dtype
+from commefficient_tpu.parallel import FederatedSession
+from commefficient_tpu.utils import Config, MetricsWriter, TableLogger, parse_args
+from commefficient_tpu.utils.logging import make_logdir
+
+DEFAULTS = dict(model="laguna_xs2", dataset_name="fedtext", num_clients=64,
+                local_batch_size=2, max_seq_len=2048, max_grad_norm=1.0, lr_scale=0.01)
+
+
+def mask_lm(batch, row_mask):
+    """Eval's padded tail rows carry no label."""
+    return {**batch, "lm_labels": jnp.where(row_mask[:, None], batch["lm_labels"],
+                                            IGNORE_INDEX)}
+
+
+def build_model_and_data(cfg: Config):
+    """``(train, test, lcfg, model, params, loss_fn)``."""
+    if cfg.model not in PRESETS:
+        raise ValueError(f"unknown lm model {cfg.model!r} ({' | '.join(PRESETS)})")
+    if cfg.dataset_name != "fedtext":
+        raise ValueError(f"unknown lm dataset {cfg.dataset_name!r} (fedtext)")
+    lcfg = PRESETS[cfg.model](dtype=model_dtype(cfg.compute_dtype))
+    train, test = load_fed_text(num_clients=cfg.num_clients, seq_len=cfg.max_seq_len,
+                                vocab=lcfg.vocab_held, seed=cfg.seed)
+    model = LagunaLM(lcfg)
+    # shapes only: the real init would run every kernel once on zeros
+    shapes = jax.eval_shape(model.init, jax.random.key(cfg.seed),
+                            jnp.zeros((1, cfg.max_seq_len), jnp.int32))
+    params = _init_params(shapes, cfg.seed, lcfg.initializer_range)
+    return train, test, lcfg, model, params, causal_lm_loss(
+        model.apply, compute_dtype=cfg.compute_dtype)
+
+
+def _init_params(shapes, seed: int, std: float):
+    """Normal(0, std) for every matrix, ones for every ``scale``: what the
+    modules' own initializers draw, without tracing the model: one draw of
+    all D numbers, cut into the leaves (a draw a leaf compiles for a minute
+    on the chip)."""
+    leaves, treedef = jax.tree_util.tree_flatten_with_path(shapes)
+    sizes = [int(np.prod(a.shape)) for _, a in leaves]
+    starts = np.cumsum([0] + sizes)
+
+    def draw(key):
+        flat = std * jax.random.normal(key, (int(starts[-1]),), jnp.float32)
+        return [jnp.ones(a.shape, a.dtype) if getattr(path[-1], "key", "") == "scale"
+                else flat[at:at + n].reshape(a.shape).astype(a.dtype)
+                for (path, a), at, n in zip(leaves, starts, sizes)]
+
+    return jax.tree.unflatten(treedef, jax.jit(draw)(jax.random.key(seed)))
+
+
+class _LmHooks:
+    """The LM workload's plug-ins for the shared runner (runner.WorkloadHooks)."""
+
+    def __init__(self, cfg, session, test_ds, eval_batch_size):
+        self.cfg, self.session = cfg, session
+        self.test_ds, self.eval_batch_size = test_ds, eval_batch_size
+
+    def new_accumulator(self):
+        return {"loss": 0.0, "held": 0.0, "dropped": 0.0}
+
+    def accumulate(self, acc, loss, metrics):
+        acc["loss"] += loss
+        acc["held"] += float(metrics.get("moe/held_assignments", 0.0))
+        acc["dropped"] += float(metrics.get("moe/dropped", 0.0))
+
+    def evaluate(self):
+        return evaluate_ppl(self.session, self.test_ds, self.eval_batch_size)
+
+    def epoch_row(self, *, epoch, lr, acc, val, train_time, val_time, steps_per_epoch):
+        return {
+            "epoch": epoch + 1, "lr": lr,
+            "train_loss": acc["loss"] / steps_per_epoch,
+            "held_per_round": acc["held"] / steps_per_epoch,
+            "dropped": acc["dropped"],
+            "val_nll": val["nll"], "val_ppl": val["ppl"],
+            "train_time": train_time, "val_time": val_time,
+        }
+
+    def write_val(self, writer, val, step):
+        writer.scalar("val/nll", val["nll"], step)
+        writer.scalar("val/ppl", val["ppl"], step)
+
+    def on_epoch_end(self, epoch, val):
+        pass
+
+
+def evaluate_ppl(session: FederatedSession, test_ds, batch_size: int):
+    """Token-weighted nll over the test rows -> perplexity."""
+    out = session.evaluate(test_ds.eval_batches(batch_size))
+    nll = out["lm_loss_sum"] / max(out["token_count"], 1.0)
+    return {"nll": nll, "ppl": float(np.exp(min(nll, 20.0))), "loss": out["loss"]}
+
+
+def train_loop(cfg: Config, session: FederatedSession, sampler: FedSampler, test_ds,
+               writer: Optional[MetricsWriter] = None, table: Optional[TableLogger] = None,
+               eval_batch_size: int = 2, checkpointer=None):
+    from commefficient_tpu.train.runner import run_train_loop
+
+    return run_train_loop(
+        cfg, session, sampler, _LmHooks(cfg, session, test_ds, eval_batch_size),
+        writer=writer, table=table, checkpointer=checkpointer,
+        generated_by="train/lm_train",
+    )
+
+
+def build_session_and_sampler(cfg: Config, train, params, loss_fn):
+    session = FederatedSession(cfg, params, loss_fn, mask_batch=mask_lm)
+    sampler = FedSampler(train, num_workers=cfg.num_workers,
+                         local_batch_size=cfg.sampler_batch_size, seed=cfg.seed)
+    # the token rows live in HBM (8 MB); rounds ship only [W, B] indices
+    session.maybe_attach_data(train, sampler)
+    return session, sampler
+
+
+def main(argv=None, **overrides):
+    from commefficient_tpu import native
+    from commefficient_tpu.control import controller_header
+    from commefficient_tpu.multihost import initialize_multihost
+    from commefficient_tpu.parallel.mesh import initialize_distributed
+    from commefficient_tpu.resilience import EXIT_PREEMPTED, PreemptShutdown
+    from commefficient_tpu.utils.checkpoint import FedCheckpointer
+    from commefficient_tpu.utils.platform import configure_compile_cache
+
+    configure_compile_cache()
+    cfg = parse_args(argv, defaults=DEFAULTS, **overrides)
+    if not initialize_multihost(cfg):
+        initialize_distributed()
+    train, test, lcfg, _model, params, loss_fn = build_model_and_data(cfg)
+    print(f"dataset=fedtext (synthetic) model={cfg.model} (V={lcfg.vocab_held} of "
+          f"{lcfg.vocab_size}, L={lcfg.num_layers}, E={lcfg.hidden_size}, experts "
+          f"{len(lcfg.experts_held)} of {lcfg.num_experts}) mode={cfg.mode} "
+          f"clients={train.num_clients} workers={cfg.num_workers} "
+          f"host_loader={native.describe()}")
+    session, sampler = build_session_and_sampler(cfg, train, params, loss_fn)
+    bpr = session.bytes_per_round()
+    print(f"grad_size D={session.grad_size}  upload/client/round="
+          f"{bpr['upload_bytes']:,} B  download={bpr['download_bytes']:,} B")
+    writer = MetricsWriter(make_logdir(cfg), cfg.tensorboard, cfg=cfg,
+                           extra_header=controller_header(session))
+    checkpointer = FedCheckpointer(cfg)
+    try:
+        val = train_loop(cfg, session, sampler, test, writer, checkpointer=checkpointer)
+    except PreemptShutdown as e:
+        print(str(e))
+        raise SystemExit(EXIT_PREEMPTED) from e
+    finally:
+        checkpointer.close()
+        writer.close()
+    print(f"final: val_nll={val['nll']:.4f} ppl={val['ppl']:.2f}")
+    return val
+
+
+if __name__ == "__main__":
+    main()

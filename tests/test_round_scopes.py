@@ -20,10 +20,11 @@ from test_device_data import _mlp_loss, _toy_ds, augment_batch
 import commefficient_tpu
 from commefficient_tpu.data import FedSampler
 from commefficient_tpu.parallel import FederatedSession, make_mesh
-from commefficient_tpu.telemetry.trace import ROUND_SCOPES
+from commefficient_tpu.telemetry.trace import MODEL_SCOPES, ROUND_SCOPES
 from commefficient_tpu.utils.config import Config
 
 NAMES = tuple(name for name, _ in ROUND_SCOPES)
+MODEL_NAMES = tuple(name for name, _ in MODEL_SCOPES)
 BASE = dict(num_clients=16, num_workers=8, num_devices=1, local_batch_size=4,
             weight_decay=5e-4, max_grad_norm=1.0, seed=1)
 MODES = {
@@ -129,10 +130,10 @@ def test_resketch_compaction_is_named_ef_resketch_and_nothing_else(op_names):
         assert any(n.endswith("ef_resketch/" + prim) for n in under), prim
 
 
-@pytest.mark.parametrize("name", NAMES)
+@pytest.mark.parametrize("name", NAMES + MODEL_NAMES)
 def test_no_name_contains_another(name):
     assert re.fullmatch(r"[a-z][a-z0-9_]*", name)
-    assert [o for o in NAMES if o != name and name in o] == []
+    assert [o for o in NAMES + MODEL_NAMES if o != name and name in o] == []
 
 
 def test_source_opens_only_scopes_of_the_list():
@@ -146,4 +147,47 @@ def test_source_opens_only_scopes_of_the_list():
                         r'named_scope\(\s*"([^"]+)"\s*\)', fh.read()))
     opened |= {"sketch_decode_sharded", "sparse_aggregate_decode"}  # round.py
     # picks one of the two by the plan, through a variable
-    assert opened == set(NAMES)
+    assert opened == set(NAMES) | set(MODEL_NAMES)
+
+
+@pytest.fixture(scope="module")
+def laguna_op_names():
+    """The ``op_name`` metadata of the compiled index round of the LM entry
+    at ``laguna_tiny``, built as ``lm_train.main`` builds it."""
+    from commefficient_tpu.train import lm_train
+
+    cfg = lm_train.parse_args(
+        ["--model", "laguna_tiny", "--max_seq_len", "128", "--num_clients", "8",
+         "--num_workers", "2", "--num_devices", "1", "--mode", "uncompressed"],
+        defaults=lm_train.DEFAULTS)
+    train, _test, _lcfg, _model, params, loss_fn = lm_train.build_model_and_data(cfg)
+    session, sampler = lm_train.build_session_and_sampler(cfg, train, params, loss_fn)
+    ids, idx, plan = sampler.sample_round_indices(0)
+    cids, idxd, pl = session.stage_round_indices(ids, idx, plan)
+    text = session._round_idx_fn.lower(
+        session.state, session._dev_data, jnp.asarray(cids), idxd, pl,
+        jnp.float32(0.1), env=(),
+    ).compile().as_text()
+    return set(re.findall(r'op_name="([^"]*)"', text))
+
+
+@pytest.mark.parametrize("scope", MODEL_NAMES)
+def test_model_scopes_sit_under_client_grad_forward_and_backward(laguna_op_names, scope):
+    """Each is in the round the LM entry compiles, always inside
+    ``client_grad``, once wrapped by ``jvp(`` alone and once by
+    ``transpose(``: the per-layer metrics read all of a scope's passes.
+    (A sub-computation the compiler shares between call sites is lowered
+    once, under its own relative path: no ``jit(`` prefix, the scope still
+    in the name.)"""
+    under = [n for n in laguna_op_names if re.search(r"\b" + scope + r"\b", n)]
+    assert under
+    outside = [n for n in under if "client_grad" not in n and n.startswith("jit(")]
+    assert not outside, outside
+    assert any("transpose(" in n for n in under)
+    assert any("transpose(" not in n for n in under)
+
+
+def test_the_round_scopes_still_close_on_the_lm_round(laguna_op_names):
+    rx = re.compile("|".join(NAMES))
+    bare = {n for n in laguna_op_names if n.startswith("jit(") and not rx.search(n)}
+    assert bare <= {"jit(wrapped)/add"}, bare
