@@ -47,12 +47,14 @@ from commefficient_tpu.parallel.mesh import (
     worker_axis_size,
 )
 from commefficient_tpu.parallel.round import (
+    PER_CLIENT_VECTOR,
     FedState,
     make_aggregate_tail,
     make_decode_mapped,
     make_grad_one,
     make_per_client,
     resolve_aggregation,
+    resolve_client_path,
     server_phase,
 )
 from commefficient_tpu.utils.config import Config
@@ -92,6 +94,15 @@ def build_async_round_fns(
     """
     comp = get_compressor(cfg, d=d, spec=spec)
     comp.resolved_dampening()
+    # the launch program's product IS each client's own [D] row (the buffer
+    # weights and consumes them one by one), so a config whose rounds would
+    # sum the clients leaf by leaf has no launch program
+    if resolve_client_path(cfg, comp) != PER_CLIENT_VECTOR:
+        raise ValueError(
+            "the asyncfed programs buffer per-client rows: build them from "
+            "a config with async_buffer > 0 (resolve_client_path says "
+            "leafwise for this one)"
+        )
     W = cfg.num_workers
     f32 = jnp.float32
     lm = cfg.local_momentum

@@ -52,6 +52,12 @@ class Compressor:
     # True -> the fused flattened-batch gradient fast path is mathematically
     # identical for this mode (nothing per-client in the transmit rule)
     supports_fused_clients: bool = False
+    # True -> the per-client rules are the base ones below: client_grad is
+    # the single gradient pass and client_transmit returns u unchanged, so
+    # the round needs only the SUM of the clients' clipped gradients and
+    # may take it leaf by leaf (parallel/round.py::resolve_client_path).
+    # A subclass that overrides either rule sets False.
+    base_client_rules: bool = True
     # True -> the class implements encode_grad_table() and the round may
     # run the sketch-fused backward (cfg.sketch_fused_bwd): the worker's
     # gradient is produced directly as an encoded table by per-leaf

@@ -96,6 +96,7 @@ class FedAvgCompressor(_DenseServerMixin, Compressor):
     allowed_error_types = ("none",)
     supports_fsdp = False
     supports_fused_clients = False  # the local-SGD scan is inherently per-client
+    base_client_rules = False  # client_grad below is the local-SGD scan
     dense_delta = True
 
     def server_state_kinds(self):

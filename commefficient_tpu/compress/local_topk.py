@@ -29,6 +29,7 @@ class LocalTopkCompressor(_DenseServerMixin, Compressor):
     supports_fsdp = False  # per-client [num_clients, D] state: the memory
     # wall is offload_client_state's, not FSDP's
     supports_fused_clients = False  # per-client error/selection by definition
+    base_client_rules = False  # client_transmit below: local EF + top-k
     # the device's summed transmit has <= w_loc*k nonzeros (each client
     # sends <= k), so the aggregate rebuilds EXACTLY from one W*k-pair
     # all_gather — replicated dense result, server algebra untouched, safe

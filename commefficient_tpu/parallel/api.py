@@ -20,6 +20,7 @@ the reference's API contract either — workers and server state are opaque).
 from __future__ import annotations
 
 import functools
+import logging
 from typing import Any, Callable, Dict, Iterable, Optional
 
 import jax
@@ -46,9 +47,12 @@ from commefficient_tpu.parallel.round import (
     mask_classification,
     needs_client_err,
     needs_client_vel,
+    resolve_client_path,
 )
 from commefficient_tpu.telemetry.spans import span_of
 from commefficient_tpu.utils.config import Config
+
+_log = logging.getLogger(__name__)
 
 
 def _round_step(train_round):
@@ -87,10 +91,12 @@ class _Rung:
 
     __slots__ = ("cfg", "label", "spec", "compressor", "round_fn",
                  "sketch_decode_resolved", "aggregate_resolved",
-                 "round_idx_fn", "width_fns", "width_idx_fns")
+                 "client_path_resolved", "round_idx_fn", "width_fns",
+                 "width_idx_fns")
 
     def __init__(self, cfg, label, spec, compressor, round_fn,
-                 sketch_decode_resolved, aggregate_resolved):
+                 sketch_decode_resolved, aggregate_resolved,
+                 client_path_resolved):
         self.cfg = cfg
         self.label = label  # "" (single rung) | "rung0", "rung1", ...
         self.spec = spec
@@ -98,6 +104,8 @@ class _Rung:
         self.round_fn = round_fn
         self.sketch_decode_resolved = sketch_decode_resolved
         self.aggregate_resolved = aggregate_resolved  # "sparse" | "dense"
+        # "leafwise" | "per_client_vector" (round.resolve_client_path)
+        self.client_path_resolved = client_path_resolved
         self.round_idx_fn = None
         # elastic fleet (README "Elastic fleet"): one round program per
         # NON-BASE realized width, keyed by width — empty unless
@@ -271,6 +279,7 @@ class FederatedSession:
         self.compressor = rung.compressor
         self.sketch_decode_resolved = rung.sketch_decode_resolved
         self.aggregate_resolved = rung.aggregate_resolved
+        self.client_path_resolved = rung.client_path_resolved
         self.round_fn = rung.round_fn
         if cfg.fsdp:
             # FSDP round (parallel/fsdp.py): params + dense server state
@@ -524,6 +533,14 @@ class FederatedSession:
                 "exists when the workers axis is real; 'auto' picks dense "
                 "here for exactly that reason."
             )
+        # how the shard's client gradients reach the server (the round
+        # builders make the same call from the same inputs): static per
+        # compiled round, so the record is one string here and no per-round
+        # op. The flattened-batch fast path sits before either.
+        client_path_resolved = resolve_client_path(rcfg, compressor)
+        _log.info("round%s: client path %s (mode=%s)",
+                  f" [{label}]" if label else "", client_path_resolved,
+                  rcfg.mode)
         hook = self.retrace_sentinel.hook_for(_rung_hook_name(label))
         if rcfg.fsdp:
             from commefficient_tpu.parallel.fsdp import build_fsdp_round_fn
@@ -538,7 +555,8 @@ class FederatedSession:
                 d=self.grad_size, trace_hook=hook,
             )
         return _Rung(rcfg, label, spec, compressor, round_fn,
-                     decode_resolved, aggregate_resolved)
+                     decode_resolved, aggregate_resolved,
+                     client_path_resolved)
 
     def set_active_rung(self, i: int, *, migrate: bool = True) -> None:
         """Switch dispatch to rung ``i``: swap the session's active
@@ -568,6 +586,7 @@ class FederatedSession:
         self.compressor = new.compressor
         self.sketch_decode_resolved = new.sketch_decode_resolved
         self.aggregate_resolved = new.aggregate_resolved
+        self.client_path_resolved = new.client_path_resolved
         self._select_programs()
 
     # -- elastic fleet (per-width round programs; README "Elastic fleet") --

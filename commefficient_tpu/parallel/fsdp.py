@@ -82,7 +82,10 @@ from commefficient_tpu.parallel.mesh import WORKERS
 from commefficient_tpu.parallel.round import (
     FedState,
     _psum_fused,
+    LEAFWISE,
     make_grad_one,
+    make_leafwise_sum,
+    resolve_client_path,
     sum_client_grads,
 )
 from commefficient_tpu.telemetry import nonfinite_sentinel, table_sqnorm_estimate
@@ -244,6 +247,9 @@ def build_fsdp_round_fn(
         and cfg.dp_noise_multiplier == 0
         and not use_fedsim
     )
+    # the replicated round's own rule and helper, so the two cannot drift
+    leafwise = resolve_client_path(cfg, comp) == LEAFWISE
+    leafwise_sum = make_leafwise_sum(cfg, loss_fn, unravel)
 
     def body(p_sh, m_in, e_in, batch, client_ids, rng, lr, *fs):
         # fs: (live_mask [w_loc], corrupt [w_loc], live_count) iff fedsim
@@ -255,6 +261,7 @@ def build_fsdp_round_fn(
             live_sh, corr_sh, live_count = fs
         local, loss_local, aux = sum_client_grads(
             grad_one, params_vec, batch, client_ids, rng, fused=fused,
+            leafwise_sum=leafwise_sum if leafwise else None,
             live=live_sh, corrupt=corr_sh,
         )
         # one fused all-reduce for the scalar telemetry (loss + aux leaves)

@@ -62,6 +62,7 @@ declared per compressor class (``allowed_error_types``):
 
 from __future__ import annotations
 
+import math
 from typing import Any, Callable, NamedTuple, Optional
 
 import jax
@@ -173,23 +174,64 @@ def init_state(cfg: Config, params_vec: jnp.ndarray, spec: Optional[CountSketch]
     )
 
 
+LEAFWISE = "leafwise"
+PER_CLIENT_VECTOR = "per_client_vector"
+
+
+def resolve_client_path(cfg: Config, comp) -> str:
+    """Trace-time choice of how a shard's client gradients reach the
+    server, from what the configuration and the compressor declare (never
+    from a mode's name; the ONE place the rule lives: build_round_fn,
+    parallel/fsdp.py and asyncfed/round.py call it, FederatedSession keeps
+    the answer as ``client_path_resolved``).
+
+    ``"leafwise"``: the round needs only the SUM of the clients' clipped
+    gradients, so ``make_leafwise_sum`` clips and sums the gradient leaves
+    and builds one [D] vector per shard; no [w_loc, D] buffer exists.
+    ``"per_client_vector"``: something downstream takes each client's own
+    [D] row (``make_per_client`` under vmap): a compressor whose
+    per-client rules are not the base ones (local_topk's transmit rule,
+    fedavg's local-SGD scan), local momentum or local error (the row is
+    client state), DP noise (one [D] draw a client), a fedsim mask (kept
+    on this path for now, ROADMAP S4), and the asyncfed engine, whose
+    launch program's product IS the rows."""
+    leafwise = (
+        comp.base_client_rules
+        and cfg.local_momentum == 0
+        and cfg.error_type != "local"
+        and cfg.dp_noise_multiplier == 0
+        and not cfg.fedsim_enabled
+        and not cfg.asyncfed_enabled
+    )
+    return LEAFWISE if leafwise else PER_CLIENT_VECTOR
+
+
+def _client_value_and_grad(loss_fn: Callable, unravel: Callable, params_vec,
+                           batch):
+    """``((loss, aux), gradient pytree)`` of one client's batch: the
+    closure both client paths share, and all they share. When the loss
+    shards its compute over model/seq axes (tensor.build_tp_flat_loss),
+    the vma transpose totals the gradient over those axes by itself."""
+    return jax.value_and_grad(loss_fn, has_aux=True)(unravel(params_vec),
+                                                     batch)
+
+
 def make_grad_one(cfg: Config, loss_fn: Callable, unravel: Callable):
     """Per-client gradient closure (the fed_worker forward_grad analog):
     ``(params_vec, batch, noise_rng) -> (flat grad [D], loss, aux)`` with
-    weight decay, global-norm clip, and worker-side DP noise applied.
-    Shared by the replicated round (build_round_fn) and the FSDP round
-    (parallel/fsdp.py) so the gradient semantics can never drift. When the
-    loss shards its compute over model/seq axes
-    (tensor.build_tp_flat_loss), the vma transpose totals the gradient
-    over those axes by itself."""
+    weight decay, global-norm clip, and worker-side DP noise applied: the
+    per-client-vector path (``resolve_client_path``). Shared by the
+    replicated round (build_round_fn), the asyncfed launch program and the
+    FSDP round (parallel/fsdp.py) so the gradient semantics can never
+    drift."""
     f32 = jnp.float32
 
     def grad_one(params_vec, batch, noise_rng):
         # the scopes here and below are telemetry.trace.ROUND_SCOPES: op
         # metadata only (no ops added), read back from the device trace
         with jax.named_scope("client_grad"):
-            params = unravel(params_vec)
-            (loss, aux), grads = jax.value_and_grad(loss_fn, has_aux=True)(params, batch)
+            (loss, aux), grads = _client_value_and_grad(
+                loss_fn, unravel, params_vec, batch)
             # marker: the sketch-fused-backward tests pin that THEIR
             # lowered round contains no flat [D] gradient concat
             # (tests/test_sketch_fused_bwd.py)
@@ -207,6 +249,83 @@ def make_grad_one(cfg: Config, loss_fn: Callable, unravel: Callable):
         return g, loss, aux
 
     return grad_one
+
+
+def _param_leaves(unravel: Callable, params_vec):
+    """``unravel(params_vec)``'s pytree, each leaf sliced from the vector on
+    its own. XLA compiles ``unravel``'s split of the [D] vector into one
+    relayout of the WHOLE vector per distinct minor dimension of the
+    leaves; asked for a second time after the backward pass (the decay's
+    read), those [D] copies are shared with the forward's and stay alive
+    across the round: four of them at the start of the Laguna round, and
+    7 ms each (PERF.md section 6, PR 30). The barrier keeps every leaf's
+    relayout its own, next to its one reader."""
+    structs = jax.eval_shape(
+        unravel, jax.ShapeDtypeStruct(params_vec.shape, params_vec.dtype))
+    leaves, treedef = jax.tree.flatten(structs)
+    out, off = [], 0
+    for st in leaves:
+        n = math.prod(st.shape)
+        flat = jax.lax.optimization_barrier(params_vec[off:off + n])
+        out.append(flat.reshape(st.shape))
+        off += n
+    return jax.tree.unflatten(treedef, out)
+
+
+def make_leafwise_sum(cfg: Config, loss_fn: Callable, unravel: Callable):
+    """The leafwise client path (``resolve_client_path``): ``(params_vec,
+    batch {k: [w_loc, ...]}) -> (sum of the shard's clipped client
+    gradients [D], loss sum, aux sum)``, with the clients' gradients kept
+    as the pytree ``value_and_grad`` returns (leaves [w_loc, *shape]) and
+    never raveled into [w_loc, D]. A client's global norm is a sum of
+    per-leaf sums of squares, and the clipped sum is a per-leaf weighted
+    sum under one [w_loc] vector of scales, so per element this is
+    ``make_grad_one``'s expression, ``scale_w * (g_w + wd * p)``, summed in
+    ``jnp.sum(transmit, axis=0)``'s order over clients; only the order the
+    norm's squares are added in differs (per leaf, then across leaves:
+    float32 reassociation, ~1e-7 relative on the scale). One
+    ``ravel_pytree`` of the SUMMED leaves builds the [D] vector the encode,
+    the aggregation tail and the server take."""
+    f32 = jnp.float32
+    wd = cfg.weight_decay
+    max_norm = cfg.max_grad_norm
+
+    def leafwise_sum(params_vec, batch):
+        def grads_one(b):
+            with jax.named_scope("client_grad"):
+                (loss, aux), grads = _client_value_and_grad(
+                    loss_fn, unravel, params_vec, b)
+            return grads, loss, aux
+
+        grads, losses, auxes = jax.vmap(grads_one)(batch)
+        with jax.named_scope("client_clip"):
+            gs = jax.tree.map(lambda g: g.astype(f32), grads)
+            if wd:
+                gs = jax.tree.map(lambda g, p: g + wd * p, gs,
+                                  _param_leaves(unravel, params_vec))
+            scale = None
+            if max_norm is not None:
+                sq = None  # [w_loc]: each client's squared global norm
+                for g in jax.tree.leaves(gs):
+                    leaf_sq = jnp.sum(g * g, axis=tuple(range(1, g.ndim)))
+                    sq = leaf_sq if sq is None else sq + leaf_sq
+                # clip_by_global_norm's own expression, a client a lane
+                scale = jnp.minimum(1.0, max_norm / (jnp.sqrt(sq) + 1e-12))
+        with jax.named_scope("client_sum"):
+            if scale is not None:
+                gs = jax.tree.map(
+                    lambda g: g * scale.reshape((-1,) + (1,) * (g.ndim - 1)),
+                    gs)
+            summed = jax.tree.map(lambda g: jnp.sum(g, axis=0), gs)
+            with jax.named_scope("flat_grad_concat"):
+                local, _ = ravel_pytree(summed)
+            return (
+                local,
+                jnp.sum(losses),
+                jax.tree.map(lambda a: jnp.sum(a, 0), auxes),
+            )
+
+    return leafwise_sum
 
 
 def leaf_groups(sizes, segments):
@@ -284,8 +403,6 @@ def make_sketch_grad_one(cfg: Config, loss_fn: Callable, unravel: Callable,
 
     # static per-leaf offsets of the ravel_pytree flat layout (jax.tree
     # leaf order == ravel_pytree order)
-    import math
-
     leaf_structs = jax.tree.leaves(
         jax.eval_shape(unravel, jax.ShapeDtypeStruct((d,), jnp.float32))
     )
@@ -368,13 +485,17 @@ def make_sketch_grad_one(cfg: Config, loss_fn: Callable, unravel: Callable,
 
 
 def sum_client_grads(grad_one, params_vec, batch, client_ids, rng, *,
-                     fused: bool, live=None, corrupt=None):
+                     fused: bool, leafwise_sum=None, live=None, corrupt=None):
     """(sum of client grads [D], loss sum, aux sum) over one shard's clients
     — the NO-client-state aggregation shared by the replicated round's fused
     fast path and the FSDP round (parallel/fsdp.py), extracted so the two
     cannot drift. ``fused``: one flattened-batch grad replaces the per-client
     vmap — identical math when nothing per-client is configured
     (w_loc * flat-mean-grad == sum of per-client mean-grads).
+    ``leafwise_sum`` (``make_leafwise_sum``'s product, passed where
+    ``resolve_client_path`` says leafwise): the non-fused sum is that
+    helper's, the replicated round's own; ``None`` keeps the per-client
+    [w_loc, D] vectors (DP noise, fedsim masks).
 
     ``live``/``corrupt`` ([w_loc] 0/1 floats, fedsim masked aggregation —
     FSDP path only; the round builders disable fusion whenever fedsim is
@@ -390,6 +511,8 @@ def sum_client_grads(grad_one, params_vec, batch, client_ids, rng, *,
         g, loss_flat, aux = grad_one(params_vec, flat, rng)
         with jax.named_scope("client_sum"):
             return w_loc * g, w_loc * loss_flat, aux
+    if leafwise_sum is not None:
+        return leafwise_sum(params_vec, batch)
 
     def per_client(b, cid):
         return grad_one(params_vec, b, jax.random.fold_in(rng, cid))
@@ -860,6 +983,10 @@ def build_round_fn(
     plan = resolve_aggregation(cfg, comp, Wd)
     sparse_state = plan.sparse_state
 
+    # leafwise or per-client vectors (resolve_client_path): two paths that
+    # share the gradient closure and nothing after it
+    leafwise = not fused and resolve_client_path(cfg, comp) == LEAFWISE
+    leafwise_sum = make_leafwise_sum(cfg, loss_fn, unravel)
     per_client = make_per_client(cfg, comp, grad_one, use_fedsim=use_fedsim)
     aggregate_tail = make_aggregate_tail(cfg, comp, plan, W=W, Wd=Wd, d=d,
                                          axes=axes)
@@ -909,6 +1036,10 @@ def build_round_fn(
             local, loss_local, aux = sum_client_grads(
                 grad_one, params_vec, batch, client_ids, rng, fused=True
             )
+            new_vel = jnp.zeros((w_loc, 1), f32)
+            new_err = jnp.zeros((w_loc, 1), f32)
+        elif leafwise:
+            local, loss_local, aux = leafwise_sum(params_vec, batch)
             new_vel = jnp.zeros((w_loc, 1), f32)
             new_err = jnp.zeros((w_loc, 1), f32)
         else:

@@ -87,18 +87,27 @@ ROUND_SCOPES: Tuple[Tuple[str, str], ...] = (
     ("data_gather", "in-graph gather of the round's batch from the "
                     "device-resident dataset, and the device-side "
                     "augmentation (parallel/api.py)"),
-    ("client_grad", "value_and_grad of the loss and the flat-gradient "
-                    "concat (parallel/round.py)"),
-    ("flat_grad_concat", "the [D] concat of the gradient leaves, inside "
-                         "client_grad; absent under sketch_fused_bwd"),
+    ("client_grad", "value_and_grad of the loss (parallel/round.py); on "
+                    "the per-client-vector path also each client's "
+                    "flat-gradient concat"),
+    ("flat_grad_concat", "the [D] concat of gradient leaves: on the leafwise "
+                         "path the ONE concat of the summed leaves, under "
+                         "client_sum, once a shard; on the per-client-vector "
+                         "path each client's own, inside client_grad; absent "
+                         "under sketch_fused_bwd"),
     ("sketch_fused_bwd", "the sketch-fused backward: the gradient produced "
                          "as a table by per-leaf taps"),
-    ("client_clip", "weight decay, clip_by_global_norm and DP noise on the "
-                    "flat [D] gradient"),
+    ("client_clip", "weight decay and the clip's [w_loc] scales, leaf by "
+                    "leaf (a client's squared norm is a sum of per-leaf sums "
+                    "of squares); on the per-client-vector path weight "
+                    "decay, clip_by_global_norm and DP noise on the flat [D] "
+                    "gradient"),
     ("client_transmit", "local momentum and the compressor's per-client "
                         "transmit rule"),
-    ("client_sum", "sum of the [w_loc, D] transmits, losses and aux over "
-                   "the shard's clients"),
+    ("client_sum", "the clipped sum over the shard's clients, leaf by leaf "
+                   "under the [w_loc] scales, then flat_grad_concat, with the "
+                   "losses and aux; on the per-client-vector path the sum of "
+                   "the [w_loc, D] transmits"),
     ("encode", "comp.device_encode / encode_grad_table: the sketch "
                "accumulate (identity for dense modes)"),
     ("aggregate_tail", "the cross-worker psum / sparse all-reduce and the "

@@ -42,8 +42,13 @@ EVERY_ROUND = {"data_gather", "client_grad", "flat_grad_concat", "client_clip",
 TRACED = {
     "sketch": EVERY_ROUND | {"encode", "estimate_all", "topk_select",
                              "ef_resketch"},
-    # device_encode is the identity and the server keeps no bank to decode
-    "uncompressed": EVERY_ROUND,
+    # device_encode is the identity and the server keeps no bank to decode;
+    # on the leafwise path (parallel/round.py::make_leafwise_sum) nothing
+    # sits between the one [D] concat of the summed leaves and the
+    # aggregation tail's own concat of the psum payload, so the compiler
+    # makes one concatenate of the two and keeps the tail's name (the
+    # program still opens the scope: tests/test_leafwise_clients.py)
+    "uncompressed": EVERY_ROUND - {"flat_grad_concat"},
     "local_topk": EVERY_ROUND | {"client_transmit", "topk_select"},
 }
 
