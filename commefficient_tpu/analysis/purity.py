@@ -4,7 +4,7 @@ roots must stay host-pure.
 The whole system rests on the compiled round being a pure function of
 its arguments: bit-exact replay after rollback (resilience/), bit-exact
 resume from checkpoint, the retrace sentinel's zero-retrace contract
-(telemetry/), and the pipeline engine's any-depth == depth-0 pin all
+(telemetry/), and asyncfed's K = W, C = 1 == synchronous pin all
 assume that tracing the same program twice yields the same program. One
 ``time.time()`` or ``np.random.<draw>`` inside traced code bakes a
 different constant into every trace; one ``float(x)`` on a tracer is a

@@ -3,7 +3,7 @@ consumed exactly once.
 
 The repo's randomness is layered — fedsim availability draws, the
 client sampler, DP noise, powersgd's sketch matrices, data augmentation
-— and the resume/replay contracts (resilience/, pipeline/) hold only
+— and the resume/replay contracts (resilience/, asyncfed/) hold only
 because each layer's stream is (a) deterministic given ``cfg.seed`` and
 (b) disjoint from every other layer's. The conventions that keep that
 true (established by the fedsim PR's ``FEDSIM_STREAM`` tag):

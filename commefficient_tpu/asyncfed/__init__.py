@@ -8,7 +8,7 @@ update once ``K`` contributions have arrived, and weights each
 contribution by the polynomial staleness discount ``(1+s)^(-alpha)``
 (``--staleness_exponent``) before it enters the shared aggregation tail.
 
-Three pieces:
+Four pieces:
 
 * ``schedule``: ``AsyncSchedule`` — the pre-simulated deterministic
   arrival process (per-cohort exponential delays on a dedicated rng
@@ -18,13 +18,16 @@ Three pieces:
   per-client transmit rows) and an ``apply_fn`` (weighted buffer drain ->
   server update), sharing the synchronous helpers so the K=W, C=1,
   alpha=0 anchor reduces bit-identically to ``build_round_fn``.
-* ``engine``: ``AsyncFederation`` — the round-source driver (same
-  protocol as ``pipeline.PipelinedRounds``) owning the in-flight window,
-  cohort staging (``pipeline.CohortScheduler``), staleness weighting,
-  overlap telemetry, and the vault snapshot riders.
+* ``staging``: ``CohortScheduler`` over ``RoundPrefetcher`` — the
+  background worker that realizes and stages cohorts ahead of their
+  launch, with crash propagation and a replay horizon.
+* ``engine``: ``AsyncFederation`` — the round source the runner drives
+  instead of its plain loop (``start``/``epoch_rounds``/``restart``/
+  ``close``), owning the in-flight window, staleness weighting, overlap
+  telemetry, and the vault snapshot riders.
 
 ``--async_buffer 0`` (default) constructs nothing — the synchronous
-engines and their golden recordings are untouched.
+loop and its golden recordings are untouched.
 """
 
 from commefficient_tpu.asyncfed.engine import AsyncFederation

@@ -6,7 +6,7 @@ Per update ``u`` the engine:
 
 1. launches the cohorts ``AsyncSchedule.updates[u].launches_before``
    scripts — each realized in cohort order by a ``CohortScheduler``
-   (pipeline/cohorts.py) and dispatched through the active rung's
+   (asyncfed/staging.py) and dispatched through the active rung's
    ``launch_fn`` against the CURRENT params (server version ``u``);
 2. assembles the update's K consumed ``(cohort, slot)`` contributions
    (canonical order — see asyncfed/schedule.py) into fixed [W, ...]
@@ -62,17 +62,17 @@ import jax.numpy as jnp
 import numpy as np
 
 from commefficient_tpu.asyncfed.schedule import AsyncSchedule, UpdateSpec
-from commefficient_tpu.pipeline.cohorts import CohortScheduler
+from commefficient_tpu.asyncfed.staging import CohortScheduler
 from commefficient_tpu.telemetry.spans import span_of
 
 
 class AsyncFederation:
     """Buffered-asynchronous round source (``cfg.async_buffer > 0``).
 
-    Same constructor/protocol shape as ``pipeline.PipelinedRounds``:
-    ``start(resume_step)``, ``epoch_rounds(epoch, start_step)`` yielding
-    ``(step, lr, metrics)``, ``restart(step)``, ``close()``, ``stats()``
-    — plus ``snapshot_extra``/``restore_extra`` for the vault rider."""
+    The runner's round-source protocol: ``start(resume_step)``,
+    ``epoch_rounds(epoch, start_step)`` yielding ``(step, lr, metrics)``,
+    ``restart(step)``, ``close()``, ``stats()`` — plus
+    ``snapshot_extra``/``restore_extra`` for the vault rider."""
 
     def __init__(self, cfg, session, sampler, lr_fn, num_rounds,
                  steps_per_epoch=None, spans=None, profiler=None):
@@ -105,8 +105,8 @@ class AsyncFederation:
         self._consumed: Dict[int, int] = {}  # cohort -> consumed slots
         self._next_cohort = 0
         # replay horizon in COHORT units (fedsim nan_client transients
-        # fire on first realization only — same discipline as the
-        # pipelined engine's round-unit horizon)
+        # fire on first realization only — the session's round-unit
+        # _replay_horizon discipline)
         self._cohort_horizon = 0
         self._restored = None
         self.restarts = 0
@@ -138,7 +138,7 @@ class AsyncFederation:
     # -- lifecycle ---------------------------------------------------------
     def start(self, resume_step: int = 0) -> "AsyncFederation":
         if self._scheduler is not None:
-            return self  # idempotent, like PipelinedRounds.start
+            return self  # idempotent
         self._init_window(int(resume_step), None)
         return self
 

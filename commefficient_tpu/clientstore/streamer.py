@@ -8,34 +8,26 @@ worker. Its contract with the round:
   * ``gather(cids) -> StagedCohort`` — the cohort's [n, D] device rows
     per bank (``()`` for an absent bank, the round extras convention),
     assembled cache-first and staged H2D via the session's
-    ``stage_fn``. Callable from the prefetch worker thread: the PR 9
-    prefetcher realizes round t+1's cohort while round t computes, so
-    the H2D overlaps device compute.
+    ``stage_fn``. The round calls it just before its dispatch; it waits
+    on any pending writeback that touches the same clients, so the rows
+    are always the previous round's.
   * ``scatter(cids, new_vel, new_err)`` — the round's updated rows.
     Cache on: rows land in the device cache dirty (write-through on
     eviction keeps the bank honest). Cache off: the writeback worker
     syncs D2H and scatters into the bank ASYNCHRONOUSLY — the host loop
     never waits on the previous round's writeback.
-  * hazard versioning: every scatter bumps a global version and stamps
-    ``last_write[cids]``; a ``StagedCohort`` records its gather-time
-    version, and ``is_stale`` tells the dispatcher whether any staged
-    row was overwritten since (same cohort drawn twice in the pipeline
-    window) — the consumer regathers synchronously, so pipelined runs
-    stay BIT-exact while overlap pays off whenever cohorts don't
-    collide.
   * ``flush()`` — the drain fence: joins pending writebacks and writes
     dirty cache rows through, so checkpoint saves / vault snapshots /
     whole-bank reads observe every completed round.
 
-A writeback fault is stored and re-raised at the next gather/flush
-(the prefetcher's consumer-side fault discipline). Per-round
-``clientstore/*`` scalars (cache hit rate, evictions, H2D stage ms,
+A writeback fault is stored and re-raised at the next gather/flush.
+Per-round ``clientstore/*`` scalars (cache hit rate, evictions, H2D stage ms,
 writeback ms) accumulate here and drain via ``pop_round_stats``.
 
 Trace correlation (schema v11): when the session attaches a PhaseSpans
 recorder (its ``spans`` setter forwards here), gather/writeback/flush
-record spans — ``clientstore_gather`` on the calling thread (usually
-the prefetch lane), ``clientstore_writeback`` on the worker's own
+record spans — ``clientstore_gather`` on the calling thread,
+``clientstore_writeback`` on the worker's own
 labeled lane, ``clientstore_flush`` on the fencing thread — and
 gather/scatter accept the owning round's ``trace_id`` from the caller
 (the streamer has no round clock of its own), so a Perfetto dump links
@@ -60,12 +52,10 @@ _END = object()
 
 
 class StagedCohort(NamedTuple):
-    """A realized cohort payload: per-bank device rows (or ``()``) plus
-    the gather-time version the staleness check keys off."""
+    """A realized cohort payload: per-bank device rows (or ``()``)."""
 
     vel: Any
     err: Any
-    version: int
 
 
 class _WriteEntry:
@@ -95,8 +85,6 @@ class CohortStreamer:
         # array under the session's batch sharding; identity for tests
         self._stage = stage_fn if stage_fn is not None else (lambda x: x)
         self._lock = threading.Lock()
-        self._version = 0
-        self._last_write = np.zeros(self.num_clients, np.int64)
         self._pending: list = []
         self._fault: Optional[BaseException] = None
         self._q: queue.Queue = queue.Queue()
@@ -203,7 +191,6 @@ class CohortStreamer:
         ids = np.asarray(cids).reshape(-1)
         idset = set(int(i) for i in ids)
         with self._lock:
-            version = self._version
             cached = {}
             if self._cache is not None:
                 for pos, cid in enumerate(int(i) for i in ids):
@@ -229,7 +216,7 @@ class CohortStreamer:
             spans.span_at("clientstore_gather", t0, t1,
                           step=step_of_trace_id(trace_id),
                           trace_id=trace_id)
-        return StagedCohort(vel, err, version)
+        return StagedCohort(vel, err)
 
     def _assemble(self, store, ids, missing, cached, bank):
         if store is None:
@@ -250,15 +237,6 @@ class CohortStreamer:
                     dev[pos] = np.asarray(row)
         return dev
 
-    def is_stale(self, cids, version: int) -> bool:
-        """True iff any of the cohort's rows were scattered after the
-        staged gather at ``version`` — the dispatcher then regathers
-        synchronously (always exact; overlap pays when cohorts don't
-        collide inside the pipeline window)."""
-        ids = np.asarray(cids).reshape(-1)
-        with self._lock:
-            return bool((self._last_write[ids] > version).any())
-
     def scatter(self, cids, new_vel, new_err, trace_id=None) -> None:
         """Write the round's updated rows back (per-bank ``()``/None for
         absent banks). Returns immediately; ``flush()`` is the fence.
@@ -274,8 +252,6 @@ class CohortStreamer:
         err = new_err if (self.err_store is not None and new_err is not None
                           and not isinstance(new_err, tuple)) else None
         with self._lock:
-            self._version += 1
-            self._last_write[ids] = self._version
             if self._cache is not None:
                 for pos, cid in enumerate(int(i) for i in ids):
                     self._cache.put(
@@ -336,9 +312,6 @@ class CohortStreamer:
         with self._lock:
             if self._cache is not None:
                 self._cache.invalidate()
-            # staged cohorts gathered before the load are now stale
-            self._version += 1
-            self._last_write[:] = self._version
 
     # ------------------------------------------------------------------
     def pop_round_stats(self) -> dict:

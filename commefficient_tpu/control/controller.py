@@ -90,15 +90,13 @@ class BudgetController:
         # on_round_start clamps to it) and rides the checkpoint blob so a
         # resumed run stays demoted.
         self.min_rung = 0
-        # rung-switch observers (pipeline/engine.py registers one): called
+        # rung-switch observers (asyncfed/engine.py registers one): called
         # host-side, AFTER the dispatch-table swap + state migration and
-        # BEFORE the round dispatches — the pipelined engine's quiesce
-        # point. ``on_round_start`` stays a PRE-STAGING barrier in the
-        # pipeline sense: staged work is rung-INVARIANT (batch geometry,
-        # env masks and lr never depend on the rung), so a switch
-        # invalidates nothing in the in-flight window, and every rung's
-        # program is AOT-prewarmed — the listener lets the engine account/
-        # span the quiesce without re-deriving any of that.
+        # BEFORE the round dispatches. Staged work is rung-INVARIANT
+        # (batch geometry, env masks and lr never depend on the rung), so
+        # a switch invalidates nothing in the in-flight window, and every
+        # rung's program is AOT-prewarmed — the listener lets the engine
+        # account/span the switch without re-deriving any of that.
         self._switch_listeners = []
         # asyncfed (K, C) retune state (staleness_aware policy): the
         # controller owns the authoritative pair — the engine registers a

@@ -129,23 +129,13 @@ class FedEnvironment:
             "fleet/last_resize_round": float(last),
         }
 
-    def round_envs(self, start: int, stop: int):
-        """Yield ``round_env(r)`` for r in [start, stop) — the pipeline
-        prefetcher's (and bench's) bulk-realization form. Each env is a
-        pure function of ``(seed, FEDSIM_STREAM, round_idx)`` with no
-        shared mutable state, so realization commutes with execution:
-        prefetching round t+k's environment from a worker thread while
-        round t computes yields bit-identical masks to realizing it
-        synchronously (the pipeline/ determinism contract leans on this)."""
-        for r in range(start, stop):
-            yield self.round_env(r)
-
     def round_env(self, round_idx: int, replay: bool = False,
                   width: Optional[int] = None) -> RoundEnv:
         """Realize round ``round_idx``'s masks + telemetry scalars —
         deterministic and resume-stable from (seed, round_idx). Pure and
-        thread-safe: a fresh rng per call, nothing mutated (see
-        ``round_envs``). ``replay=True`` marks a round re-executed after a
+        thread-safe: a fresh rng per call, nothing mutated, so the
+        asyncfed staging worker realizes it ahead of the launch with the
+        same masks. ``replay=True`` marks a round re-executed after a
         resilience/ rollback: the transient nan_client injection is
         suppressed (faults.apply_chaos), every other draw — and therefore
         every mask — is bit-identical to the first pass.

@@ -13,8 +13,8 @@ story. This package owns the three planes of a distributed run:
   partition — its slots' sampler draws on its own rng stream, its rows
   of the (globally-deterministic) fedsim ``RoundEnv``, and a clientstore
   bank holding only its clients. ``assemble_rows`` lifts the slices into
-  one globally-sharded array, so the pipeline/scan/async engines
-  downstream are unchanged.
+  one globally-sharded array, so the round sources downstream (the
+  plain loop, asyncfed) are unchanged.
 * **aggregation plane**: no new code here by design — every worker-axis
   collective resolves its axis group through ``parallel.mesh
   .worker_axes(mesh)``, so the sketch-table psum and the dense fused

@@ -32,8 +32,8 @@ three partitions:
 — each process supplies data only for shards it addresses, so on a real
 pod the non-owned rows never exist host-side, while on the mesh-faked
 twin (all devices addressable by one process) the same call assembles all
-virtual hosts' slices. The engines downstream (pipeline/scan/async) see
-an ordinary ``[W, ...]``-sharded array and are unchanged.
+virtual hosts' slices. The round sources downstream (the plain loop,
+asyncfed) see an ordinary ``[W, ...]``-sharded array and are unchanged.
 """
 
 from __future__ import annotations
@@ -281,9 +281,6 @@ class HostClientBank:
     def scatter(self, cids, new_vel, new_err, trace_id=None) -> None:
         self._streamer.scatter(self._local(cids), new_vel, new_err,
                                trace_id=trace_id)
-
-    def is_stale(self, cids, version: int) -> bool:
-        return self._streamer.is_stale(self._local(cids), version)
 
     def flush(self) -> None:
         self._streamer.flush()

@@ -1011,13 +1011,12 @@ class FederatedSession:
                          cfg: Optional[Config] = None):
         """The UNJITTED index-round closure
         ``(state, data, client_ids, idx, plan, lr, env=()) -> (state,
-        metrics)`` — the traceable body both the jitted per-round program
-        (``_build_round_idx_fn``) and the scan-over-rounds engine's
-        ``lax.scan`` body (pipeline/scan_engine.py) wrap, so the two
-        dispatch granularities share one round trace by construction.
-        Defaults to the active rung and the attached augmenter; ``cfg``
-        overrides the trace-time config (the fleet width builds pass the
-        rung config narrowed to ``num_workers = w``)."""
+        metrics)`` — the traceable body the jitted per-round program
+        (``_build_round_idx_fn``) wraps; the ``data_gather`` scope is
+        named here. Defaults to the active rung and the attached
+        augmenter; ``cfg`` overrides the trace-time config (the fleet
+        width builds pass the rung config narrowed to
+        ``num_workers = w``)."""
         from commefficient_tpu.parallel.round import build_round_fn as _brf
 
         if rung is None:
@@ -1069,17 +1068,16 @@ class FederatedSession:
             donate_argnums=(0,),
         )
 
-    # -- eager H2D staging (pipeline/ prefetch lane) -----------------------
+    # -- H2D staging ------------------------------------------------------
     def stage_round_payload(self, client_ids, batch):
-        """Commit one round's host batch to the mesh EAGERLY — the
-        pipeline prefetcher's H2D lane: round t+1's arrays start their
-        host->device copy while round t computes. Returns
-        ``(client_ids_np, dev_batch)``; committed arrays pass through the
-        dispatch-time ``device_put`` in ``train_round`` as an identity
-        (same sharding, no copy), so a staged round dispatches with zero
-        H2D on the critical path. Safe from a worker thread (pure
-        ``device_put``, no tracing, no session state touched). client_ids
-        stay host-side numpy: the offload path indexes host stores with
+        """Commit one round's host batch to the mesh. ``train_round``
+        calls it at dispatch; the asyncfed staging worker
+        (asyncfed/staging.py) calls it ahead of a cohort's launch, so the
+        host->device copy overlaps earlier launches' compute. Returns
+        ``(client_ids_np, dev_batch)``; committed arrays pass through a
+        later ``device_put`` as an identity (same sharding, no copy).
+        Safe from a worker thread (pure ``device_put``, no tracing, no
+        session state touched). client_ids stay host-side numpy: the offload path indexes host stores with
         them, and at [W] ints their dispatch-time put is noise."""
         cids = np.asarray(client_ids)
         dev_batch = jax.tree.map(
@@ -1182,8 +1180,7 @@ class FederatedSession:
         """(device env tuple for round_fn, host ``fedsim/*`` stats) for the
         CURRENT round — ``((), {})`` when the simulator is inactive.
         ``env`` (a fedsim.RoundEnv) overrides the session environment's
-        schedule; tests drive explicit masks through it (the pipelined
-        engine passes its prefetched realizations the same way).
+        schedule; tests drive explicit masks through it.
         ``client_ids`` (host [W]) lets the resilience blacklist compose
         into the mask — trace-only callers (prewarm/audit) may omit it."""
         if env is None:
@@ -1356,32 +1353,16 @@ class FederatedSession:
         return {**metrics, **stats} if stats else metrics
 
     # -- train ------------------------------------------------------------
-    def stage_cohort_rows(self, client_ids, trace_id=None):
-        """Realize the cohort's hosted [W, D] device rows (or None when
-        the session has no hosted store) — the prefetcher calls this from
-        its worker thread so the clientstore gather + H2D overlap the
-        previous round's compute; ``train_round(..., cohort=)`` consumes
-        the result, regathering only if the staged rows went stale.
-        ``trace_id=`` stamps the gather span with the round being
-        prefetched (the prefetcher knows it; this session does not)."""
-        if self._streamer is None:
-            return None
-        return self._streamer.gather(np.asarray(client_ids),
-                                     trace_id=trace_id)
-
     @_round_step
     def train_round(self, client_ids: np.ndarray, batch: Dict[str, np.ndarray],
-                    lr: float, env=None, cohort=None):
+                    lr: float, env=None):
         from commefficient_tpu.telemetry.trace import round_trace_id
 
         w = self._fleet_round_begin()
         if w != self.cfg.num_workers:
-            # session-owned width slicing (the sampler stays base-width);
-            # a cohort staged at the base width no longer matches the
-            # sliced ids — drop it and regather the w rows below
+            # session-owned width slicing (the sampler stays base-width)
             client_ids = np.asarray(client_ids)[:w]
             batch = jax.tree.map(lambda a: a[:w], batch)
-            cohort = None
         tid = round_trace_id(self._round_clock)
         with self._span("device_put", trace_id=tid):
             cids, dev_batch = self.stage_round_payload(client_ids, batch)
@@ -1404,13 +1385,10 @@ class FederatedSession:
             stats = self._host_round_stats(fs_stats)
             return {**metrics, **stats} if stats else metrics
         # hosted client state (clientstore/): cohort rows are ARGUMENTS of
-        # the compiled round — no [num_clients, D] operand in the HLO. A
-        # prefetched cohort is used only if none of its rows were
-        # scattered since its gather (same client drawn twice inside the
-        # pipeline window) — the staleness regather keeps pipelined runs
-        # bit-exact with the sequential schedule.
-        if cohort is None or self._streamer.is_stale(cids, cohort.version):
-            cohort = self._streamer.gather(cids, trace_id=tid)
+        # the compiled round — no [num_clients, D] operand in the HLO. The
+        # gather waits on any pending writeback of the same clients, so
+        # the rows are the previous round's.
+        cohort = self._streamer.gather(cids, trace_id=tid)
         with self._span("round_dispatch", collective=True,
                         trace_id=tid) as sp:
             self.state, metrics, new_vel, new_err = self.round_fn(

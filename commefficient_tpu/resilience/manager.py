@@ -20,8 +20,8 @@ The manager's recovery sequence, on a caught ``DivergenceError``:
   4. act — the policy's repair (retry/demote/skip_clients; policy.py);
   5. report — append the history entry, write the ``_recovery``-tagged
      flight dump carrying it, and hand the rollback step back to the
-     runner, which restarts the round source there (the pipelined engine
-     quiesces its prefetch window like a checkpoint fence).
+     runner, which restarts the round source there (the asyncfed engine
+     rebuilds its in-flight window).
 
 ``resilience/*`` scalars (schema v6) ride every round's metric dict
 through ``FederatedSession._host_round_stats`` — a constant key set, as

@@ -112,11 +112,9 @@ from commefficient_tpu.telemetry.xla_audit import (
 # whose cum-bytes invariant is the sum over rungs of active-rung bytes —
 # live-count-weighted under fedsim masking), and the header/flight
 # "controller" block (policy, ladder, rung at write/dump time).
-# v5 (pipelined round execution PR): the pipeline/* scalar namespace
-# (occupancy in [0, 1], host_stall_ms, the integer staged_rounds — both
-# invariants checker-enforced), and thread-aware spans: per-event lane
-# ``tid``s plus "M" thread_name metadata events labeling the prefetch
-# lane's own track.
+# v5: thread-aware spans: per-event lane ``tid``s plus "M" thread_name
+# metadata events labeling a worker thread's own track (the pipeline/*
+# scalar namespace v5 also brought left with its engines).
 # v6 (self-healing training PR): the resilience/* scalar namespace
 # (recoveries / rung_demotions / blacklisted_clients — non-negative
 # integer counters; preempt_requested in {0, 1}; rollback_round an

@@ -23,7 +23,7 @@ the jitted program either way (spans are pure host code).
 
 Thread-awareness (schema v5): spans record the CALLING thread as a small
 lane id in ``tid`` — the constructing thread is lane 0, every other
-thread gets the next lane on first use — so the pipeline prefetcher's
+thread gets the next lane on first use — so the asyncfed staging worker's
 ``prefetch_realize``/``prefetch_stage`` spans render as their own
 Perfetto track instead of interleaving with the dispatch spans on one
 line. ``register_lane(name)`` additionally emits a Chrome-trace
@@ -170,7 +170,7 @@ class PhaseSpans:
         self._first_step: Optional[int] = None
         self._dumped: Optional[str] = None
         # thread -> lane map (the constructing thread is lane 0): spans
-        # from other threads (the pipeline prefetch worker) get their own
+        # from other threads (the asyncfed staging worker) get their own
         # Perfetto track instead of interleaving with dispatch spans
         self._lanes = {threading.get_ident(): 0}
         self._lane_lock = threading.Lock()
@@ -190,7 +190,8 @@ class PhaseSpans:
     def register_lane(self, name: str) -> int:
         """Name the CALLING thread's track (a Chrome-trace ``thread_name``
         metadata event; schema v5) and return its lane id. Worker threads
-        (the pipeline prefetcher) call this once at startup."""
+        (the asyncfed staging worker, the clientstore writeback) call this
+        once at startup."""
         lane = self._lane()
         if self.enabled:
             self._meta_events.append({

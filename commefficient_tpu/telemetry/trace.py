@@ -8,8 +8,8 @@ id minted at realization time — ``round_trace_id(step) == "r<step>"``
 for rounds (the root of each causal tree), ``cohort_trace_id(c) ==
 "c<cohort>"`` for asyncfed cohorts (whose ``parent`` is the round that
 launched them). All four planes stamp their spans with the owning id
-(``PhaseSpans.span(..., trace_id=, parent=)``): the PR 9 prefetch lane
-(sampler draw, fedsim realize, H2D stage), the PR 17 clientstore
+(``PhaseSpans.span(..., trace_id=, parent=)``): the asyncfed staging
+lane (sampler draw, fedsim realize, H2D stage), the PR 17 clientstore
 streamer (gather, writeback, flush), the PR 15 asyncfed engine (launch,
 buffer residency, apply dispatch/drain) and the dispatch plane
 (device_put, round dispatch, metric drain). A Perfetto dump then
@@ -43,7 +43,7 @@ pack_metric_dicts requires.
 a run directory (spans dump + metrics.jsonl + flight records +
 perf_report.json, whichever exist) into a versioned ``run_report.json``
 — per-stage p50/p95, attribution fractions summing to 1, anomaly flags
-(stall spikes, staleness drift, cache-hit collapse) — consumed by
+(staleness drift, cache-hit collapse) — consumed by
 ``scripts/analyze_run.py`` and written at train-loop close when
 ``cfg.run_report`` (the default; accuracy_run.py opts out like it does
 for perf_audit). ``ProfilerWindow`` arms a programmatic
@@ -402,7 +402,7 @@ def _read_metrics_series(path: str) -> Dict[str, List[float]]:
 
 
 def _detect_anomalies(series: Dict[str, List[float]]) -> List[dict]:
-    """Flag the three failure smells the subsystems' scalars expose.
+    """Flag the failure smells the subsystems' scalars expose.
     Thresholds are deliberately coarse — these are triage flags for a
     human, not gates (the checkers own gating)."""
     out: List[dict] = []
@@ -411,16 +411,6 @@ def _detect_anomalies(series: Dict[str, List[float]]) -> List[dict]:
         q = max(1, len(xs) // 4)
         return (sum(xs[:q]) / q, sum(xs[-q:]) / q)
 
-    stalls = series.get("pipeline/host_stall_ms", [])
-    if len(stalls) >= 8:
-        p50, p95 = _percentile(stalls, 0.5), _percentile(stalls, 0.95)
-        if p95 > max(5.0 * p50, 1.0):
-            out.append({
-                "kind": "stall_spike", "metric": "pipeline/host_stall_ms",
-                "detail": f"p95 {p95:.2f} ms vs p50 {p50:.2f} ms — "
-                          "prefetch is not keeping the pipe fed on some "
-                          "rounds (data source or H2D hiccups)",
-            })
     stale = series.get("async/staleness_mean", [])
     if len(stale) >= 8:
         first, last = quarter_means(stale)

@@ -1,7 +1,7 @@
 """Compiled-graph performance audit — measure the round from the artifact.
 
 Everything perf-shaped the repo asserted before this module was *analytic*:
-``CommLedger`` bytes come from ``bytes_per_round`` arithmetic, bench MFU
+``CommLedger`` bytes come from ``bytes_per_round`` arithmetic, MFU
 from a hand-maintained FLOPs model, and the PR-6 "no dense decode, every
 all-gather <= W*k" discipline from a test-time HLO grep. FetchSGD's whole
 claim is a communication/computation trade (arXiv:2007.07682), so the
@@ -22,10 +22,9 @@ PR regresses it. Three pieces live here:
     the classic invisible perf killer: a weak-type or dtype drift in one
     argument recompiles a minutes-long XLA program with no visible signal
     but the wall clock.
-  * ``chip_peak_flops`` / ``audited_mfu`` — the hardware peak table
-    (moved here from bench.py so bench, profile_round and the audit share
-    one denominator) and the audited-FLOPs MFU next to the legacy
-    hand-model line.
+  * ``chip_peak_flops`` / ``audited_mfu`` — the hardware peak table and
+    the audited-FLOPs MFU. Nothing the driver runs reads either (the
+    benchmark has its own ``benchmark/peaks.json``); ROADMAP D7.
 
 Degradation contract: every analysis is optional per backend/jax version —
 where jax 0.4.37 (this container) or the platform doesn't expose one, the
@@ -54,8 +53,8 @@ import re
 from contextlib import contextmanager
 from typing import Any, Dict, Optional
 
-# Peak dense-matmul throughput (bf16 FLOP/s) of the chips we bench on —
-# the MFU denominator (moved from bench.py r3 so every consumer shares it).
+# Peak dense-matmul throughput (bf16 FLOP/s) per chip kind — the MFU
+# denominator.
 # A chip that is not in the table is an error, not a default.
 PEAK_FLOPS = {"TPU v5 lite": 197e12, "TPU v5": 459e12, "TPU v4": 275e12}
 
@@ -89,7 +88,7 @@ def audited_mfu(flops_per_round: float, sec_per_round: float,
     """MFU from the COMPILED round's own FLOP count (cost_analysis), not
     the hand model. NB ``Compiled.cost_analysis()`` reports the PER-DEVICE
     SPMD module's FLOPs, so per-device figures pair with ``n_chips=1``
-    and one chip's peak (the bench default); pass ``n_chips`` only when
+    and one chip's peak; pass ``n_chips`` only when
     ``flops_per_round`` is a whole-program total from some other source."""
     return flops_per_round / (sec_per_round * peak_flops * max(n_chips, 1))
 

@@ -204,11 +204,10 @@ def train_loop(cfg: Config, session: FederatedSession, sampler: FedSampler,
                checkpointer=None):
     """The epoch loop (cv_train.py ~L120-240). Returns final val metrics.
 
-    Since the pipelined-execution PR this is a thin adapter over the
-    shared runner (train/runner.py), which owns the deferred-drain/
-    checkpoint/crash scaffold and the ``--pipeline_depth`` round-source
-    selection; only the CV-specific pieces (accuracy accumulation, eval,
-    the console row) live here. Checkpoint/resume semantics are the
+    A thin adapter over the shared runner (train/runner.py), which owns
+    the deferred-drain/checkpoint/crash scaffold and the host loop; only
+    the CV-specific pieces (accuracy accumulation, eval, the console row)
+    live here. Checkpoint/resume semantics are the
     runner's: a resumed run fast-forwards to the checkpointed round
     (sampler + lr schedule + fedsim environment are pure functions of the
     step, so this reproduces the uninterrupted run exactly)."""

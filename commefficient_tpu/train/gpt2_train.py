@@ -196,9 +196,8 @@ def train_loop(cfg: Config, session: FederatedSession, sampler: FedSampler,
                checkpointer=None, gcfg=None):
     """Epoch loop with the reference's eval: nll -> ppl + MC accuracy
     (gpt2_train.py ~L280-360). A thin adapter over the shared runner
-    (train/runner.py — same scaffold and ``--pipeline_depth`` round-source
-    selection as cv_train); honors checkpoint_every/resume like
-    cv_train.train_loop."""
+    (train/runner.py — same scaffold and host loop as cv_train); honors
+    checkpoint_every/resume like cv_train.train_loop."""
     from commefficient_tpu.train.runner import run_train_loop
 
     return run_train_loop(

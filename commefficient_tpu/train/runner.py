@@ -1,35 +1,31 @@
-"""The shared train-loop runner — one epoch/drain/crash scaffold for both
-workload entries.
+"""The shared train-loop runner — one epoch/drain/crash scaffold for every
+workload entry.
 
-``cv_train.train_loop`` and ``gpt2_train.train_loop`` used to carry
-near-identical copies of the round loop: the deferred-drain buffer and its
-``live_drain`` crash-flush closure, checkpoint ``will_save``-then-drain
+``run_train_loop`` owns the epoch loop, the deferred-drain buffer and its
+``live_drain`` crash-flush closure, the checkpoint ``will_save``-then-drain
 ordering, ``DivergenceError`` surfacing, the telemetry-rider/controller/
-perf-observability construction order, and the resume fast-forward. The
-pipelined round engine (pipeline/) would have had to be wired TWICE into
-that duplication — so the scaffold now lives here once, and each entry
-supplies only its workload-specific pieces through ``WorkloadHooks``
+perf-observability construction order and the resume fast-forward; each
+entry supplies only its workload-specific pieces through ``WorkloadHooks``
 (accumulation, eval, the console row, the optional per-epoch hook).
 
-Round-source selection is the ONE place ``cfg.pipeline_depth`` is read:
-depth 0 runs ``_sync_epoch_rounds`` — the legacy synchronous loop, moved
-here verbatim (nothing pipeline-related constructed; golden parity and
-level-0 HLO untouched) — while depth >= 1 builds a
-``pipeline.PipelinedRounds`` engine whose prefetcher overlaps round
-t+1..t+depth's host work and H2D with round t's device compute. Both
-sources yield the same ``(step, lr, metrics)`` triples to the same drain/
-checkpoint/crash machinery, which is what makes the two execution modes
-bit-exact (tests/test_pipeline.py pins it end to end).
+There is one host loop, ``_sync_epoch_rounds``: the sampler runs two
+rounds ahead on a thread (``data/sampler.py::prefetch``), each round is
+staged and dispatched in order, and XLA's asynchronous dispatch hides the
+rest of the host. The one alternative schedule is the buffered-
+asynchronous engine (``--async_buffer K``, asyncfed/), an object with
+``start``/``epoch_rounds``/``restart``/``close`` built only when that flag
+is set. Both yield the same ``(step, lr, metrics)`` triples to the same
+drain/checkpoint/crash machinery.
 
-Since the self-healing PR the scaffold also hosts the resilience/ layer,
-wired once for both entries: a ``DivergenceError`` raised by any drain is
-offered to the ``ResilienceRider`` first — a successful rollback restores
-the last drain-certified vault snapshot, restarts the round source at the
-rollback round (the pipelined engine quiesces its prefetch window like a
-checkpoint fence) and re-enters the epoch loop; only an unrecoverable
-divergence (policy 'none', recoveries exhausted, no snapshot) reaches the
-legacy crash path. A preemption request (SIGTERM/SIGINT rider or the
-seeded ``preempt@R`` chaos event) is honored at round granularity: drain,
+The scaffold also hosts the resilience/ layer: a ``DivergenceError``
+raised by any drain is offered to the ``ResilienceRider`` first — a
+successful rollback restores the last drain-certified vault snapshot,
+restarts the round source at the rollback round (the plain loop simply
+re-enters at that step; the asyncfed engine rebuilds its in-flight
+window) and re-enters the epoch loop; only an unrecoverable divergence
+(policy 'none', recoveries exhausted, no snapshot) reaches the crash
+path. A preemption request (SIGTERM/SIGINT rider or the seeded
+``preempt@R`` chaos event) is honored at round granularity: drain,
 ``maybe_save(force=True)``, then ``PreemptShutdown`` — which rides the
 normal crash teardown (flight dump, ledger write, spans close) out to the
 entries' distinct ``EXIT_PREEMPTED`` code. ``--recover_policy none``
@@ -81,9 +77,9 @@ class WorkloadHooks:
 
 def _sync_epoch_rounds(cfg, session, sampler, lr_fn, spans, profiler,
                        epoch, start_step, steps_per_epoch):
-    """The legacy synchronous round source (pipeline_depth 0): assemble,
-    stage and dispatch each round on the critical path, exactly the
-    pre-runner train-loop body. Yields ``(step, lr, metrics)``."""
+    """The host loop: take each round's draw from the sampler's
+    look-ahead thread, stage it and dispatch it, in order. Yields
+    ``(step, lr, metrics)``."""
     use_idx = getattr(session, "_dev_data", None) is not None
     rounds = (
         prefetch(sampler.epoch_indices(epoch))
@@ -128,7 +124,7 @@ def run_train_loop(cfg, session, sampler, hooks: WorkloadHooks,
     honors ``cfg.checkpoint_every``/``cfg.resume``: a resumed run
     fast-forwards to the checkpointed round (sampler, lr schedule and the
     fedsim environment are pure functions of the step, so this reproduces
-    the uninterrupted run exactly — at any pipeline depth)."""
+    the uninterrupted run exactly)."""
     steps_per_epoch = sampler.steps_per_epoch()
     num_rounds = steps_per_epoch * cfg.num_epochs
     if session.fedsim_env is not None:
@@ -158,7 +154,7 @@ def run_train_loop(cfg, session, sampler, hooks: WorkloadHooks,
     if cfg.profile_rounds:
         # --profile_rounds A-B (telemetry/trace.py ProfilerWindow): a
         # CLI-chosen jax.profiler capture window, stacked behind the same
-        # profiler facade the engines already drive — no engine changes.
+        # profiler facade the round sources already drive.
         # The entry/exit fence syncs on the params so deferred applies /
         # pending writebacks retire OUTSIDE the captured rounds.
         import os
@@ -221,7 +217,7 @@ def run_train_loop(cfg, session, sampler, hooks: WorkloadHooks,
     val = {}
     step = 0
     # the current epoch's drain closure, reachable from the crash handler:
-    # a BudgetExhaustedError, a prefetch-worker fault, or any mid-epoch
+    # a BudgetExhaustedError, a staging-worker fault, or any mid-epoch
     # crash fires BEFORE the deferred epoch-end drain, so without this
     # flush the ledger/flight would be blind to the crashed epoch's
     # completed rounds
@@ -236,44 +232,12 @@ def run_train_loop(cfg, session, sampler, hooks: WorkloadHooks,
                 if spans is not None:
                     spans.resume_at(step)
                 print(f"resumed from checkpoint at round {step}")
-        # pipelined round engine (pipeline/): ONLY built at depth >= 1 —
-        # the one place both entries' pipelining is wired. Constructed
-        # AFTER the restore so the prefetcher starts at the resumed step
-        # (its inputs are pure functions of the round index, so the
-        # staged stream is the uninterrupted run's).
-        if cfg.scan_rounds > 1:
-            # scan-over-rounds engine (pipeline/scan_engine.py): K rounds
-            # per XLA dispatch on the device-resident index path; mutually
-            # exclusive with pipeline_depth / the control plane (Config
-            # validated). Built AFTER the restore like the pipelined
-            # engine — its staging is a pure function of the round index.
-            from commefficient_tpu.pipeline import ScanRounds
-
-            engine = ScanRounds(
-                cfg, session, sampler, lr_fn, num_rounds,
-                steps_per_epoch=steps_per_epoch, spans=spans,
-                profiler=profiler,
-            ).start(step)
-            print(f"scan engine: up to {cfg.scan_rounds} rounds/dispatch "
-                  "(device-resident lax.scan; pinned equal to per-round "
-                  "dispatch on params and drained scalars)")
-        elif cfg.pipeline_enabled:
-            from commefficient_tpu.pipeline import PipelinedRounds
-
-            engine = PipelinedRounds(
-                cfg, session, sampler, lr_fn, num_rounds,
-                steps_per_epoch=steps_per_epoch, spans=spans,
-                profiler=profiler,
-            ).start(step)
-            print(f"pipeline: depth={cfg.pipeline_depth} (host staging + "
-                  "H2D overlap device compute; bit-exact vs depth 0)")
-        elif getattr(cfg, "asyncfed_enabled", False):
+        if cfg.asyncfed_enabled:
             # buffered-asynchronous engine (asyncfed/): each engine step
             # is one SERVER UPDATE consuming K of the C in-flight cohorts'
-            # contributions, staleness-discounted. Mutually exclusive with
-            # the pipeline/scan engines (Config-validated); built after
-            # the restore like them (the schedule is a pure function of
-            # the config, the window rebuilds at the resumed update).
+            # contributions, staleness-discounted. Built AFTER the restore
+            # so its window starts at the resumed update (the schedule is
+            # a pure function of the config).
             from commefficient_tpu.asyncfed import AsyncFederation
 
             engine = AsyncFederation(
@@ -294,7 +258,7 @@ def run_train_loop(cfg, session, sampler, hooks: WorkloadHooks,
     except BaseException:
         # a pre-loop failure (restore walk-back exhausted, engine start,
         # baseline capture) never reaches the finally below — join the
-        # already-started prefetch worker and restore the signal
+        # already-started staging worker and restore the signal
         # dispositions before propagating, or a surviving process
         # (embedding, pytest) leaks the staging thread and keeps
         # flag-only SIGTERM/SIGINT handlers nobody polls
@@ -392,7 +356,7 @@ def run_train_loop(cfg, session, sampler, hooks: WorkloadHooks,
                                 extras = ({"acc": dict(acc_state)}
                                           if isinstance(acc_state, dict)
                                           else {})
-                                if hasattr(engine, "snapshot_extra"):
+                                if engine is not None:
                                     extras["asyncfed"] = (
                                         engine.snapshot_extra()
                                     )
@@ -473,14 +437,12 @@ def run_train_loop(cfg, session, sampler, hooks: WorkloadHooks,
                         # the next boundary resumes without the fork
                         checkpointer.resave(session, step)
                 if engine is not None:
-                    if hasattr(engine, "restore_extra"):
-                        # hand the snapshot's in-flight window back before
-                        # the restart rebuilds it (asyncfed: pending
-                        # launches restore verbatim -> bit-identical
-                        # replay; absent/None -> deterministic cold
-                        # rebuild at the rollback point)
-                        engine.restore_extra(extras.get("asyncfed"))
-                    engine.restart(step)  # quiesce + restage the window
+                    # hand the snapshot's in-flight window back before the
+                    # restart rebuilds it (pending launches restore
+                    # verbatim -> bit-identical replay; absent/None ->
+                    # deterministic cold rebuild at the rollback point)
+                    engine.restore_extra(extras.get("asyncfed"))
+                    engine.restart(step)
                 m = resil.manager
                 print(f"resilience: recovered from divergence at round "
                       f"{e.step} — rolled back to round {step} under "
@@ -513,7 +475,7 @@ def run_train_loop(cfg, session, sampler, hooks: WorkloadHooks,
         raise
     finally:
         if engine is not None:
-            engine.close()  # join the prefetch worker (crash paths too)
+            engine.close()  # join the staging worker (crash paths too)
         profiler.close()
         if spans is not None:
             session.spans = None

@@ -181,12 +181,11 @@ class Config:
     # the telemetry_level-0 discipline), "host" (pinned-numpy bank in host
     # RAM; only the round's W participant rows cross PCIe each round, C
     # bounded by host DRAM), "mmap" (the same cohort-streaming contract
-    # over a memory-mapped file; C bounded by disk). host/mmap stream
-    # cohort rows through the pipeline prefetcher when one is active and
-    # write back asynchronously after the drain fence, so the compiled
-    # round's HLO carries no [C, D]-scale gather and the strict O(W·k)
-    # sparse-aggregate bound holds with no exemption (README
-    # "Host-resident client state").
+    # over a memory-mapped file; C bounded by disk). host/mmap gather the
+    # cohort's rows inside its round and write back asynchronously after
+    # the drain fence, so the compiled round's HLO carries no [C, D]-scale
+    # gather and the strict O(W·k) sparse-aggregate bound holds with no
+    # exemption (README "Host-resident client state").
     client_store: str = "device"
     # LRU device cache capacity (rows) for hot cohort rows under a
     # host/mmap store — availability models make some clients far more
@@ -445,44 +444,14 @@ class Config:
     # know steps_per_epoch).
     chaos: str = ""
 
-    # --- pipelined round execution (commefficient_tpu/pipeline/;
-    # TPU-native — the reference's host loop is fully serial) ---
-    # Rounds of host-side round work (non-IID sampler draw + batch
-    # assembly, fedsim environment realization, schedule lr, eager H2D
-    # staging onto the mesh) realized AHEAD of the device by a background
-    # worker thread, so round t+1's host serial time overlaps round t's
-    # device compute. 0 (default): fully synchronous — NOTHING
-    # pipeline-related is constructed and the round stays bit-identical
-    # to a pre-pipeline build (the telemetry_level-0 discipline; golden
-    # parity recordings pin it). Any depth is BIT-EXACT vs depth 0:
-    # every prefetched input is a pure function of (seed, stream,
-    # round_idx), controller decisions/drains keep their synchronous
-    # order, and checkpoint saves fence the window (README "Pipelined
-    # round execution" documents the determinism contract).
-    pipeline_depth: int = 0
-    # Scan-over-rounds device-resident execution (pipeline/scan_engine.py):
-    # K > 1 executes K rounds per XLA dispatch via ``lax.scan`` on the
-    # device-resident index path — sampler indices staged per EPOCH (one
-    # H2D for the whole epoch's [spe, W, B] draws), telemetry packs
-    # stacked by the scan and drained at scan exit, per-round python
-    # dispatch overhead amortized K-fold. Blocks are CHOPPED at every
-    # point the synchronous loop would act on state (epoch end,
-    # checkpoint_every, snapshot_every, controller... see the engine
-    # docstring), so the drained scalar sequence and the params are
-    # pinned equal to K=1. 0/1 (default): the per-round dispatch path,
-    # bit-untouched. Requires device_data (the index round) and is
-    # mutually exclusive with the control plane, pipeline_depth and
-    # preemption sources (validated at construction / train entry).
-    scan_rounds: int = 0
-
     # --- buffered-asynchronous federation (commefficient_tpu/asyncfed/;
     # FedBuff-style — the reference's round is a synchronous barrier
     # over num_workers) ---
     # K: the server applies an update once K of the in-flight cohorts'
     # contributions have arrived. 0 (default): synchronous rounds —
     # NOTHING asyncfed-related is constructed and the round stays
-    # bit-identical to a pre-asyncfed build (the telemetry_level-0 /
-    # pipeline_depth-0 discipline). The correctness anchor:
+    # bit-identical to a pre-asyncfed build (the telemetry_level-0
+    # discipline). The correctness anchor:
     # async_buffer=num_workers with async_concurrency=1 and
     # staleness_exponent=0 reduces BIT-IDENTICALLY to the synchronous
     # round across every mode/error-type/fedsim combination
@@ -786,7 +755,6 @@ class Config:
         self._validate_client_store()
         self._validate_sketch_fused_bwd()
         self._validate_overlap_collectives()
-        self._validate_scan_rounds()
         if self.num_workers % self.num_devices != 0:
             raise ValueError(
                 "num_workers must be divisible by num_devices "
@@ -867,11 +835,6 @@ class Config:
                 f"beyond the first compile) or None (count only), got "
                 f"{self.max_retraces}"
             )
-        if self.pipeline_depth < 0:
-            raise ValueError(
-                f"pipeline_depth must be >= 0 (0 = synchronous), got "
-                f"{self.pipeline_depth}"
-            )
         self._validate_asyncfed()
         self._validate_multihost()
         self._validate_control()
@@ -902,20 +865,6 @@ class Config:
                 "width W, so a mid-run resize would orphan in-flight "
                 "slots — model elastic participation there with "
                 "availability='poisson' instead"
-            )
-        if self.scan_rounds > 1:
-            raise ValueError(
-                "fleet events are incompatible with scan_rounds > 1: a "
-                "scanned block compiles ONE width for K rounds, and a "
-                "resize inside the block could not swap programs — drop "
-                "scan_rounds or the fleet events"
-            )
-        if self.pipeline_depth > 0:
-            raise ValueError(
-                "fleet events are incompatible with pipeline_depth > 0 "
-                "for now: the prefetcher stages round payloads at the "
-                "base width ahead of the resize decision point — run "
-                "synchronous rounds with the fleet plan"
             )
         if self.fsdp:
             raise ValueError(
@@ -1052,62 +1001,13 @@ class Config:
                 f"{self.overlap_collectives!r}"
             )
 
-    def _validate_scan_rounds(self) -> None:
-        """Scan-over-rounds flags (pipeline/scan_engine.py). The engine
-        executes K rounds per dispatch, so anything that must act
-        host-side BETWEEN two arbitrary rounds is incompatible and
-        refused here; boundaries the engine can honor by CHOPPING blocks
-        (checkpoints, snapshots, epoch ends) need no constraint."""
-        if self.scan_rounds < 0:
-            raise ValueError(
-                f"scan_rounds must be >= 0 (0/1 = per-round dispatch), "
-                f"got {self.scan_rounds}"
-            )
-        if self.scan_rounds <= 1:
-            return
-        if not self.device_data:
-            raise ValueError(
-                "scan_rounds > 1 runs the device-resident index round "
-                "inside lax.scan — the epoch's batches must already be "
-                "in HBM; set device_data=True (host-batch rounds would "
-                "serialize on H2D anyway)"
-            )
-        if self.client_state_hosted or self.fsdp:
-            raise ValueError(
-                "scan_rounds > 1 needs the device-resident index path, "
-                "which excludes --client_store host|mmap and fsdp "
-                "(host-resident rows cross PCIe between rounds)"
-            )
-        if self.control_enabled:
-            raise ValueError(
-                "scan_rounds > 1 is mutually exclusive with the control "
-                "plane: the controller decides immediately-pre-dispatch "
-                "per ROUND, and a scanned block admits no host decision "
-                "between its rounds — run one or the other"
-            )
-        if self.pipeline_depth > 0:
-            raise ValueError(
-                "scan_rounds > 1 already stages the whole epoch's "
-                "sampler indices up front (a superset of the "
-                "prefetcher's depth-K window on the index path) — drop "
-                "pipeline_depth"
-            )
-        if self.preempt_signals or "preempt@" in self.chaos:
-            raise ValueError(
-                "scan_rounds > 1 cannot honor round-granular preemption: "
-                "the device state only exists at block boundaries, so a "
-                "mid-block preempt would checkpoint the wrong round — "
-                "disable preempt_signals / the preempt@ chaos event"
-            )
-
     def _validate_asyncfed(self) -> None:
         """Buffered-asynchronous federation flags (asyncfed/). The async
         engine launches overlapping per-client cohorts and applies a
         staleness-weighted update once K contributions arrive, so anything
         that assumes one cohort per server version — or that removes the
         per-client transmit rows the launch program ships — is refused
-        here at construction instead of at first trace (the
-        _validate_scan_rounds discipline)."""
+        here at construction instead of at first trace."""
         if self.async_buffer < 0:
             raise ValueError(
                 f"async_buffer must be >= 0 (0 = synchronous barrier "
@@ -1165,19 +1065,6 @@ class Config:
                 "async_buffer > 0 currently requires HBM-resident client "
                 "state on the replicated engine (--client_store host|mmap "
                 "and fsdp run their own round builders)"
-            )
-        if self.scan_rounds > 1:
-            raise ValueError(
-                "async_buffer > 0 is mutually exclusive with "
-                "scan_rounds > 1: a scanned block admits no host-side "
-                "arrival buffering between its rounds"
-            )
-        if self.pipeline_depth > 0:
-            raise ValueError(
-                "async_buffer > 0 supersedes pipeline_depth: the asyncfed "
-                "engine owns its own cohort prefetch window "
-                "(async_concurrency cohorts in flight) — drop "
-                "pipeline_depth"
             )
         if self.preempt_signals or "preempt@" in self.chaos:
             raise ValueError(
@@ -1483,7 +1370,7 @@ class Config:
         """True when the divergence rollback-and-recover machinery must be
         built (resilience/ vault + manager). False keeps the train loop on
         the untouched fast path with nothing resilience-related
-        constructed — the fedsim/control/pipeline gate discipline. (The
+        constructed — the fedsim/control gate discipline. (The
         preemption guard has its own gate: ``preempt_signals`` or a
         ``preempt@R`` chaos event.)"""
         return self.recover_policy != "none"
@@ -1500,19 +1387,11 @@ class Config:
         return self.client_store in ("host", "mmap")
 
     @property
-    def pipeline_enabled(self) -> bool:
-        """True when the pipelined round engine must be built (pipeline/
-        package). False keeps the train loop on the legacy synchronous
-        path with nothing pipeline-related constructed — the
-        fedsim_enabled/control_enabled discipline."""
-        return self.pipeline_depth > 0
-
-    @property
     def asyncfed_enabled(self) -> bool:
         """True when the buffered-asynchronous engine must be built
         (asyncfed/ package). False keeps the train loop on the synchronous
         engines with nothing asyncfed-related constructed — the
-        fedsim_enabled/pipeline_enabled gate discipline."""
+        fedsim_enabled/control_enabled gate discipline."""
         return self.async_buffer > 0
 
     @property

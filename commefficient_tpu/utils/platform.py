@@ -29,8 +29,8 @@ COMPILE_CACHE_DIR = os.path.join(
 
 def configure_compile_cache() -> str:
     """Place JAX's persistent compilation cache; returns the directory in
-    force. Called first thing by every entry point (both train mains,
-    bench.py, ``__graft_entry__``, chip_smoke.py).
+    force. Called first thing by every entry point (the train mains,
+    ``benchmark/run.py``, ``__graft_entry__``, chip_smoke.py).
 
     Where ``JAX_COMPILATION_CACHE_DIR`` is set the caller owns the cache:
     JAX reads the variable itself and nothing is set in code. Otherwise

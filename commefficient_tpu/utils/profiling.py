@@ -1,28 +1,23 @@
 """Profiling hooks — ``jax.profiler`` traces around a window of rounds,
-plus the shared micro-benchmark helpers (``fence``/``timeit``).
+plus ``fence``, the one way the host waits on a device value.
 
 The reference's only tracing is a console Timer around epoch phases
 (SURVEY.md §5 "Tracing/profiling"); the rebuild equivalent is a real XLA
 trace viewable in TensorBoard/Perfetto. ``StepProfiler`` wraps a few
 steady-state rounds (after compile/warmup) so the trace shows the real hot
-path, not compilation. ``fence``/``timeit`` used to live (duplicated) in
-scripts/profile_round.py; they are here so bench.py, profile_round and the
-telemetry span recorder all share one fencing/warmup discipline.
+path, not compilation. The train runner drives it every round; the
+telemetry span recorder and the ``--profile_rounds`` window share its
+fencing and warm-up discipline.
 """
 
 from __future__ import annotations
 
-import time
-
 import jax
 
 # The first executed round compiles and the second fills the other donated-
-# buffer layout (see bench.py's warmup note); a trace window that includes
-# them measures XLA, not the round. start_step=0 used to do exactly that —
-# now every window starts at least this many steps after the first executed
-# round, and ``timeit`` warms with exactly this many calls (one warm call
-# used to leave the second donated-buffer layout uncompiled, so the first
-# timed rep paid a compile on donated paths).
+# buffer layout; a trace window that includes them measures XLA, not the
+# round. Every window starts at least this many steps after the first
+# executed round.
 MIN_WARMUP_STEPS = 2
 
 
@@ -32,26 +27,6 @@ def fence(x) -> float:
     assert finite."""
     jax.block_until_ready(x)
     return float(jax.tree.leaves(x)[0].ravel()[0])
-
-
-def timeit(name, fn, *args, reps: int = 10, warmup: int = MIN_WARMUP_STEPS):
-    """Mean ms/call of ``fn(*args)`` over ``reps``, printed and returned.
-
-    Warms with ``warmup`` calls (default MIN_WARMUP_STEPS=2: the first
-    compiles, the second fills the other donated-buffer layout) and fences
-    once before and once after the timed loop (steady-state pipelined
-    dispatch, the bench.py methodology)."""
-    out = None
-    for _ in range(max(warmup, 1)):
-        out = fn(*args)
-    fence(out)
-    t0 = time.perf_counter()
-    for _ in range(reps):
-        out = fn(*args)
-    fence(out)
-    dt = (time.perf_counter() - t0) / reps * 1e3
-    print(f"{name:42s} {dt:8.2f} ms")
-    return dt
 
 
 class StepProfiler:

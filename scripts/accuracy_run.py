@@ -113,8 +113,7 @@ def main():
         telemetry_level=args.telemetry_level, logdir=args.logdir,
         # the compiled-round audit costs one extra XLA compile PER RUN
         # x a dozen table rows — this suite
-        # measures accuracy-vs-bytes, not perf; bench.py owns the audited
-        # perf numbers
+        # measures accuracy-vs-bytes, not perf (that is benchmark/run.py's)
         perf_audit=False,
         # same opt-out for the critical-path run report: a dozen table
         # rows would each write a run_report.json into the shared logdir

@@ -10,9 +10,8 @@ and writes ``run_report.json`` next to them:
   * critical-path attribution fractions summing to 1 (idle included —
     unattributed wall-clock is a finding, not a rounding error),
   * the modal binding stage + per-stage binding counts,
-  * anomaly flags: stall spikes (pipeline/host_stall_ms), staleness
-    drift (async/staleness_mean), cache-hit collapse
-    (clientstore/cache_hit_rate).
+  * anomaly flags: staleness drift (async/staleness_mean), cache-hit
+    collapse (clientstore/cache_hit_rate).
 
     python scripts/analyze_run.py RUN_DIR [RUN_DIR ...] [--out NAME]
 

@@ -1,5 +1,9 @@
 """Bench regression gate: compare the latest BENCH_*.json to the trajectory.
 
+(Dead since PR 31 deleted ``bench.py``: nothing writes these records any
+more, the driver judges ``benchmark/run.py`` against ``PERF_LEDGER.jsonl``.
+Kept with its 18 tests as ROADMAP debt D7.)
+
 The repo carries one ``BENCH_rNN.json`` per build round (the driver wraps
 bench.py's stdout JSON line in ``{"parsed": {...}}``), but until this
 script nothing *read* the trajectory — a 20% throughput regression rode a

@@ -60,10 +60,9 @@ from pathlib import Path
 # communication-budget PR): control/* scalar namespace, the ledger's
 # per-rung "rungs" accounting block (cum bytes == sum over rungs of
 # active-rung bytes, live-count-weighted under masking), header/flight
-# "controller" block; v5 (pipelined round execution PR): pipeline/*
-# scalar namespace (occupancy in [0, 1] and integer staged_rounds
-# enforced below), spans thread_name "M" metadata events + per-lane
-# tids; v6 (self-healing training PR): resilience/* scalar namespace
+# "controller" block; v5: spans thread_name "M" metadata events +
+# per-lane tids (its pipeline/* scalar namespace left with the engines
+# that wrote it); v6 (self-healing training PR): resilience/* scalar namespace
 # (integer counters, preempt_requested in {0, 1}, rollback_round >= -1 —
 # enforced below), the flight dump's recovery_history block (one entry
 # per divergence rollback), and the fedsim/preempt scheduled-preemption
@@ -120,7 +119,7 @@ KNOWN_SCHEMA_VERSIONS = (1, 2, 3, 4, 5, 6, 7, 8, 9, 10, 11, 12, 13)
 # scalar-name schema: bare "lr", or a namespaced name under one of the
 # documented prefixes (README "Observability")
 SCALAR_PREFIXES = ("train/", "val/", "diag/", "comm/", "fedsim/", "xla/",
-                   "control/", "pipeline/", "resilience/", "async/",
+                   "control/", "resilience/", "async/",
                    "clientstore/", "trace/", "multihost/", "fleet/")
 
 # pinned copy of telemetry.trace.STAGES (this checker imports nothing
@@ -240,43 +239,9 @@ def _check_scalar_value(v, name: str, where: str) -> None:
         )
 
 
-def _check_pipeline_scalar(name: str, v, where: str) -> None:
-    """v5 ``pipeline/*`` value invariants. These are host-computed gauges
-    (never legitimately non-finite, unlike a diverging loss), so the
-    nan/inf markers are rejected too: ``occupancy`` is staged/depth and
-    must be a real fraction of the window; ``staged_rounds`` is a queue
-    COUNT and must be a non-negative integer — a fractional or negative
-    value means the writer miscounted, exactly what this check catches."""
-    if not name.startswith("pipeline/"):
-        return
-    if isinstance(v, bool) or not isinstance(v, (int, float)):
-        raise SchemaError(
-            f"{where}: {name!r} must be a finite number (host gauge), "
-            f"got {v!r}"
-        )
-    if name == "pipeline/occupancy" and not 0.0 <= v <= 1.0:
-        raise SchemaError(
-            f"{where}: pipeline/occupancy {v} outside [0, 1] — occupancy "
-            "is staged_rounds / pipeline_depth by definition"
-        )
-    if name == "pipeline/staged_rounds" and (v != int(v) or v < 0):
-        raise SchemaError(
-            f"{where}: pipeline/staged_rounds {v} is not a non-negative "
-            "integer — it counts whole staged rounds"
-        )
-    if name == "pipeline/scan_rounds_per_dispatch" and (
-            v != int(v) or v < 1):
-        raise SchemaError(
-            f"{where}: pipeline/scan_rounds_per_dispatch {v} is not a "
-            "positive integer — it counts the scanned block's whole "
-            "rounds (scan engine, pipeline/scan_engine.py)"
-        )
-
-
 def _check_resilience_scalar(name: str, v, where: str) -> None:
-    """v6 ``resilience/*`` value invariants. Host-computed gauges like the
-    pipeline/* family (never legitimately non-finite, so the nan/inf
-    markers are rejected too): ``recoveries`` / ``rung_demotions`` /
+    """v6 ``resilience/*`` value invariants. Host-computed gauges (never
+    legitimately non-finite, so the nan/inf markers are rejected too): ``recoveries`` / ``rung_demotions`` /
     ``blacklisted_clients`` COUNT whole events/clients and must be
     non-negative integers; ``preempt_requested`` is a 0/1 flag;
     ``rollback_round`` is the last rollback target round, -1 when the run
@@ -589,7 +554,6 @@ def validate_metrics_jsonl(path) -> int:
             if "value" not in rec:
                 raise SchemaError(f"{where}: missing required field 'value'")
             _check_scalar_value(rec["value"], name, where)
-            _check_pipeline_scalar(name, rec["value"], where)
             _check_resilience_scalar(name, rec["value"], where)
             _check_async_scalar(name, rec["value"], where)
             _check_clientstore_scalar(name, rec["value"], where)
@@ -781,7 +745,6 @@ def validate_flight(path) -> dict:
         for name, v in scalars.items():
             _check_scalar_name(name, w, allow_bare_aux=True)
             _check_scalar_value(v, name, w)
-            _check_pipeline_scalar(name, v, w)
             _check_resilience_scalar(name, v, w)
             _check_async_scalar(name, v, w)
             _check_clientstore_scalar(name, v, w)

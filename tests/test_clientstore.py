@@ -141,24 +141,22 @@ def test_lru_eviction_write_through():
 
 
 # ---------------------------------------------------------------------------
-# streamer: staleness versioning + async writeback fence
+# streamer: async writeback fence
 # ---------------------------------------------------------------------------
 
-def test_streamer_staleness_and_writeback_fence():
+def test_streamer_writeback_fence():
     s = CohortStreamer(
         vel_store=HostStore(num_rows=8, row_dim=2),
         err_store=HostStore(num_rows=8, row_dim=2),
         num_clients=8,
     )
-    cohort = s.gather(np.array([1, 2]))
-    assert not s.is_stale(np.array([1, 2]), cohort.version)
+    before = s.gather(np.array([1, 2]))
+    np.testing.assert_array_equal(before.vel, np.zeros((2, 2), np.float32))
     new = np.ones((2, 2), np.float32)
     s.scatter(np.array([2, 5]), new, 2 * new)
-    # overlap (client 2) -> stale; disjoint cohort -> still fresh
-    assert s.is_stale(np.array([1, 2]), cohort.version)
-    assert not s.is_stale(np.array([1, 3]), cohort.version)
-    # a regather observes the async write (gather waits on the pending
-    # entry for overlapping ids)
+    # the next gather observes the async write (it waits on the pending
+    # entry for overlapping ids), so a client drawn in two consecutive
+    # rounds reads the row the first round wrote
     fresh = s.gather(np.array([2, 5]))
     np.testing.assert_array_equal(fresh.vel, new)
     np.testing.assert_array_equal(fresh.err, 2 * new)
@@ -172,13 +170,16 @@ def test_streamer_staleness_and_writeback_fence():
     s.close()
 
 
-def test_streamer_load_invalidates_staged_cohorts():
+def test_streamer_load_wins_over_pending_writeback():
     s = CohortStreamer(vel_store=HostStore(num_rows=4, row_dim=2),
                        num_clients=4)
-    cohort = s.gather(np.array([0, 1]))
+    s.scatter(np.array([0, 1]), np.ones((2, 2), np.float32), ())
     bank = np.full((4, 2), 3.0, np.float32)
-    s.load_vel(bank)  # checkpoint/vault restore
-    assert s.is_stale(np.array([0, 1]), cohort.version)
+    # checkpoint/vault restore: the load drains the pending write first,
+    # so pre-restore rows cannot land over the restored bank
+    s.load_vel(bank)
+    np.testing.assert_array_equal(s.gather(np.array([0, 1])).vel,
+                                  bank[[0, 1]])
     np.testing.assert_array_equal(s.gather(np.array([2])).vel, bank[[2]])
     assert s.gather(np.array([0])).err == ()  # absent bank convention
     s.close()
@@ -430,34 +431,6 @@ def test_vault_rollback_hosted_replay_bitwise():
     replay_params, replay_vel = two_more()
     np.testing.assert_array_equal(replay_params, first_params)
     np.testing.assert_array_equal(replay_vel, first_vel)
-    sess.close_client_store()
-
-
-# ---------------------------------------------------------------------------
-# pipeline: prefetched cohorts (+ staleness regather) stay bit-exact
-# ---------------------------------------------------------------------------
-
-def test_pipelined_hosted_bitwise_matches_sync():
-    """depth 2 over 12 clients / 8 workers: cohorts collide inside the
-    window every round, so this exercises the stale-cohort regather."""
-    from commefficient_tpu.pipeline.engine import PipelinedRounds
-
-    # sync twin (plain loop, fixed lr)
-    sync = _run_store(n_rounds=6, client_store="host", telemetry_level=0)
-
-    cfg = Config(**{**KW, **BASE}, client_store="host", pipeline_depth=2)
-    ds, params, loss_fn = _setup(cfg.num_clients)
-    sess = FederatedSession(cfg, params, loss_fn)
-    sampler = FedSampler(ds, num_workers=cfg.num_workers,
-                         local_batch_size=cfg.local_batch_size, seed=1)
-    eng = PipelinedRounds(cfg, sess, sampler, lambda s: 0.3, num_rounds=6,
-                          steps_per_epoch=6).start()
-    losses = [float(m["loss"]) for _, _, m in eng.epoch_rounds(0, 0)]
-    eng.close()
-    np.testing.assert_array_equal(np.asarray(losses), sync["losses"][:6])
-    np.testing.assert_array_equal(_final_vec(sess), sync["params"])
-    np.testing.assert_array_equal(np.asarray(sess.host_vel), sync["vel"])
-    assert sess.retrace_sentinel.retraces == 0
     sess.close_client_store()
 
 

@@ -43,12 +43,6 @@ def test_validation():
         Config(synthetic_variant="bogus")
     with pytest.raises(ValueError, match="sketch_backend"):
         Config(sketch_backend="cuda")
-    with pytest.raises(ValueError, match="pipeline_depth"):
-        Config(pipeline_depth=-1)
-    # 0 = synchronous (nothing constructed), any positive depth is legal
-    assert not Config(pipeline_depth=0).pipeline_enabled
-    assert Config(pipeline_depth=3).pipeline_enabled
-    assert parse_args(["--pipeline_depth", "2"]).pipeline_depth == 2
 
 
 def test_sketch_backend_cli_reaches_spec():

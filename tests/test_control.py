@@ -616,33 +616,33 @@ def test_controller_state_checkpoint_roundtrip(tmp_path):
 # cv_train e2e (the PR acceptance run)
 # ---------------------------------------------------------------------------
 
-def _rung_sequence(logdir):
-    """{step: rung} from every metrics.jsonl under ``logdir``."""
-    out = {}
+def _metric_records(logdir):
+    """Every scalar record under ``logdir`` as (name, value, step) in file
+    order: the unit two runs are compared in (wall time ``t`` may differ;
+    ``trace/*`` and ``xla/exposed_collective_ms`` are host wall-clock)."""
+    out = []
     for root, _, files in os.walk(logdir):
-        for f in files:
+        for f in sorted(files):
             if f != "metrics.jsonl":
                 continue
             with open(os.path.join(root, f)) as fh:
                 for line in fh:
                     rec = json.loads(line)
-                    if rec.get("name") == "control/rung":
-                        out[rec["step"]] = rec["value"]
+                    if "name" not in rec or rec["name"].startswith(
+                            ("trace/", "xla/exposed_collective_ms")):
+                        continue
+                    out.append((rec["name"], rec["value"], rec["step"]))
     return out
 
 
 def _scalar_trail(logdir, name):
-    out = {}
-    for root, _, files in os.walk(logdir):
-        for f in files:
-            if f != "metrics.jsonl":
-                continue
-            with open(os.path.join(root, f)) as fh:
-                for line in fh:
-                    rec = json.loads(line)
-                    if rec.get("name") == name:
-                        out[rec["step"]] = rec["value"]
-    return out
+    """{step: value} of one scalar from every metrics.jsonl under
+    ``logdir``."""
+    return {s: v for n, v, s in _metric_records(logdir) if n == name}
+
+
+def _rung_sequence(logdir):
+    return _scalar_trail(logdir, "control/rung")
 
 
 @pytest.mark.slow  # ~27 s of femnist compiles; the clamp/exhaustion logic
@@ -694,10 +694,76 @@ def test_cv_train_budget_hard_stop_e2e(tmp_path):
     assert rec["controller"]["policy"] == "budget_pacing"
 
 
+def test_runner_ladder_dropout_resume_bit_exact_tinymlp(tmp_path):
+    """The cv_train e2e's default-tier twin on the TinyMLP task: the REAL
+    shared runner (train_loop) under bernoulli dropout + a 3-rung
+    ef_feedback ladder switches at least once with zero retraces, and a
+    resume from a mid-run checkpoint reproduces the uninterrupted run:
+    final params bitwise, and the scalar tail record for record."""
+    import shutil
+
+    from commefficient_tpu.data import FedDataset
+    from commefficient_tpu.train.cv_train import train_loop
+    from commefficient_tpu.utils.checkpoint import FedCheckpointer
+    from commefficient_tpu.utils.logging import MetricsWriter
+
+    ds, params, loss_fn = _setup(12)
+    test_ds = FedDataset({"x": ds.data["x"][:40], "y": ds.data["y"][:40]},
+                         1, seed=0)
+
+    def run(resume):
+        cfg = Config(**{**BASE, **dict(
+            mode="true_topk", error_type="virtual", virtual_momentum=0.9,
+            topk_method="threshold", telemetry_level=1, perf_audit=False,
+            availability="bernoulli", dropout_prob=0.25,
+            control_policy="ef_feedback", ladder="k=60,30,15",
+            control_ef_up=1e-9, control_ef_down=-1.0, control_hysteresis=1,
+            num_epochs=1, pivot_epoch=1, lr_scale=0.1,
+            checkpoint_dir=str(tmp_path / "ckpt"), checkpoint_every=5,
+            resume=resume,
+        )})
+        sess = FederatedSession(cfg, params, loss_fn)
+        sampler = FedSampler(ds, num_workers=cfg.num_workers,
+                             local_batch_size=cfg.local_batch_size, seed=1)
+        run_dir = str(tmp_path / ("resume" if resume else "full"))
+        writer = MetricsWriter(run_dir, cfg=cfg)
+        ck = FedCheckpointer(cfg)
+        try:
+            train_loop(cfg, sess, sampler, test_ds, writer,
+                       eval_batch_size=32, checkpointer=ck)
+        finally:
+            ck.close()
+            writer.close()
+        return sess, run_dir
+
+    full, full_dir = run(resume=False)
+    seq = _metric_records(full_dir)
+    rungs = [v for n, v, _s in seq if n == "control/rung"]
+    assert rungs[0] == 2.0 and len(set(rungs)) >= 2, rungs
+    assert {v for n, v, _s in seq if n == "xla/retraces"} == {0.0}
+    assert full.retrace_sentinel.retraces == 0
+    # resume: drop all but the FIRST surviving checkpoint and replay
+    kept = sorted(int(p.name) for p in (tmp_path / "ckpt").iterdir()
+                  if p.name.isdigit())
+    resume_step = kept[0]
+    assert resume_step < max(s for _n, _v, s in seq), kept
+    for s in kept[1:]:
+        shutil.rmtree(tmp_path / "ckpt" / str(s))
+    resumed, resumed_dir = run(resume=True)
+    np.testing.assert_array_equal(np.asarray(full.state.params_vec),
+                                  np.asarray(resumed.state.params_vec))
+    drop = ("comm/",)  # process-local cumulative ledger, by design
+    tail = [r for r in _metric_records(resumed_dir)
+            if r[2] >= resume_step and not r[0].startswith(drop)]
+    want = [r for r in seq if r[2] >= resume_step
+            and not r[0].startswith(drop)]
+    assert tail == want, "resume diverged from the uninterrupted run"
+
+
 @pytest.mark.slow  # ~130 s of femnist compiles — moved to the slow tier
 # in the sketch-gap PR per the 870 s tier-1 budget (the PR-9/10
 # precedent). Its claims hold default-tier coverage at TinyMLP scale:
-# test_pipeline.py::test_runner_pipelined_resume_bit_exact_tinymlp runs
+# test_runner_ladder_dropout_resume_bit_exact_tinymlp above runs
 # the SAME 3-rung ef_feedback ladder through the REAL shared runner
 # (>= 1 switch, zero retraces, mid-run checkpoint resume reproducing the
 # tail), and the session-level switch/checkpoint/ledger pins above cover

@@ -110,9 +110,6 @@ _FLEET_KW = dict(mode="uncompressed", num_clients=16, num_workers=8,
     # engines that cannot re-shape a round mid-run
     (dict(chaos="resize@4:rounds=3-", async_buffer=4,
           async_concurrency=2), r"async_buffer"),
-    (dict(chaos="resize@4:rounds=3-", scan_rounds=2), r"scan_rounds"),
-    (dict(chaos="resize@4:rounds=3-", pipeline_depth=2),
-     r"pipeline_depth"),
     (dict(chaos="resize@4:rounds=3-", fsdp=True), r"fsdp"),
     # shrink models a LOSS: needs the recovery path, a round to roll
     # back over, and a width strictly below the one in effect

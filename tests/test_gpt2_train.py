@@ -70,43 +70,6 @@ def test_gpt2_train_e2e_sketch_trains(tmp_path):
     assert np.isfinite(rows[-1]["val_ppl"])
 
 
-# ~14 s standalone (gpt2_tiny, 1 epoch, 2 depths): pins the SECOND
-# workload entry's pipeline wiring through the shared runner; the full
-# bit-exactness contract holds deeper coverage in tests/test_pipeline.py
-@pytest.mark.slow  # r20 tier budget: the depth-0 twin here is the only
-# unique surface (gpt2 entry x pipeline flag plumbing); the contract
-# itself stays tier-1 in test_pipeline's TinyMLP runner pins
-def test_gpt2_train_pipelined_depth2_matches_depth0(tmp_path):
-    """gpt2_train.train_loop at --pipeline_depth 2 == depth 0 bitwise
-    (final params), through the shared runner's engine wiring."""
-    from commefficient_tpu.train import gpt2_train
-    from commefficient_tpu.data.sampler import FedSampler
-    from commefficient_tpu.parallel import FederatedSession, mask_gpt2
-    from commefficient_tpu.utils.config import Config
-
-    def run(depth):
-        cfg = Config(
-            model="gpt2_tiny", dataset_name="personachat",
-            mode="true_topk", error_type="virtual", virtual_momentum=0.9,
-            k=400, topk_method="threshold", num_epochs=1, num_clients=4,
-            num_workers=2, num_devices=2, local_batch_size=2,
-            max_seq_len=64, weight_decay=0.0, lr_scale=0.05,
-            pivot_epoch=1, pipeline_depth=depth,
-        )
-        train, test, _real, _hf, _gcfg, _model, params, loss_fn = (
-            gpt2_train.build_model_and_data(cfg)
-        )
-        session = FederatedSession(cfg, params, loss_fn,
-                                   mask_batch=mask_gpt2)
-        sampler = FedSampler(train, num_workers=2, local_batch_size=2,
-                             seed=1)
-        session.maybe_attach_data(train, sampler)
-        gpt2_train.train_loop(cfg, session, sampler, test)
-        return np.asarray(session.state.params_vec)
-
-    np.testing.assert_array_equal(run(0), run(2))
-
-
 def test_ppl_token_weighted_under_ragged_batches():
     """nll must be identical whether the val set is evaluated in one exact
     batch or in batches whose final one is ragged/padded — true only under

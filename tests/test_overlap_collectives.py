@@ -5,7 +5,7 @@ collectives — the sketch-table psum and the top-k modes' pair all_gather
 — into per-leaf-group / per-segment collectives the latency-hiding
 scheduler can issue as the backward produces them. The knob is a pure
 scheduling choice, so the contract pinned here is equality, not speed
-(the speed side is bench.py's ``sketch_overlap_layerwise`` leg):
+(no benchmark cell turns it on; the speed side is not measured):
 
   * ops level, on the real 8-device mesh: ``psum_segments`` is BIT-equal
     to one psum of the concatenated segments (``psum_segments_fused``),

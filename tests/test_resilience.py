@@ -391,27 +391,6 @@ def test_retry_heals_nan_client_bit_exactly(tmp_path, uninterrupted):
     assert os.path.exists(os.path.join(run_dir, "flight_5.json"))
 
 
-def test_retry_heals_under_pipelined_engine(tmp_path, uninterrupted):
-    """The pipelined twin of the retry acceptance: at --pipeline_depth 2
-    the recovery quiesces the in-flight prefetch window like a checkpoint
-    fence (engine.restart), restages from the rollback round with
-    replay=True semantics, and the healed run is STILL bit-identical to
-    the uninterrupted (depth-0, chaos-free) run."""
-    sess, run_dir, _val = _run_loop(
-        tmp_path, "_retry_p2",
-        chaos="nan_client@1:rounds=5-5", recover_policy="retry",
-        snapshot_every=4, pipeline_depth=2,
-    )
-    np.testing.assert_array_equal(np.asarray(sess.state.params_vec),
-                                  uninterrupted["params"])
-    assert _last_value(run_dir, "resilience/recoveries") == 1.0
-    # pipeline/* gauges exist only at depth > 0 — exclude them from the
-    # cross-depth scalar comparison, like tests/test_pipeline.py does
-    seq = _scalars(run_dir, exclude=("resilience/", "pipeline/", "trace/",
-                                     "xla/exposed_collective_ms"))
-    assert seq == uninterrupted["scalars"]
-
-
 def test_retry_rollback_into_completed_epoch_no_duplicate_rows(tmp_path):
     """Review fix: a rollback landing INSIDE an already-completed epoch
     (divergence in epoch 1, newest snapshot mid-epoch 0) must not re-run

@@ -306,51 +306,29 @@ def test_cli_json_summary_always_last_line(tmp_path, capsys):
 
 
 # ---------------------------------------------------------------------------
-# v5: pipeline/* scalars + thread-aware spans
+# v5: thread-aware spans (the pipeline/* scalars of v5 left with the
+# engines that wrote them)
 # ---------------------------------------------------------------------------
 
-def test_v5_pipeline_scalars_validate_and_reject(tmp_path):
-    """The pipeline/ scalar prefix is in-schema through the REAL writer;
-    the occupancy-range and staged-rounds-integer invariants are enforced
-    (tampered values rejected). The per-round-metric form is additionally
-    pinned by tests/test_pipeline.py through the real engine."""
+def test_retired_pipeline_namespace_is_outside_the_schema(tmp_path):
+    """No writer emits pipeline/* any more, so the prefix is not a
+    documented namespace: a metrics file that still carries one is
+    refused by name, through the REAL writer."""
     mod = _checker()
-    cfg = Config(mode="uncompressed", telemetry_level=1, pipeline_depth=2)
+    cfg = Config(mode="uncompressed", telemetry_level=1)
     run_dir = str(tmp_path / "run")
     writer = MetricsWriter(run_dir, cfg=cfg)
-    for s in range(3):
-        writer.scalar("train/loss", 1.0, s)
-        writer.scalar("lr", 0.1, s)
-        writer.scalar("pipeline/occupancy", s / 2.0, s)
-        writer.scalar("pipeline/host_stall_ms", 0.4, s)
-        writer.scalar("pipeline/staged_rounds", float(s), s)
+    writer.scalar("train/loss", 1.0, 0)
+    writer.scalar("lr", 0.1, 0)
     writer.close()
     path = os.path.join(run_dir, "metrics.jsonl")
-    assert mod.validate_metrics_jsonl(path) == 15
-    lines = open(path).read().splitlines()
-    for bad_rec, msg in [
-        ({"name": "pipeline/occupancy", "value": -0.1, "step": 0,
-          "t": 1.0}, "outside \\[0, 1\\]"),
-        ({"name": "pipeline/occupancy", "value": 2.0, "step": 0,
-          "t": 1.0}, "outside \\[0, 1\\]"),
-        ({"name": "pipeline/staged_rounds", "value": 0.5, "step": 0,
-          "t": 1.0}, "integer"),
-        ({"name": "pipeline/staged_rounds", "value": -1.0, "step": 0,
-          "t": 1.0}, "integer"),
-        ({"name": "pipeline/host_stall_ms", "value": "nan", "step": 0,
-          "t": 1.0}, "finite number"),
-        # scan engine (sketch-gap PR): the block length is a count of
-        # whole scanned rounds, >= 1 — fractional/zero values mean the
-        # engine miscounted its block plan
-        ({"name": "pipeline/scan_rounds_per_dispatch", "value": 2.5,
-          "step": 0, "t": 1.0}, "positive integer"),
-        ({"name": "pipeline/scan_rounds_per_dispatch", "value": 0.0,
-          "step": 0, "t": 1.0}, "positive integer"),
-    ]:
-        bad = tmp_path / "bad.jsonl"
-        bad.write_text(lines[0] + "\n" + json.dumps(bad_rec) + "\n")
-        with pytest.raises(mod.SchemaError, match=msg):
-            mod.validate_metrics_jsonl(str(bad))
+    assert mod.validate_metrics_jsonl(path) == 2
+    assert "pipeline/" not in mod.SCALAR_PREFIXES
+    with open(path, "a") as f:
+        f.write(json.dumps({"name": "pipeline/occupancy", "value": 0.5,
+                            "step": 0, "t": 1.0}) + "\n")
+    with pytest.raises(mod.SchemaError, match="outside the documented"):
+        mod.validate_metrics_jsonl(path)
 
 
 def test_v5_spans_thread_metadata_validates_and_rejects(tmp_path):

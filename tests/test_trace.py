@@ -1,14 +1,12 @@
 """Round tracing & critical-path attribution (telemetry/trace.py, PR 18).
 
-What this file pins, and why it is shaped as three runs instead of the
-one the acceptance sentence names: ``async_buffer`` is mutually
-exclusive with BOTH ``pipeline_depth`` and hosted client stores
-(utils/config.py _validate_asyncfed — the asyncfed engine owns its own
-cohort prefetch window and requires HBM-resident banks), so "pipelined +
-async + hosted-clientstore" is covered by a pipelined+hosted run (depth
-2, ``--client_store host``) and an async run (C = 3) whose span dumps
-together carry every prefetch/writeback/apply span with the owning
-round's/cohort's trace id.
+What this file pins, and why it is shaped as two runs: ``async_buffer``
+is mutually exclusive with hosted client stores (utils/config.py
+_validate_asyncfed — the asyncfed engine requires HBM-resident banks),
+so the planes are covered by a hosted run of the plain loop
+(``--client_store host``) and an async run (C = 3) whose span dumps
+together carry every gather/writeback/dispatch/launch/apply span with
+the owning round's/cohort's trace id.
 
   * trace-id grammar: deterministic ids minted at realization time —
     ``r<step>`` for rounds, ``c<cohort>`` for async cohorts (parent =
@@ -18,8 +16,8 @@ round's/cohort's trace id.
     sum to exactly the round wall-clock (idle is the remainder), exposed
     collective is assigned first, and non-path spans
     (async_buffer_residency) never stretch the round window.
-  * e2e: the pipelined+hosted dump validates under schema v11, every
-    prefetch/gather/writeback span carries its round's id, the lagged
+  * e2e: the hosted dump validates under schema v11, every
+    gather/writeback/dispatch span carries its round's id, the lagged
     ``trace/*`` scalars ride the metric stream with a constant key set,
     and the run dir round-trips through write_run_report ->
     validate_run_report -> scripts/analyze_run.py.
@@ -214,27 +212,30 @@ def test_profiler_window_clamps_fences_and_disarms(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# e2e: pipelined + hosted clientstore — ids on every plane, then the
+# e2e: the plain loop + hosted clientstore — ids on every plane, then the
 # full report chain (write_run_report -> checker -> analyze_run CLI)
 # ---------------------------------------------------------------------------
 
-def test_pipelined_hosted_trace_ids_and_run_report(tmp_path):
-    from commefficient_tpu.pipeline.engine import PipelinedRounds
+def test_hosted_trace_ids_and_run_report(tmp_path):
+    import itertools
 
-    cfg = Config(**{**KW, **BASE}, client_store="host", pipeline_depth=2,
-                 telemetry_level=1)
+    from commefficient_tpu.train.runner import _sync_epoch_rounds
+    from commefficient_tpu.utils.profiling import StepProfiler
+
+    cfg = Config(**{**KW, **BASE}, client_store="host", telemetry_level=1)
     ds, params, loss_fn = _setup(cfg.num_clients)
     sess = FederatedSession(cfg, params, loss_fn)
     sampler = FedSampler(ds, num_workers=cfg.num_workers,
                          local_batch_size=cfg.local_batch_size, seed=1)
     spans = PhaseSpans(str(tmp_path))
     sess.spans = spans
-    eng = PipelinedRounds(cfg, sess, sampler, _lr_fn, num_rounds=6,
-                          steps_per_epoch=6, spans=spans).start(0)
+    rounds = _sync_epoch_rounds(cfg, sess, sampler, _lr_fn, spans,
+                                StepProfiler(""), 0, 0,
+                                sampler.steps_per_epoch())
     try:
-        ms = [m for _s, _lr, m in eng.epoch_rounds(0, 0)]
+        ms = [m for _s, _lr, m in itertools.islice(rounds, 6)]
     finally:
-        eng.close()
+        rounds.close()
     assert sess.retrace_sentinel.retraces == 0
     sess.close_client_store()  # flush: writeback spans must be recorded
     path = spans.close()
@@ -253,16 +254,15 @@ def test_pipelined_hosted_trace_ids_and_run_report(tmp_path):
     assert sum(ms[2][k] for k in keys if k.endswith("_exclusive_ms")) > 0
     assert 0 <= int(ms[2]["trace/critical_stage"]) < len(STAGES)
 
-    # v11 spans dump validates; every prefetch/gather/writeback span
+    # v11 spans dump validates; every gather/writeback/dispatch span
     # carries the OWNING round's id (flush spans carry none by design)
     rec = _script("check_telemetry_schema").validate_spans(path)
     evs = [e for e in rec["traceEvents"] if e["ph"] == "X"]
     by_name = {}
     for e in evs:
         by_name.setdefault(e["name"], []).append(e)
-    for name in ("prefetch_realize", "prefetch_stage",
-                 "clientstore_gather", "clientstore_writeback",
-                 "round_dispatch"):
+    for name in ("device_put", "clientstore_gather",
+                 "clientstore_writeback", "round_dispatch"):
         group = by_name.get(name, [])
         assert group, f"no {name} spans recorded"
         for e in group:
@@ -271,9 +271,9 @@ def test_pipelined_hosted_trace_ids_and_run_report(tmp_path):
                 f"{name} span not stamped with its round's trace id"
     for e in by_name.get("clientstore_flush", []):
         assert "trace_id" not in e["args"]
-    # prefetch realizes every round once; writebacks cover every round
+    # the gather runs inside its round; writebacks cover every round
     assert sorted({e["args"]["step"]
-                   for e in by_name["prefetch_realize"]}) == list(range(6))
+                   for e in by_name["clientstore_gather"]}) == list(range(6))
     assert sorted({e["args"]["step"]
                    for e in by_name["clientstore_writeback"]}) == \
         list(range(6))
