@@ -21,16 +21,24 @@ Layout (this module, v5 — "banded"):
     within-chunk offsets into a WINDOW of ``V = band * stride`` buckets
     starting at ``q * stride`` of the global row, so neighboring chunks'
     windows OVERLAP and each coordinate's collision pool is V (~5k)
-    buckets, not a private per-chunk pool. One static ``[m, V]`` one-hot
-    realizes a whole row as a single ``[nc, m] x [m, V]`` MXU matmul
-    followed by ``band`` static shifted adds (overlap-add) — no scatter,
-    no gather. Estimation is the windowed view (static slices) and the
-    transposed matmul, then median across rows.
+    buckets, not a private per-chunk pool. One static one-hot, kept by
+    band as ``[m, band, stride]``, realizes a whole row as a single
+    ``[nc, m] x [m, V]`` MXU matmul whose result stays ``[nc, band,
+    stride]``, followed by ``band`` static shifted adds (overlap-add) — no
+    scatter, no gather. Estimation stacks the row's ``band`` shifted views
+    of whole ``stride``-wide rows as ``[nc, band, stride]`` and contracts
+    both dimensions (the transposed matmul), then median across rows. No
+    ``[nc, V]`` array exists on either side: ``stride`` is no multiple of
+    the 128-lane tile, and merging the band into a ``band * stride`` minor
+    dimension was a lane-by-lane relayout of 319 MB a row at the GPT-2
+    geometry (PERF.md section 6, PR 32).
   * Before any row layout, ONE seed-derived static permutation of
     ``scramble_block``-sized coordinate blocks (a cheap row-gather)
     decorrelates parameter structure from chunk structure; each row then
-    applies a distinct-prime RIFFLE (``reshape(f, L/f).T`` transpose) so
-    partner sets differ across rows.
+    applies a distinct-prime RIFFLE (the flat order of ``reshape(f,
+    L/f).T``) so partner sets differ across rows. A factor under the lane
+    tile is realized as two tile-aligned moves (``_riffle``); a larger one
+    as the plain transpose.
 
 v3/v4 POSTMORTEM (do not regress to disjoint pools): with per-chunk
 PRIVATE pools (v3 riffles only, v4 + scramble), a coordinate can only
@@ -552,14 +560,6 @@ class CountSketch(NamedTuple):
             _mix32(off, self._row_key(row)) % jnp.uint32(self.V_row(row))
         ).astype(jnp.int32)
 
-    def _row_onehot(self, row: int) -> jnp.ndarray:
-        """[m, V] static one-hot of ``_offset_slots`` — the whole row's hash
-        as one small matmul operand."""
-        slots = self._offset_slots(row)
-        return (
-            slots[:, None] == jnp.arange(self.V_row(row), dtype=jnp.int32)
-        ).astype(self.dtype)
-
 
 @_functools.lru_cache(maxsize=None)
 def _scramble_perms(d_eff: int, block: int, seed: int):
@@ -628,17 +628,56 @@ def _unscramble(spec: "CountSketch", v_s: jnp.ndarray) -> jnp.ndarray:
     return v_s.reshape(-1, b)[jnp.asarray(inv)].reshape(spec.d_eff)[: spec.d]
 
 
+def _riffle_tile(spec: "CountSketch", row: int) -> int:
+    """Lane tile ``t`` of this row's riffle, or 0 for the plain
+    ``reshape(f, G).T`` form. The tiled moves are for a factor shorter than
+    a lane tile: the plain transpose then leaves ``G`` rows of ``f < 128``
+    floats, each a partial tile, and the re-pitch to the chunk size runs row
+    by row (f = 97 at the GPT-2 geometry: 28.0 ms to the layout and 17.4 ms
+    back against 8.9 and 9.0 tiled; the large primes' plain form reads 12-13
+    ms either way and keeps it — scripts/sketch_layout_probe.py, PR 32). They
+    need a chunk size that has the tile."""
+    return 128 if 1 < spec._factor(row) < 128 and spec.chunk_m % 128 == 0 else 0
+
+
+def _riffle(x: jnp.ndarray, f: int, t: int) -> jnp.ndarray:
+    """[L] position order -> [L] riffled order (``x.reshape(f, G).T`` flat)
+    as two tile-aligned transposes. ``G = L/f`` is a multiple of the chunk
+    size by construction (``_L_row``), so with ``a = a1 * t + a2`` the
+    riffled index ``a * f + b`` reads ``a1 * (t f) + (a2 * f + b)``: the
+    prime ``f`` is only ever the major part of a minor dimension ``t f``,
+    never a minor dimension itself (XLA:TPU keeps the two moves apart: a
+    ``copy``, a row merge, a ``copy``, the re-cut to the chunk size; the
+    one transpose with an ``f``-wide minor dimension compiles to a
+    ``while`` of row-granular ``dynamic-update-slice``s — PERF.md section
+    6, PR 32)."""
+    A = x.shape[0] // (f * t)
+    y = x.reshape(f, A, t).transpose(2, 0, 1)  # [a2, b, a1]
+    return y.reshape(t * f, A).T.reshape(-1)  # [a1, a2 f + b]
+
+
+def _unriffle(x: jnp.ndarray, f: int, t: int) -> jnp.ndarray:
+    """Inverse of ``_riffle``: the same two moves backwards."""
+    A = x.shape[0] // (f * t)
+    y = x.reshape(A, t * f).T  # [a2 f + b, a1]
+    return y.reshape(t, f, A).transpose(1, 2, 0).reshape(-1)  # [b, a1, a2]
+
+
 def _to_layout(spec: "CountSketch", x_d: jnp.ndarray, row: int) -> jnp.ndarray:
     """[d] position-ordered -> [nc_row, m] chunk layout for this row.
 
     Riffle with factor f: original coordinate p lands at riffled index
-    ``(p mod G) * f + p // G`` with ``G = L_row / f`` — realized as
-    ``reshape(f, G).T``, a contiguous transpose. Chunks are then
-    contiguous blocks of m. f=1 rows are plain contiguous chunking.
+    ``(p mod G) * f + p // G`` with ``G = L_row / f`` — the flat order of
+    ``reshape(f, G).T``, realized as tiled moves (``_riffle``) where
+    ``_riffle_tile`` says so. Chunks are then contiguous blocks of m. f=1
+    rows are plain contiguous chunking.
     """
     f, L = spec._factor(row), spec._L_row(row)
     xp = jnp.pad(x_d, (0, L - spec.d_eff))
-    if f > 1:
+    t = _riffle_tile(spec, row)
+    if t:
+        xp = _riffle(xp, f, t)
+    elif f > 1:
         xp = xp.reshape(f, L // f).T.reshape(L)
     return xp.reshape(L // spec.chunk_m, spec.chunk_m)
 
@@ -647,7 +686,18 @@ def _from_layout(spec: "CountSketch", x_chunks: jnp.ndarray, row: int) -> jnp.nd
     """[nc_row, m] chunk layout -> [d] position-ordered (inverse)."""
     f, L = spec._factor(row), spec._L_row(row)
     xp = x_chunks.reshape(L)
-    if f > 1:
+    t = _riffle_tile(spec, row)
+    if t:
+        xp = _unriffle(xp, f, t)
+    elif f > 1 and spec.chunk_m % 128 == 0:
+        # the plain transpose written onto the vector's own [f, G/128, 128]
+        # tiles: its result is the flat vector bit for bit, where the
+        # [f, G] form pays one more pass to re-tile it (estimate_all at
+        # the GPT-2 geometry 96.7 -> 87.6 ms; the same view on the way in
+        # makes the re-pitch loop transpose as well and loses, 86.0 ->
+        # 88.2 ms for sketch_vec — scripts/sketch_layout_probe.py, PR 32)
+        xp = xp.reshape(L // f // 128, 128, f).transpose(2, 0, 1).reshape(L)
+    elif f > 1:
         xp = xp.reshape(L // f, f).T.reshape(L)
     return xp[: spec.d_eff]
 
@@ -656,39 +706,21 @@ def _ceil_mult(x: int, q: int) -> int:
     return -(-x // q) * q
 
 
-def _overlap_add(spec: CountSketch, O: jnp.ndarray, row: int) -> jnp.ndarray:
-    """[nc, V] per-chunk windows -> flat row via ``band`` shifted adds
-    (chunk q's window covers positions [q*t, q*t + V))."""
-    nc, u, t = spec._nc_row(row), spec.u_row(row), spec.s_row(row)
-    if u == 1:
-        return O.reshape(nc * t)
-    Or = O.reshape(nc, u, t)
-    # parallel form: u statically-shifted padded copies summed in one
-    # reduction (the sequential .at[i:i+nc].add chain serialized u
-    # dynamic-update-slices)
-    stack = jnp.stack(
-        [
-            jnp.pad(Or[:, i, :], ((i, u - 1 - i), (0, 0)))
-            for i in range(u)
-        ]
-    )
-    return stack.sum(0).reshape((nc + u - 1) * t)
-
-
-def _overlap_gather(spec: CountSketch, row_vec: jnp.ndarray, row: int) -> jnp.ndarray:
-    """Inverse view: flat row -> [nc, V] per-chunk windows (static slices)."""
-    nc, u, t = spec._nc_row(row), spec.u_row(row), spec.s_row(row)
-    if u == 1:
-        return row_vec[: nc * t].reshape(nc, t)
-    acc = row_vec[: (nc + u - 1) * t].reshape(nc + u - 1, t)
-    return jnp.stack([acc[i : i + nc] for i in range(u)], axis=1).reshape(
-        nc, u * t
-    )
+def _band_onehot(spec: CountSketch, row: int) -> jnp.ndarray:
+    """The row's static one-hot by band: ``[m, u, s]``, window ``i`` of a
+    chunk being the ``s`` buckets from ``(q + i) * s`` of the flat row. The
+    products contract ``(u, s)`` or leave it as two dimensions, so no
+    ``[nc, V]`` array with the band merged into a ``u * s`` minor dimension
+    (328 is no multiple of 128: that merge was a lane-by-lane relayout of
+    319 MB a row) exists on either side."""
+    u, s = spec.u_row(row), spec.s_row(row)
+    buckets = jnp.arange(u * s, dtype=jnp.int32).reshape(u, s)
+    return (spec._offset_slots(row)[:, None, None] == buckets).astype(spec.dtype)
 
 
 def _sketch_one_row(spec: CountSketch, v_s: jnp.ndarray, row: int) -> jnp.ndarray:
     # v_s is already in scrambled space ([d_eff]); signs are scrambled-keyed
-    sv = _to_layout(spec, v_s * spec._row_signs(row), row)
+    sv = _to_layout(spec, v_s * spec._row_signs(row), row).astype(spec.dtype)
     # NB matmul precision: the default (fast bf16-pass) path measures
     # STABLE in the FetchSGD feedback loop once the banded layout is in
     # place (lab acc 0.340 at paper-scale settings, vs 0.305 with
@@ -696,13 +728,23 @@ def _sketch_one_row(spec: CountSketch, v_s: jnp.ndarray, row: int) -> jnp.ndarra
     # exact in bf16 and the ~2^-8 relative bucket noise is far below the
     # collision noise floor. The divergence postmortem (module docstring)
     # was a LAYOUT problem, not a precision problem.
+    u = spec.u_row(row)
     out = jnp.einsum(
-        "cm,ms->cs",
-        sv.astype(spec.dtype),
-        spec._row_onehot(row),
+        "cm,mus->cus",
+        sv,
+        _band_onehot(spec, row),
         preferred_element_type=jnp.float32,
     )
-    out = _overlap_add(spec, out, row)
+    if u == 1:
+        out = out.reshape(-1)
+    else:
+        # overlap-add: window i of chunk q lands on row q + i of the
+        # [nc + u - 1, s] row view — u statically shifted padded slices
+        # summed in one reduction (a sequential .at[i:i+nc].add chain
+        # serialized u dynamic-update-slices)
+        out = sum(
+            jnp.pad(out[:, i, :], ((i, u - 1 - i), (0, 0))) for i in range(u)
+        ).reshape(-1)
     return jnp.pad(out, (0, spec.c_actual - out.shape[0]))
 
 
@@ -757,11 +799,15 @@ def table_sqnorm_estimate(table: jnp.ndarray) -> jnp.ndarray:
 
 
 def _estimate_one_row(spec: CountSketch, table_row: jnp.ndarray, row: int) -> jnp.ndarray:
-    tab = _overlap_gather(spec, table_row, row)
+    nc, u, t = spec._nc_row(row), spec.u_row(row), spec.s_row(row)
+    acc = table_row[: (nc + u - 1) * t].reshape(nc + u - 1, t).astype(spec.dtype)
+    # chunk q's windows are rows q .. q + u - 1 of the row view: whole rows
+    # of s buckets, stacked as [nc, u, s] and contracted as two dimensions
+    win = jnp.stack([acc[i : i + nc] for i in range(u)], axis=1)
     est = jnp.einsum(
-        "cs,ms->cm",
-        tab.astype(spec.dtype),
-        spec._row_onehot(row),
+        "cus,mus->cm",
+        win,
+        _band_onehot(spec, row),
         preferred_element_type=jnp.float32,
     )
     # scrambled-space estimate [d_eff]; estimate_all unscrambles after the
@@ -809,7 +855,12 @@ def estimate_all(spec: CountSketch, table: jnp.ndarray) -> jnp.ndarray:
         ests = jnp.stack(
             [_estimate_one_row(spec, table[r], r) for r in range(spec.r)]
         )
-        return _unscramble(spec, _median_rows(ests))
+        # the barrier keeps the unscramble's [blocks, block] view out of the
+        # rows: without it XLA:TPU moves that reshape above the median and
+        # every row pays a reshape, a slice and a layout copy of its own
+        # [d_eff] estimates (three passes over 0.5 GB a row) to be laid out
+        # for a gather that runs once
+        return _unscramble(spec, jax.lax.optimization_barrier(_median_rows(ests)))
 
 
 def _scrambled_pos(spec: CountSketch, idx: jnp.ndarray) -> jnp.ndarray:

@@ -2,18 +2,26 @@
 
 The banded-einsum path (ops/countsketch.py) realizes each row as
 
-    [nc, m] signed values  x  [m, V] STATIC one-hot  ->  [nc, V]  ->  overlap-add
+    [nc, m] signed values  x  [m, u, s] STATIC one-hot  ->  [nc, u, s]  ->  overlap-add
 
 which is MXU-friendly but pays for it three ways at GPT-2 scale
-(d=124M, c=5M, m=8192, V~5k — the BENCH_r05 3.5x sketch-round gap):
+(d=124M, c=5M, m=8192, V = u s ~5k):
 
-  1. the [m, V] one-hot is a materialized jit constant (~170 MB f32 at the
-     GPT-2 geometry) that streams from HBM on every row;
-  2. the [nc, V] window intermediate (~320 MB) round-trips HBM between the
-     einsum and the overlap-add;
+  1. the one-hot (~170 MB f32 at the GPT-2 geometry) is generated by a
+     compare and streams through HBM on every row;
+  2. the [nc, u, s] window intermediate (~320 MB) round-trips HBM between
+     the einsum and the overlap-add (since PR 32 never as an [nc, V] array:
+     the band stays a dimension of its own through both products, which
+     took 2.2 ms a row a direction off the einsum path);
   3. the sign vector is a materialized [d_eff] table — and for the poly4
      hash family it is HOST-evaluated uint64 numpy, which is why poly4 was
      CV-scale-only before this module.
+
+Measured once at that geometry (scripts/sketch_layout_probe.py, PR 32, v5e):
+these kernels read 123.2 ms for ``sketch_vec`` and 165.8 ms for
+``estimate_all`` against the einsum path's 100.6 / 132.8 before that PR: the
+per-tile one-hot compares on the vector unit cost more than the round trip
+they spare, and the riffle stays outside them either way.
 
 Here each row is ONE tiled kernel: a grid over chunk tiles keeps a
 [TC, V] accumulator in VMEM, loops over offset tiles generating the

@@ -229,7 +229,7 @@ def _ops_under(text, scope):
 
 def test_hlo_dense_round_has_no_dense_pass_under_ef_resketch():
     """The compiled dense-decode round: under ``ef_resketch`` no
-    ``sketch_vec`` einsum (the ``[nc, m] x [m, V]`` dot_general) and no
+    ``sketch_vec`` einsum (the ``[nc, m] x [m, u, s]`` dot_general) and no
     block-scramble gather (the one gather that returns ``d_eff``
     elements); ``encode``, which is ``sketch_vec``, proves both markers.
     The compaction's and the scatter's ops are there, under that name and
@@ -240,7 +240,7 @@ def test_hlo_dense_round_has_no_dense_pass_under_ef_resketch():
     d_eff = sess.rungs[0].spec.d_eff
 
     def dense_pass(ops):
-        einsum = [p for p, _n, _l in ops if "cm,ms->cs" in p]
+        einsum = [p for p, _n, _l in ops if "cm,mus->cus" in p]
         scramble = [p for p, n, ln in ops
                     if p.endswith("/gather") and " gather(" in ln and n == d_eff]
         return einsum, scramble
