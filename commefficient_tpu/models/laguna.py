@@ -23,7 +23,11 @@ experts' rows first, grouped; the grouped product
 rows fill. Row movement is sized for ``FAST_ROWS_FACTOR`` times the expected
 number of held assignments, and for all ``tokens x top_k`` of them on a
 branch taken only when a client's router sends more (``lax.cond``; both
-branches give the same numbers). The branch is per client, so ``vmap`` over
+branches give the same numbers). A preset may name more such tiers
+(``expert_row_tiers``; ``lax.switch``), the product's tile
+(``expert_tiling``), and a floor (``expert_rows_floored``: the product
+always runs over the first tier's rows, so that a round's time does not
+follow the router's skew below it). The branch is per client, so ``vmap`` over
 clients runs the layer client after client (``sequential_vmap``), and the
 backward pass is written out (``custom_vjp``) so that it branches the same
 way and keeps no residual of the branch not taken.
@@ -45,6 +49,18 @@ sigmoid per head from the normed input; router weights are the selected
 sigmoid scores over their sum, times the scaling factor; ``silu``; rotary
 halves ``[x1, x2] -> [x1 cos - x2 sin, x2 cos + x1 sin]``; no QK norm, no
 selection bias, no auxiliary router loss.
+
+Since PR 33 this file is also the decoder other presets are made of
+(``models/keye.py``): what differs between them is a field of the
+configuration — a layer's attention kind (``full_attention`` /
+``sliding_attention`` / ``indexed_attention``: a learned index chooses
+``index_topk`` keys a query, ``ops/pallas/indexed_attention.py``),
+``qk_norm`` (a per-head RMSNorm on queries and keys before the rotary),
+``output_gate`` and the ``router`` form (``sigmoid_scaled_shared``: the
+above; ``softmax_renormalised``: a softmax over all experts, the largest
+renormalised, no scaling factor, no shared expert). The defaults are
+Laguna's, whose presets build the tree and lower to the program they did
+before the fields were there (``tests/test_decoder_presets.py``).
 """
 
 from __future__ import annotations
@@ -58,7 +74,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from commefficient_tpu.models.losses import softmax_cross_entropy_sum
+from commefficient_tpu.models.losses import IGNORE_INDEX, softmax_cross_entropy_sum
+from commefficient_tpu.ops.pallas.indexed_attention import SELECT_RESIDUAL, indexed_attention
 from commefficient_tpu.ops.pallas.library_kernels import (
     GMM_TILING,
     banded_attention,
@@ -148,6 +165,19 @@ class LagunaConfig:
     rms_norm_eps: float = 1e-6
     initializer_range: float = 0.02
     dtype: Any = jnp.bfloat16
+    # what other presets of this decoder set (module docstring)
+    qk_norm: bool = False
+    output_gate: bool = True
+    router: str = "sigmoid_scaled_shared"
+    index_heads: int = 0        # ``indexed_attention`` layers: the index's heads,
+    index_head_dim: int = 0     # their width (one shared key head of that width),
+    index_topk: int = 0         # and the keys a query attends to
+    head_chunk: int = 0         # positions whose logits live at once (0: a row's, whole)
+    expert_tiling: Tuple[int, int, int] = GMM_TILING   # the grouped product's tile
+    # the expert layer's branches, each this many times the held rows a
+    # uniform router sends (the last resort, every row, needs no entry)
+    expert_row_tiers: Tuple[float, ...] = (FAST_ROWS_FACTOR,)
+    expert_rows_floored: bool = False  # the product always runs over the first tier's rows
 
     @property
     def num_layers(self) -> int:
@@ -271,27 +301,49 @@ class Attention(nn.Module):
         c = self.cfg
         B, T, E = h.shape
         H, KV, d = c.num_attention_heads_per_layer[self.layer], c.num_key_value_heads, c.head_dim
-        sliding = c.layer_types[self.layer] == "sliding_attention"
+        kind = c.layer_types[self.layer]
+        sliding = kind == "sliding_attention"
         w = lambda name, shape: _Kernel(shape, c.initializer_range, name=name)()  # noqa: E731
         with jax.named_scope("attn_proj"):
             q = _dot(h, w("q_proj", (E, H * d)), c.dtype).reshape(B, T, H, d)
             k = _dot(h, w("k_proj", (E, KV * d)), c.dtype).reshape(B, T, KV, d)
             v = _dot(h, w("v_proj", (E, KV * d)), c.dtype).reshape(B, T, KV, d)
+        if c.qk_norm:
+            q = RMSNorm(c.rms_norm_eps, d, name="q_norm")(q)
+            k = RMSNorm(c.rms_norm_eps, d, name="k_norm")(k)
         cos, sin, r = (c.rope_sliding if sliding else c.rope_full).tables(T, d)
         q = _rotate(q, cos, sin, r) / math.sqrt(d)
         k = _rotate(k, cos, sin, r)
-        with jax.named_scope("attn_window") if sliding else jax.named_scope("attn_full"):
-            o = banded_attention(q.astype(c.dtype), k.astype(c.dtype), v.astype(c.dtype),
-                                 window=c.sliding_window if sliding else None)
+        q, k, v = q.astype(c.dtype), k.astype(c.dtype), v.astype(c.dtype)
+        counters = None
+        if kind == "indexed_attention":
+            J, e = c.index_heads, c.index_head_dim
+            with jax.named_scope("attn_index"):
+                # the three projections are one leaf and one product, columns
+                # [queries J x e | the shared key e | the heads' weights J].
+                # S_t is a constant of the backward pass: no cotangent reaches
+                # the index, whose leaf takes the weight decay's term alone
+                x = jax.lax.stop_gradient(h)
+                proj = _dot(x, w("index_proj", (E, J * e + e + J)), c.dtype)
+                qi = proj[..., :J * e].reshape(B, T, J, e)
+                ki, wi = proj[..., J * e:J * e + e], proj[..., J * e + e:]
+            # the selection (attn_index/attn_select: scores and selection in
+            # one kernel) and the attention (attn_sparse) open their own scopes
+            o, counters = indexed_attention(q, k, v, qi.astype(c.dtype), ki.astype(c.dtype), wi,
+                                            topk=c.index_topk)
+        else:
+            with jax.named_scope("attn_window") if sliding else jax.named_scope("attn_full"):
+                o = banded_attention(q, k, v, window=c.sliding_window if sliding else None)
         with jax.named_scope("attn_proj"):
-            gate = jax.nn.sigmoid(_dot(h, w("g_proj", (E, H)), c.dtype))      # [B, T, H]
-            o = o.astype(jnp.float32) * gate[..., None]
-            return _dot(o.reshape(B, T, H * d), w("o_proj", (H * d, E)), c.dtype)
+            if c.output_gate:
+                gate = jax.nn.sigmoid(_dot(h, w("g_proj", (E, H)), c.dtype))  # [B, T, H]
+                o = o.astype(jnp.float32) * gate[..., None]
+            return _dot(o.reshape(B, T, H * d), w("o_proj", (H * d, E)), c.dtype), counters
 
 
 # ---- the routed experts ---------------------------------------------------------
 
-def _expert_rows(tok, sizes, rows, dtype):
+def _expert_rows(tok, sizes, rows, dtype, tiling):
     """The held experts' part of the layer over the first ``rows`` sorted
     assignments, as a function of what is differentiated:
     ``(h [N, E], wrow [R], gate, up, down [G, ...]) -> [N, E]`` float32."""
@@ -300,51 +352,55 @@ def _expert_rows(tok, sizes, rows, dtype):
         with jax.named_scope("moe_dispatch"):
             x = h.astype(dtype)[t]
         with jax.named_scope("moe_experts"):
-            a = jax.nn.silu(grouped_product(x, gate.astype(dtype), sizes))
-            a = a * grouped_product(x, up.astype(dtype), sizes)
-            y = grouped_product(a.astype(dtype), down.astype(dtype), sizes)
+            a = jax.nn.silu(grouped_product(x, gate.astype(dtype), sizes, tiling))
+            a = a * grouped_product(x, up.astype(dtype), sizes, tiling)
+            y = grouped_product(a.astype(dtype), down.astype(dtype), sizes, tiling)
         with jax.named_scope("moe_combine"):
             return jnp.zeros(h.shape, jnp.float32).at[t].add(y * wrow[:rows, None])
 
     return apply
 
 
-def _fast_rows(n_tokens, top_k, held, num_experts) -> int:
-    """Rows the fast branch moves: ``FAST_ROWS_FACTOR`` times the held
-    assignments a uniform router sends, in whole row tiles."""
-    tile = GMM_TILING[0]
+def _tier_rows(n_tokens, top_k, held, num_experts, factor, tile=GMM_TILING[0]) -> int:
+    """Rows a tier moves: ``factor`` times the held assignments a uniform
+    router sends, in whole row tiles."""
     expected = n_tokens * top_k * held / num_experts
-    return min(n_tokens * top_k, tile * max(1, math.ceil(FAST_ROWS_FACTOR * expected / tile)))
+    return min(n_tokens * top_k, tile * max(1, math.ceil(factor * expected / tile)))
 
 
-def make_routed_experts(fast_rows: int, dtype):
+def make_routed_experts(tiers, dtype, tiling=GMM_TILING):
     """``(h, tok, wrow, sizes, gate, up, down) -> ([N, E], rows taken)``:
     the held experts' weighted outputs summed per token, and how many of the
     sorted assignments the branch that ran gave the product (float32, no
     cotangent). ``tok`` ``[R]`` int32 is the token of each assignment in
     sorted order (held experts' first, grouped by slot), ``wrow`` ``[R]`` its
-    routing weight (0 where the expert is not held), ``sizes`` ``[G]`` each
-    held slot's count. Per client it takes the branch that moves
-    ``fast_rows`` rows when they hold every held assignment, and all ``R``
-    otherwise."""
+    routing weight (0 where the expert is not held), ``sizes`` ``[G]`` the
+    rows each held slot's product is given. Per client it takes the first
+    branch of ``tiers`` (rows moved, ascending) that holds ``sum(sizes)``
+    rows, and all ``R`` where none does."""
 
     def either(tok, sizes, make, *args):
         """``make(rows)(*args)`` at the rows this client needs."""
         every = tok.shape[0]
-        if fast_rows >= every:
+        rows = [r for r in tiers if r < every]
+        if not rows:
             return make(every)(*args)
-        return jax.lax.cond(jnp.sum(sizes) <= fast_rows, make(fast_rows), make(every), *args)
+        need = jnp.sum(sizes)
+        if len(rows) == 1:
+            return jax.lax.cond(need <= rows[0], make(rows[0]), make(every), *args)
+        return jax.lax.switch(sum((need > r).astype(jnp.int32) for r in rows),
+                              [make(r) for r in rows] + [make(every)], *args)
 
     def forward_one(h, tok, wrow, sizes, gate, up, down):
         def taking(rows):
-            apply = _expert_rows(tok, sizes, rows, dtype)
+            apply = _expert_rows(tok, sizes, rows, dtype, tiling)
             return lambda *a: (apply(*a), jnp.float32(rows))
 
         return either(tok, sizes, taking, h, wrow, gate, up, down)
 
     def backward_one(h, tok, wrow, sizes, gate, up, down, ct):
         def pull(rows):
-            return lambda *a: jax.vjp(_expert_rows(tok, sizes, rows, dtype), *a)[1](ct)
+            return lambda *a: jax.vjp(_expert_rows(tok, sizes, rows, dtype, tiling), *a)[1](ct)
 
         return either(tok, sizes, pull, h, wrow, gate, up, down)
 
@@ -366,6 +422,15 @@ def make_routed_experts(fast_rows: int, dtype):
     return routed_experts
 
 
+def _floored(sizes, floor: int):
+    """``sizes`` with the last slot grown until they sum to ``floor``: the
+    sorted rows after the held ones are assignments held elsewhere (weight 0:
+    they add nothing and take no cotangent), and with them in the last
+    slot's product its time no longer follows the router's skew below
+    ``floor`` rows."""
+    return sizes.at[-1].add(jnp.maximum(floor - jnp.sum(sizes), 0))
+
+
 class MoE(nn.Module):
     """The routed feed-forward on normed input ``h`` ``[B, T, E]``; returns
     ``(y, counters)``. ``shared`` off is for the share test alone."""
@@ -382,10 +447,17 @@ class MoE(nn.Module):
         h = h.reshape(N, E)
         with jax.named_scope("moe_route"):
             router = _Kernel((E, c.num_experts), std, name="router")()
-            scores = jax.nn.sigmoid(jnp.dot(h, router, precision=jax.lax.Precision.HIGHEST))
+            logits = jnp.dot(h, router, precision=jax.lax.Precision.HIGHEST)
+            if c.router == "sigmoid_scaled_shared":
+                scores, scaling = jax.nn.sigmoid(logits), c.moe_routed_scaling_factor
+            elif c.router == "softmax_renormalised":
+                scores, scaling = jax.nn.softmax(logits, -1), 1.0
+            else:
+                raise ValueError(f"unknown router form {c.router!r}")
             top_s, top_e = jax.lax.top_k(scores, K)                # ties to the lower id
-            weight = c.moe_routed_scaling_factor * top_s / jnp.sum(top_s, -1, keepdims=True)
-        y = SwiGLU(c, c.shared_expert_intermediate_size, name="shared")(h) if self.shared else 0.0
+            weight = scaling * top_s / jnp.sum(top_s, -1, keepdims=True)
+        shared = self.shared and c.router == "sigmoid_scaled_shared"
+        y = SwiGLU(c, c.shared_expert_intermediate_size, name="shared")(h) if shared else 0.0
         experts = _Experts(G, E, F, std, name="experts")()
         with jax.named_scope("moe_dispatch"):
             slot_of = np.full(c.num_experts, G, np.int32)
@@ -395,8 +467,11 @@ class MoE(nn.Module):
             sizes = jnp.sum(slot[:, None] == jnp.arange(G)[None, :], 0, dtype=jnp.int32)
             tok = (order // K).astype(jnp.int32)
             wrow = jnp.where(slot[order] < G, weight.reshape(-1)[order], 0.0)
-        apply = make_routed_experts(_fast_rows(N, K, G, c.num_experts), c.dtype)
-        routed_y, taken = apply(h, tok, wrow, sizes, *experts)
+        tiers = [_tier_rows(N, K, G, c.num_experts, f, c.expert_tiling[0])
+                 for f in c.expert_row_tiers]
+        apply = make_routed_experts(tiers, c.dtype, c.expert_tiling)
+        given = _floored(sizes, tiers[0]) if c.expert_rows_floored else sizes
+        routed_y, taken = apply(h, tok, wrow, given, *experts)
         y = y + routed_y
         routed = jnp.sum(slot < G).astype(jnp.float32)
         counters = {
@@ -434,12 +509,14 @@ class Block(nn.Module):
     @nn.compact
     def __call__(self, x):
         c = self.cfg
-        x = x + Attention(c, self.layer, name="attn")(RMSNorm(c.rms_norm_eps, c.hidden_size, name="attn_norm")(x))
+        a, attended = Attention(c, self.layer, name="attn")(
+            RMSNorm(c.rms_norm_eps, c.hidden_size, name="attn_norm")(x))
+        x = x + a
         h = RMSNorm(c.rms_norm_eps, c.hidden_size, name="mlp_norm")(x)
         if c.mlp_layer_types[self.layer] == "dense":
-            return x + SwiGLU(c, c.intermediate_size, name="mlp")(h), None
+            return x + SwiGLU(c, c.intermediate_size, name="mlp")(h), None, attended
         y, counters = MoE(c, name="moe")(h)
-        return x + y, counters
+        return x + y, counters, attended
 
 
 class LagunaLM(nn.Module):
@@ -448,7 +525,8 @@ class LagunaLM(nn.Module):
     ``lm_labels`` ``[B, T]`` (-100 masked) the first result is instead the
     next-token ``(nll sum, labels kept)``, the head and the cross-entropy
     rematerialized like a block: the ``[B, T, vocab_held]`` logits are not
-    kept for the backward pass."""
+    kept for the backward pass (with ``head_chunk`` they never exist whole:
+    the head and its cross-entropy run ``head_chunk`` positions at a time)."""
 
     cfg: LagunaConfig
 
@@ -457,16 +535,27 @@ class LagunaLM(nn.Module):
         c = self.cfg
         x = nn.Embed(c.vocab_held, c.hidden_size, name="embed", param_dtype=jnp.float32,
                      embedding_init=nn.initializers.normal(c.initializer_range))(input_ids)
-        block = nn.remat(Block)
-        per_layer = []
+        indexed = "indexed_attention" in c.layer_types
+        # the selection's thresholds (128 KB a sequence a layer) are kept for
+        # the backward pass: the recomputed forward attends to the same set
+        block = nn.remat(Block, policy=jax.checkpoint_policies.save_only_these_names(
+            SELECT_RESIDUAL)) if indexed else nn.remat(Block)
+        per_layer, attended = [], []
         for i in range(c.num_layers):
-            x, counters = block(c, i, name=f"layer_{i}")(x)
+            x, counters, pairs = block(c, i, name=f"layer_{i}")(x)
             if counters is not None:
                 per_layer.append(counters)
+            if pairs is not None:
+                attended.append(pairs)
         # over the routed layers: assignments and drops add up, the load is the worst
         reduce = {"moe/max_expert_load": jnp.max}
         totals = {k: reduce.get(k, jnp.sum)(jnp.stack([p[k] for p in per_layer]))
                   for k in (per_layer[0] if per_layer else ())}
+        # over the indexed layers: the pairs attended to, of the causal ones, and
+        # the queries whose threshold tied (``attn/selected_share`` is the ratio
+        # of the first two, taken where the sums over clients and rounds end)
+        totals.update({f"attn/{k}": jnp.sum(jnp.stack([p[k] for p in attended]))
+                       for k in (attended[0] if attended else ())})
         scale = RMSNorm(c.rms_norm_eps, c.hidden_size, name="final_norm").scale()
         head = _Kernel((c.hidden_size, c.vocab_held), c.initializer_range, name="lm_head")()
 
@@ -482,4 +571,23 @@ class LagunaLM(nn.Module):
                 return softmax_cross_entropy_sum(
                     logits(x, scale, head)[..., :-1, :], lm_labels[..., 1:])
 
-        return jax.checkpoint(nll)(x, scale, head), totals
+        if not c.head_chunk:
+            return jax.checkpoint(nll)(x, scale, head), totals
+
+        def nll_chunk(args):
+            """One chunk's ``(nll sum, labels kept)``, its logits recomputed
+            in the backward pass like the whole head's."""
+            x, targets = args
+            with jax.named_scope("lm_head"):
+                return softmax_cross_entropy_sum(logits(x, scale, head), targets)
+
+        # position t's target is label t + 1, and the last position has none:
+        # the same sum, a chunk of positions at a time
+        B, T = lm_labels.shape
+        n = T // c.head_chunk
+        targets = jnp.concatenate(
+            [lm_labels[:, 1:], jnp.full((B, 1), IGNORE_INDEX, lm_labels.dtype)], 1)
+        sums, kept = jax.lax.map(jax.checkpoint(nll_chunk), (
+            x.reshape(B, n, c.head_chunk, -1).swapaxes(0, 1),
+            targets.reshape(B, n, c.head_chunk).swapaxes(0, 1)))
+        return (jnp.sum(sums), jnp.sum(kept)), totals

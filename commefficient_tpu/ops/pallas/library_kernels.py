@@ -138,16 +138,17 @@ def banded_attention(q, k, v, *, window=None):
     return o.transpose(0, 3, 1, 2, 4).reshape(B, T, H, d)
 
 
-def grouped_product(rows, w, sizes):
+def grouped_product(rows, w, sizes, tiling=GMM_TILING):
     """``rows`` ``[M, K]``, sorted so that group ``g`` owns the
     ``sizes[g]`` rows after those of the groups before it; ``w``
     ``[G, K, N]`` -> ``[M, N]`` float32: each row times its own group's
     matrix. Rows past ``sum(sizes)`` belong to no group: they come back zero
     and take no cotangent. The kernel's grid is as long as the groups' rows
-    need (tiles of ``GMM_TILING[0]`` rows, a group's last tile padded), so
+    need (tiles of ``tiling[0]`` rows, a group's last tile padded), so
     its cost follows ``sum(sizes)`` and not ``M``; ``M`` is a multiple of the
-    row tile. Not batched: ``vmap`` reaches it through the caller's own rule
-    (``models/laguna.py``).
+    row tile. ``tiling`` is the kernel's (rows, contraction, columns) tile,
+    the same in its backward kernels. Not batched: ``vmap`` reaches it
+    through the caller's own rule (``models/laguna.py``).
 
     On ``cpu`` the kernel runs interpreted, but for one place: inside a
     ``shard_map`` Pallas's interpreter slices the kernel's scalar-prefetch
@@ -163,7 +164,7 @@ def grouped_product(rows, w, sizes):
     if interpret and jax.typeof(sizes).vma:
         y = _grouped_product_plain(rows, w, sizes)
     else:
-        tm, tk, tn = GMM_TILING
+        tm, tk, tn = tiling
         tiling = (tm, min(tk, rows.shape[1]), min(tn, w.shape[2]))
         y = _typed_call(lambda r, m: _megablox.gmm(
             r, m, sizes, jnp.float32, tiling, None, None, False, interpret))(rows, w)

@@ -161,6 +161,16 @@ MODEL_SCOPES: Tuple[Tuple[str, str], ...] = (
                   "output gate and the output projection"),
     ("mlp_dense", "the dense feed-forward of the leading layer and the "
                   "shared expert of a routed one (SwiGLU)"),
+    ("attn_index", "an indexed layer's learned index: its three projections "
+                   "and, nested under attn_select, the kernel that makes the "
+                   "scores and selects"),
+    ("attn_select", "finding each query's topk largest index scores: one "
+                    "kernel makes a query block's scores and radix-selects "
+                    "their threshold (ops/pallas/indexed_attention.py); "
+                    "nests under attn_index"),
+    ("attn_sparse", "the attention kernels over the chosen keys (forward, "
+                    "dq, dkv: each rebuilds the mask from the index's "
+                    "operands) with the transposes around them"),
 )
 
 # Priority order for exclusive assignment (idle is always the remainder).

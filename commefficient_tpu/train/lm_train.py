@@ -2,17 +2,19 @@
 
 The third workload entry, beside ``cv_train`` and ``gpt2_train``, over the
 same runner, session and sampler: a decoder-only LM (``--model laguna_xs2``:
-one chip's share of Laguna-XS.2, ``models/laguna.py``; ``laguna_tiny`` for
-the CPU) trained on ``--dataset_name fedtext`` (``data/fedtext.py``: packed
-documents, one shard of rows per client), next-token loss, eval reporting
-nll -> perplexity.
+one chip's share of Laguna-XS.2, ``models/laguna.py``; ``keye_vl2``: one
+chip's share of Keye-VL-2.0-30B-A3B's language model, ``models/keye.py``;
+``laguna_tiny`` / ``keye_tiny`` for the CPU) trained on ``--dataset_name
+fedtext`` (``data/fedtext.py``: packed documents, one shard of rows per
+client), next-token loss, eval reporting nll -> perplexity.
 
   python -m commefficient_tpu.train.lm_train --mode uncompressed \
       --num_workers 4 --local_batch_size 2 --max_seq_len 2048   # the chip
   python -m commefficient_tpu.train.lm_train --model laguna_tiny \
       --max_seq_len 128 --num_clients 8 --num_workers 2 --num_epochs 1  # CPU
 
-``--max_seq_len`` is a multiple of 128 (the attention kernel's lanes).
+``--max_seq_len`` is a multiple of 128 (the attention kernel's lanes);
+``--doc_median`` is the packed documents' median length (300).
 """
 
 from __future__ import annotations
@@ -25,12 +27,14 @@ import numpy as np
 
 from commefficient_tpu.data import FedSampler, load_fed_text
 from commefficient_tpu.models import causal_lm_loss
-from commefficient_tpu.models.laguna import PRESETS, LagunaLM
+from commefficient_tpu.models import keye, laguna
+from commefficient_tpu.models.laguna import LagunaLM
 from commefficient_tpu.models.losses import IGNORE_INDEX, model_dtype
 from commefficient_tpu.parallel import FederatedSession
 from commefficient_tpu.utils import Config, MetricsWriter, TableLogger, parse_args
 from commefficient_tpu.utils.logging import make_logdir
 
+PRESETS = {**laguna.PRESETS, **keye.PRESETS}
 DEFAULTS = dict(model="laguna_xs2", dataset_name="fedtext", num_clients=64,
                 local_batch_size=2, max_seq_len=2048, max_grad_norm=1.0, lr_scale=0.01)
 
@@ -49,7 +53,7 @@ def build_model_and_data(cfg: Config):
         raise ValueError(f"unknown lm dataset {cfg.dataset_name!r} (fedtext)")
     lcfg = PRESETS[cfg.model](dtype=model_dtype(cfg.compute_dtype))
     train, test = load_fed_text(num_clients=cfg.num_clients, seq_len=cfg.max_seq_len,
-                                vocab=lcfg.vocab_held, seed=cfg.seed)
+                                vocab=lcfg.vocab_held, seed=cfg.seed, doc_median=cfg.doc_median)
     model = LagunaLM(lcfg)
     # shapes only: the real init would run every kernel once on zeros
     shapes = jax.eval_shape(model.init, jax.random.key(cfg.seed),
@@ -85,25 +89,33 @@ class _LmHooks:
         self.test_ds, self.eval_batch_size = test_ds, eval_batch_size
 
     def new_accumulator(self):
-        return {"loss": 0.0, "held": 0.0, "dropped": 0.0}
+        return {"loss": 0.0, "held": 0.0, "dropped": 0.0, "selected": 0.0, "causal": 0.0,
+                "ties": 0.0}
 
     def accumulate(self, acc, loss, metrics):
         acc["loss"] += loss
         acc["held"] += float(metrics.get("moe/held_assignments", 0.0))
         acc["dropped"] += float(metrics.get("moe/dropped", 0.0))
+        # an indexed-attention model's counters (absent otherwise)
+        acc["selected"] += float(metrics.get("attn/selected_pairs", 0.0))
+        acc["causal"] += float(metrics.get("attn/causal_pairs", 0.0))
+        acc["ties"] += float(metrics.get("attn/select_ties", 0.0))
 
     def evaluate(self):
         return evaluate_ppl(self.session, self.test_ds, self.eval_batch_size)
 
     def epoch_row(self, *, epoch, lr, acc, val, train_time, val_time, steps_per_epoch):
-        return {
+        row = {
             "epoch": epoch + 1, "lr": lr,
             "train_loss": acc["loss"] / steps_per_epoch,
             "held_per_round": acc["held"] / steps_per_epoch,
             "dropped": acc["dropped"],
-            "val_nll": val["nll"], "val_ppl": val["ppl"],
-            "train_time": train_time, "val_time": val_time,
         }
+        if acc["causal"]:
+            # attn/selected_share: the pairs attended to over the causal pairs
+            row.update(selected_share=acc["selected"] / acc["causal"], select_ties=acc["ties"])
+        return {**row, "val_nll": val["nll"], "val_ppl": val["ppl"],
+                "train_time": train_time, "val_time": val_time}
 
     def write_val(self, writer, val, step):
         writer.scalar("val/nll", val["nll"], step)

@@ -151,6 +151,9 @@ class Config:
     lm_coef: float = 1.0
     mc_coef: float = 1.0
     max_seq_len: int = 256
+    # lm_train's FedText: the median document length the packed rows are made
+    # of (log-normal, sigma 1, clipped to 16..max_seq_len)
+    doc_median: float = 300.0
 
     # --- privacy (reference: DP clip+noise flags, fed_worker.py ~L380-420) ---
     dp_noise_multiplier: float = 0.0

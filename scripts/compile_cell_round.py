@@ -29,7 +29,7 @@ from jax.sharding import NamedSharding  # noqa: E402
 from jax.sharding import PartitionSpec as P  # noqa: E402
 
 from benchmark import run  # noqa: E402
-from commefficient_tpu.ops.pallas import library_kernels  # noqa: E402
+from commefficient_tpu.ops.pallas import indexed_attention, library_kernels  # noqa: E402
 from commefficient_tpu.ops.param_utils import ravel_params  # noqa: E402
 from commefficient_tpu.parallel.mesh import make_mesh  # noqa: E402
 from commefficient_tpu.parallel.round import build_round_fn, init_state  # noqa: E402
@@ -38,7 +38,8 @@ from commefficient_tpu.train import lm_train  # noqa: E402
 
 def main():
     jax.config.update("jax_enable_compilation_cache", False)
-    library_kernels.kernels_interpreted = lambda: False   # the default backend here is the CPU
+    # the default backend here is the CPU: take the chip's branch
+    library_kernels.kernels_interpreted = indexed_attention.kernels_interpreted = lambda: False
     cell = run.load_cell(sys.argv[1])
     cfg = lm_train.parse_args(
         cell["config_file"]["argv"] + cell["traffic_file"]["argv"]

@@ -53,6 +53,7 @@ CELL_PATHS = {
     "gpt2_sketch": {**_COMMON, "sketch_decode": "dense"},
     "gpt2_uncompressed": dict(_COMMON),
     "laguna_uncompressed": dict(_COMMON),
+    "keye_uncompressed": dict(_COMMON),
 }
 
 

@@ -1,0 +1,95 @@
+"""The Laguna presets and FedText's default rows are what they were before
+the decoder's attention kind, QK norm, output gate, router form and head
+chunk became fields of the configuration (PR 33) and FedText's
+``doc_median`` an argument of the entry: the parameter tree (paths and
+shapes), the tiny preset's loss to the last bit, every gradient to the last
+bit, the lowered program's text, and the rows' bytes, against
+``tests/golden/laguna_presets.json``, which was written from the commit
+before (``python tests/test_decoder_presets.py`` prints it anew: needed
+after a JAX upgrade moves the lowered text, and only then)."""
+
+import hashlib
+import json
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark import weights
+from commefficient_tpu.data import load_fed_text
+from commefficient_tpu.models.laguna import LagunaLM, laguna_tiny, laguna_xs2
+from commefficient_tpu.models.losses import causal_lm_loss
+
+GOLDEN = os.path.join(os.path.dirname(os.path.abspath(__file__)), "golden", "laguna_presets.json")
+
+
+def _sha(*chunks):
+    return hashlib.sha256(b"".join(chunks)).hexdigest()
+
+
+def _tree(preset):
+    shapes = jax.eval_shape(LagunaLM(preset()).init, jax.random.key(0),
+                            jnp.zeros((1, 128), jnp.int32))
+    return _sha(json.dumps([[n, list(a.shape)] for n, a in zip(
+        weights.leaf_names(shapes), jax.tree.leaves(shapes))]).encode())
+
+
+def _tiny_round():
+    cfg = laguna_tiny(dtype=jnp.float32)
+    model = LagunaLM(cfg)
+    ids = jax.random.randint(jax.random.key(1), (2, 128), 0, cfg.vocab_held)
+    batch = {"input_ids": ids, "lm_labels": jnp.where(jnp.arange(128)[None, :] < 120, ids, -100)}
+    params = weights.make(jax.eval_shape(model.init, jax.random.key(0), ids), 3, {"std": 0.02})
+    f = jax.jit(jax.value_and_grad(causal_lm_loss(model.apply, "float32"), has_aux=True))
+    (loss, _aux), grads = f(params, batch)
+    return {"tiny_loss_bits": int(np.asarray(loss).view(np.uint32)),
+            "tiny_grad_sha": _sha(*(np.asarray(g).tobytes() for g in jax.tree.leaves(grads))),
+            "tiny_lowered_sha": _sha(f.lower(params, batch).as_text().encode())}
+
+
+def _fedtext(**kw):
+    train, test = load_fed_text(num_clients=4, seq_len=256, vocab=512, seed=7, **kw)
+    return _sha(train.data["input_ids"].tobytes(), train.data["lm_labels"].tobytes(),
+                test.data["input_ids"].tobytes())
+
+
+@pytest.fixture(scope="module")
+def golden():
+    with open(GOLDEN) as f:
+        return json.load(f)
+
+
+@pytest.mark.parametrize("name,preset", [("laguna_xs2", laguna_xs2), ("laguna_tiny", laguna_tiny)])
+def test_the_preset_builds_the_tree_it_built(golden, name, preset):
+    assert _tree(preset) == golden[name]
+
+
+@pytest.mark.parametrize("what", ["tiny_loss_bits", "tiny_grad_sha", "tiny_lowered_sha"])
+def test_the_tiny_round_is_the_one_it_was(golden, what):
+    assert _tiny_round()[what] == golden[what]
+
+
+def test_fedtext_at_the_default_median_makes_the_rows_it_made(golden):
+    assert _fedtext() == golden["fedtext_sha"] == _fedtext(doc_median=300.0)
+    assert _fedtext(doc_median=100.0) != golden["fedtext_sha"]
+
+
+def test_the_entry_passes_the_median_and_defaults_to_300():
+    from commefficient_tpu.train import lm_train
+
+    argv = ["--model", "laguna_tiny", "--max_seq_len", "128", "--num_clients", "4",
+            "--num_workers", "2"]
+    cfg = lm_train.parse_args(argv, defaults=lm_train.DEFAULTS)
+    assert cfg.doc_median == 300.0
+    default = lm_train.build_model_and_data(cfg)[0].data["input_ids"]
+    want = load_fed_text(num_clients=4, seq_len=128, vocab=256, seed=cfg.seed)[0].data["input_ids"]
+    assert np.array_equal(default, want)
+    cfg = lm_train.parse_args(argv + ["--doc_median", "40"], defaults=lm_train.DEFAULTS)
+    assert not np.array_equal(lm_train.build_model_and_data(cfg)[0].data["input_ids"], default)
+
+
+if __name__ == "__main__":
+    print(json.dumps({"laguna_xs2": _tree(laguna_xs2), "laguna_tiny": _tree(laguna_tiny),
+                      **_tiny_round(), "fedtext_sha": _fedtext()}))

@@ -240,7 +240,7 @@ def test_nothing_is_dropped_whatever_the_router_sends(forced):
     forced to send every token to that expert fills 256: the branch that
     moves every row is taken and gives the reference's numbers."""
     cfg, layer, h, p = _moe_setup([2])
-    assert laguna._fast_rows(256, 2, 1, 16) == 128 < 256 * 2
+    assert laguna._tier_rows(256, 2, 1, 16, laguna.FAST_ROWS_FACTOR) == 128 < 256 * 2
     if forced:
         h = jnp.abs(h)
         router = jnp.zeros_like(p["params"]["router"]["kernel"]).at[:, 2].set(1.0)
@@ -296,8 +296,54 @@ def test_the_counter_reads_the_rows_the_branch_took(monkeypatch):
     assert float(counters["moe/dropped"]) == 0.0
     monkeypatch.setattr(jax.lax, "cond", lambda pred, fast, every, *a: fast(*a))
     short, counters = layer.apply(p, h)
-    assert float(counters["moe/dropped"]) == 256 - laguna._fast_rows(256, 2, 1, 16) == 128
+    fast = laguna._tier_rows(256, 2, 1, 16, laguna.FAST_ROWS_FACTOR)
+    assert float(counters["moe/dropped"]) == 256 - fast == 128
     assert not jnp.allclose(short, sound, atol=1e-3)
+
+
+@pytest.mark.parametrize("sent,tier", [("free", 128), ("one", 256), ("both", 512)])
+def test_tiers_and_a_floor_change_no_number(sent, tier):
+    """Two experts held of 16, tiers of 128 and 256 rows under the 512 of
+    every row, the first floored: a free router's ~64 rows run as 128, a
+    router forced onto one held expert fills the second tier, onto both the
+    last resort; each gives the reference's numbers and drops nothing."""
+    cfg, layer, h, p = _moe_setup([2, 5], expert_row_tiers=(2.0, 4.0), expert_rows_floored=True)
+    assert [laguna._tier_rows(256, 2, 2, 16, f) for f in (2.0, 4.0)] == [128, 256]
+    if sent != "free":
+        h = jnp.abs(h)
+        router = jnp.zeros_like(p["params"]["router"]["kernel"]).at[:, 2].set(1.0)
+        router = router.at[:, 5].set(1.0 if sent == "both" else -1.0)
+        p = {"params": {**p["params"], "router": {"kernel": router}}}
+    (y, counters), pull = jax.vjp(lambda p, h: layer.apply(p, h), p, h)
+    held = float(counters["moe/held_assignments"])
+    assert float(counters["moe/dropped"]) == 0.0
+    assert {128: held <= 128, 256: held == 256, 512: held == 512}[tier]
+    want, ref_pull = jax.vjp(lambda p, h: _ref_moe(cfg, p, h, [2, 5]), p, h)
+    np.testing.assert_allclose(y, want, atol=1e-5)
+    ct = jax.random.normal(jax.random.key(9), y.shape)
+    zero = jax.tree.map(jnp.zeros_like, counters)
+    for a, b in zip(jax.tree.leaves(pull((ct, zero))), jax.tree.leaves(ref_pull(ct))):
+        np.testing.assert_allclose(a, b, atol=2e-5)
+
+
+@pytest.mark.parametrize("sizes,want", [([3, 0, 5], [3, 0, 125]), ([100, 20, 8], [100, 20, 8]),
+                                        ([200, 0, 0], [200, 0, 0]), ([0, 0, 0], [0, 0, 128])])
+def test_the_floor_fills_the_last_slot_and_never_shrinks_one(sizes, want):
+    assert laguna._floored(jnp.array(sizes, jnp.int32), 128).tolist() == want
+
+
+@pytest.mark.parametrize("tiling", [(128, 512, 512), (256, 32, 16), (128, 64, 32)])
+def test_the_grouped_product_is_the_same_at_every_tile(tiling):
+    key = jax.random.key(0)
+    rows = jax.random.normal(key, (512, 64))
+    w = jax.random.normal(jax.random.fold_in(key, 1), (3, 64, 32))
+    sizes = jnp.array([10, 300, 50], jnp.int32)
+    kernel = lambda rows, w: library_kernels.grouped_product(rows, w, sizes, tiling)  # noqa: E731
+    plain = lambda rows, w: library_kernels._grouped_product_plain(rows, w, sizes)  # noqa: E731
+    np.testing.assert_allclose(kernel(rows, w), plain(rows, w), atol=1e-4)
+    ct = jax.random.normal(jax.random.fold_in(key, 2), (512, 32))
+    for a, b in zip(jax.vjp(kernel, rows, w)[1](ct), jax.vjp(plain, rows, w)[1](ct)):
+        np.testing.assert_allclose(a, b, atol=1e-4)
 
 
 def test_the_grouped_product_equals_a_dense_masked_product():

@@ -1,4 +1,5 @@
-"""The Laguna cell's two library kernels compiled for a described v5e chip
+"""The Laguna cell's two library kernels and the Keye cell's four indexed-
+attention kernels compiled for a described v5e chip
 (no chip attached, nothing runs): at the published widths, inside a
 ``shard_map`` that checks varying axes, under ``vmap`` over clients, forward
 and backward. What interpret mode cannot show: Mosaic's own refusals
@@ -14,7 +15,7 @@ import pytest
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from commefficient_tpu.ops.pallas import library_kernels
+from commefficient_tpu.ops.pallas import indexed_attention, library_kernels
 
 
 @pytest.fixture(scope="module")
@@ -32,6 +33,7 @@ def mesh():
 def compiled_kernels(monkeypatch):
     # the default backend here is the CPU: take the chip's branch
     monkeypatch.setattr(library_kernels, "kernels_interpreted", lambda: False)
+    monkeypatch.setattr(indexed_attention, "kernels_interpreted", lambda: False)
     library_kernels._attention_kernel.cache_clear()
     yield
     library_kernels._attention_kernel.cache_clear()
@@ -68,14 +70,19 @@ def test_attention_compiles_at_the_published_widths(mesh, compiled_kernels, head
     assert "2048,2048" not in text                       # no [T, T] operand, per head or whole
 
 
-def test_grouped_product_compiles_at_the_published_widths(mesh, compiled_kernels):
-    rows, hidden, width, held = 4096, 2048, 512, 8
+@pytest.mark.parametrize("rows,width,tiling", [
+    (4096, 512, library_kernels.GMM_TILING),        # Laguna-XS.2's fast branch
+    (36864, 768, (512, 1024, 1024)),                # Keye-VL-2.0's first tier and tile
+])
+def test_grouped_product_compiles_at_the_published_widths(mesh, compiled_kernels, rows, width,
+                                                          tiling):
+    hidden, held = 2048, 8
 
     def body(x, w, sizes):
         w = jax.lax.pcast(w, "workers", to="varying")
 
         def loss(x, w):
-            y = library_kernels.grouped_product(x[0], w.astype(jnp.bfloat16), sizes[0])
+            y = library_kernels.grouped_product(x[0], w.astype(jnp.bfloat16), sizes[0], tiling)
             return jnp.sum(y)
 
         return _total(jax.grad(loss, (0, 1))(x, w))
@@ -85,3 +92,27 @@ def test_grouped_product_compiles_at_the_published_widths(mesh, compiled_kernels
         jax.ShapeDtypeStruct((held, hidden, width), jnp.float32),
         jax.ShapeDtypeStruct((1, held), jnp.int32), specs=(P("workers"), P(), P("workers")))
     assert text.count("tpu_custom_call") >= 2            # the product and its transposes
+
+
+def test_indexed_attention_compiles_at_the_published_widths(mesh, compiled_kernels):
+    """Keye-VL-2.0's attention at the cell's own sizes: two clients' rows of
+    16,384 positions, 32 query heads over 4 KV heads of 128, an index of 16
+    heads of 64, topk 2,048; the selection kernel holds a query block's
+    ``[T, 512]`` keys in VMEM (32 MB: over Mosaic's default limit, inside
+    the kernels' own)."""
+    T, heads, kv, d, J, e, clients, rows = 16384, 32, 4, 128, 16, 64, 2, 1
+
+    def body(q, k, v, qi, ki, w):
+        def loss(q, k, v):
+            o, counters = jax.vmap(lambda *a: indexed_attention.indexed_attention(
+                *a, topk=2048))(q, k, v, qi, ki, w)
+            return jnp.sum(o.astype(jnp.float32)), counters
+
+        (_, counters), grads = jax.value_and_grad(loss, (0, 1, 2), has_aux=True)(q, k, v)
+        return _total(grads) + sum(jnp.sum(c) for c in counters.values())
+
+    shape = lambda *s, dtype=jnp.bfloat16: jax.ShapeDtypeStruct((clients, rows, T) + s, dtype)  # noqa: E731
+    text = _compile(mesh, body, shape(heads, d), shape(kv, d), shape(kv, d), shape(J, e),
+                    shape(e), shape(J, dtype=jnp.float32), specs=(P("workers"),) * 6)
+    assert text.count("tpu_custom_call") == 4            # select, forward, dq, dkv
+    assert "16384,16384" not in text                     # no [T, T] operand, per head or whole

@@ -155,14 +155,13 @@ def test_source_opens_only_scopes_of_the_list():
     assert opened == set(NAMES) | set(MODEL_NAMES)
 
 
-@pytest.fixture(scope="module")
-def laguna_op_names():
+def _lm_op_names(model):
     """The ``op_name`` metadata of the compiled index round of the LM entry
-    at ``laguna_tiny``, built as ``lm_train.main`` builds it."""
+    at a tiny preset, built as ``lm_train.main`` builds it."""
     from commefficient_tpu.train import lm_train
 
     cfg = lm_train.parse_args(
-        ["--model", "laguna_tiny", "--max_seq_len", "128", "--num_clients", "8",
+        ["--model", model, "--max_seq_len", "128", "--num_clients", "8",
          "--num_workers", "2", "--num_devices", "1", "--mode", "uncompressed"],
         defaults=lm_train.DEFAULTS)
     train, _test, _lcfg, _model, params, loss_fn = lm_train.build_model_and_data(cfg)
@@ -176,7 +175,26 @@ def laguna_op_names():
     return set(re.findall(r'op_name="([^"]*)"', text))
 
 
-@pytest.mark.parametrize("scope", MODEL_NAMES)
+@pytest.fixture(scope="module")
+def laguna_op_names():
+    return _lm_op_names("laguna_tiny")
+
+
+@pytest.fixture(scope="module")
+def keye_op_names():
+    return _lm_op_names("keye_tiny")
+
+
+# the scopes only an indexed-attention model opens, and the ones it never does
+INDEXED_ONLY = ("attn_index", "attn_select", "attn_sparse")
+NEVER_INDEXED = ("attn_full", "attn_window", "mlp_dense")
+
+
+def _under(names, scope):
+    return [n for n in names if re.search(r"\b" + scope + r"\b", n)]
+
+
+@pytest.mark.parametrize("scope", [n for n in MODEL_NAMES if n not in INDEXED_ONLY])
 def test_model_scopes_sit_under_client_grad_forward_and_backward(laguna_op_names, scope):
     """Each is in the round the LM entry compiles, always inside
     ``client_grad``, once wrapped by ``jvp(`` alone and once by
@@ -184,7 +202,7 @@ def test_model_scopes_sit_under_client_grad_forward_and_backward(laguna_op_names
     (A sub-computation the compiler shares between call sites is lowered
     once, under its own relative path: no ``jit(`` prefix, the scope still
     in the name.)"""
-    under = [n for n in laguna_op_names if re.search(r"\b" + scope + r"\b", n)]
+    under = _under(laguna_op_names, scope)
     assert under
     outside = [n for n in under if "client_grad" not in n and n.startswith("jit(")]
     assert not outside, outside
@@ -192,7 +210,43 @@ def test_model_scopes_sit_under_client_grad_forward_and_backward(laguna_op_names
     assert any("transpose(" not in n for n in under)
 
 
-def test_the_round_scopes_still_close_on_the_lm_round(laguna_op_names):
+@pytest.mark.parametrize("scope", [n for n in MODEL_NAMES if n not in NEVER_INDEXED])
+def test_model_scopes_of_an_indexed_model(keye_op_names, scope):
+    """The same at ``keye_tiny``: its own three scopes beside the ones it
+    shares with Laguna, and none of Laguna's attention kinds. The
+    selection runs once, in the forward pass (its thresholds cross ``remat``
+    as a residual), so ``attn_select`` alone has no backward wrapping, and it
+    nests under ``attn_index``, whose projections are recomputed."""
+    under = _under(keye_op_names, scope)
+    assert under
+    # (the chunked head is a loop, whose body the compiler names from the
+    # scope down: ``jit(wrapped)/lm_head/...``; the loop's own op carries the
+    # whole path, and a trace's union under ``client_grad`` holds its span)
+    assert not [n for n in under if "client_grad" not in n and n.startswith("jit(")
+                and not n.startswith(f"jit(wrapped)/{scope}/")]
+    assert any("transpose(" not in n for n in under)
+    assert any("transpose(" in n for n in under) == (scope != "attn_select")
+    if scope == "attn_select":
+        assert all("attn_index/" in n for n in under if n.startswith("jit("))
+
+
+@pytest.mark.parametrize("scope", INDEXED_ONLY)
+def test_laguna_opens_no_scope_of_the_index(laguna_op_names, scope):
+    assert not _under(laguna_op_names, scope)
+
+
+@pytest.mark.parametrize("scope", NEVER_INDEXED)
+def test_an_indexed_model_opens_none_of_lagunas_attention_kinds(keye_op_names, scope):
+    assert not _under(keye_op_names, scope)
+
+
+@pytest.mark.parametrize("names", ["laguna_op_names", "keye_op_names"])
+def test_the_round_scopes_still_close_on_the_lm_round(names, request):
     rx = re.compile("|".join(NAMES))
-    bare = {n for n in laguna_op_names if n.startswith("jit(") and not rx.search(n)}
-    assert bare <= {"jit(wrapped)/add"}, bare
+    bare = {n for n in request.getfixturevalue(names)
+            if n.startswith("jit(") and not rx.search(n)}
+    # the chunked head's loop: a cast and two index broadcasts the compiler
+    # lifts out of the body keep the model's scope and lose the round's
+    lifted = {n for n in bare if n.startswith("jit(wrapped)/lm_head/")}
+    assert bare - lifted <= {"jit(wrapped)/add"}, bare
+    assert len(lifted) <= 4 and (not lifted or names == "keye_op_names"), lifted
