@@ -34,7 +34,13 @@ way and keeps no residual of the branch not taken.
 
 Attention never forms ``[T, T]`` scores (``library_kernels.banded_attention``).
 Each block is rematerialized in the backward pass (``remat``): what is saved
-per block is its input, the float32 residual stream ``[B, T, hidden]``.
+per block is its input, the float32 residual stream ``[B, T, hidden]``. A
+decoder with ``indexed_attention`` layers saves two more things a block, by
+name (``ops/pallas/indexed_attention.py``): the selection's thresholds
+(``SELECT_RESIDUAL``, two ``[T]`` rows a sequence) and the attention kernel's
+output and log-sum-exp (``ATTEND_RESIDUAL``, ``[B, T, heads x head_dim]`` in
+the compute dtype and ``[B, heads, T]`` float32), so its backward pass runs
+neither the selection nor the forward attention kernel a second time.
 
 Precision under ``compute_dtype`` ``mixed`` (``dtype`` bfloat16): bfloat16
 operands into every product with float32 accumulation; the residual stream,
@@ -75,7 +81,11 @@ import jax.numpy as jnp
 import numpy as np
 
 from commefficient_tpu.models.losses import IGNORE_INDEX, softmax_cross_entropy_sum
-from commefficient_tpu.ops.pallas.indexed_attention import SELECT_RESIDUAL, indexed_attention
+from commefficient_tpu.ops.pallas.indexed_attention import (
+    ATTEND_RESIDUAL,
+    SELECT_RESIDUAL,
+    indexed_attention,
+)
 from commefficient_tpu.ops.pallas.library_kernels import (
     GMM_TILING,
     banded_attention,
@@ -536,10 +546,12 @@ class LagunaLM(nn.Module):
         x = nn.Embed(c.vocab_held, c.hidden_size, name="embed", param_dtype=jnp.float32,
                      embedding_init=nn.initializers.normal(c.initializer_range))(input_ids)
         indexed = "indexed_attention" in c.layer_types
-        # the selection's thresholds (128 KB a sequence a layer) are kept for
-        # the backward pass: the recomputed forward attends to the same set
+        # kept for the backward pass beside a block's input: the selection's
+        # thresholds (128 KB a sequence a layer: the recomputed forward attends
+        # to the same set) and the attention kernel's own residuals (its output
+        # and log-sum-exp: the recomputed forward does not run the kernel again)
         block = nn.remat(Block, policy=jax.checkpoint_policies.save_only_these_names(
-            SELECT_RESIDUAL)) if indexed else nn.remat(Block)
+            SELECT_RESIDUAL, ATTEND_RESIDUAL)) if indexed else nn.remat(Block)
         per_layer, attended = [], []
         for i in range(c.num_layers):
             x, counters, pairs = block(c, i, name=f"layer_{i}")(x)

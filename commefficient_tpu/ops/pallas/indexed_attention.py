@@ -32,6 +32,20 @@ broadcasts over the keys for nothing and lies dense in HBM):
 Gradients flow to ``q``, ``k``, ``v`` only: ``S_t`` is a constant of the
 backward pass and the index's operands take no cotangent.
 
+Under ``remat`` a block's policy is expected to save two names
+(``save_only_these_names(SELECT_RESIDUAL, ATTEND_RESIDUAL)``,
+``models/laguna.py::LagunaLM``). ``SELECT_RESIDUAL`` is the thresholds above.
+``ATTEND_RESIDUAL`` is what the forward kernel leaves its own backward pass:
+the output as the kernel wrote it (``o_t`` ``[B, KV, G, d, T]``, the compute
+dtype) and the log-sum-exp (``[B, H, T]`` float32). With both kept the
+backward pass recomputes ``q``, ``k``, ``v`` (its kernels read them) and
+rebuilds ``o`` from the saved ``o_t``, and does not run ``indexed_fwd``
+again: the kernel runs once a layer, not twice. At the Keye cell's shape
+(two clients' rows of 16,384, 32 heads of 128, bfloat16) that is 128 KB +
+272 MB a layer (``o_t`` 268 MB, ``lse`` 4.2 MB) held from the forward pass to
+the layer's backward, against a kernel of 0.038 s a layer. A policy that
+leaves ``ATTEND_RESIDUAL`` out gets the same floats and the second kernel.
+
 Compiled by Mosaic on ``tpu``, interpreted on ``cpu``
 (``countsketch_kernels.kernels_interpreted``).
 """
@@ -49,6 +63,7 @@ from jax.experimental.pallas import tpu as pltpu
 from commefficient_tpu.ops.pallas.countsketch_kernels import kernels_interpreted
 
 SELECT_RESIDUAL = "attn_select_threshold"
+ATTEND_RESIDUAL = "attn_sparse_output"
 BLOCK_Q = 256        # queries a tile (lanes)
 BLOCK_K = 512        # keys a tile (sublanes)
 BLOCK_Q_SELECT = 512  # queries whose causal scores sit in VMEM at once: [T, 512] int32
@@ -419,6 +434,7 @@ def _attend(q, k, v, qi, ki, w, tau, cut):
 
 def _attend_fwd(q, k, v, qi, ki, w, tau, cut):
     o_t, lse, n = _forward(q, k, v, qi, ki, w, tau, cut)
+    o_t, lse = (checkpoint_name(a, ATTEND_RESIDUAL) for a in (o_t, lse))
     return (o_t.swapaxes(-1, -2), n), (q, k, v, qi, ki, w, tau, cut, o_t, lse)
 
 

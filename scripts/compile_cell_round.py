@@ -1,7 +1,10 @@
 """Compile a benchmark cell's round at the cell's own size for a described
 v5e chip (no chip attached, nothing runs) and print XLA's memory analysis:
 what the TPU compiler and Mosaic refuse, and whether the round's temporaries
-fit beside its state, before a chip minute is spent.
+fit beside its state, before a chip minute is spent. Beside it, how many
+times the compiled round calls each of the indexed-attention kernels
+(`indexed_fwd` once a layer where the block's `remat` keeps its residuals,
+twice where it recomputes them; nothing for a cell without such layers).
 
     JAX_PLATFORMS=cpu python scripts/compile_cell_round.py laguna_uncompressed [--num_workers 2 ...]
 
@@ -13,6 +16,7 @@ Minutes of compile on this CPU; a compile that passes is not a chip run.
 """
 
 import os
+import re
 import sys
 import time
 
@@ -62,8 +66,12 @@ def main():
         jax.ShapeDtypeStruct((), jnp.float32, sharding=rep),
     ).lower(lowering_platforms=("tpu",)).compile()
     m = compiled.memory_analysis()
+    text = compiled.as_text()
+    calls = re.findall(r'^\s*%?(indexed_\w+?)[.\d]* = .*custom_call_target="tpu_custom_call"',
+                       text, re.M)
     print({"cell": cell["name"], "D": int(flat.size), "compile_s": round(time.time() - t0, 1),
-           "kernels": compiled.as_text().count("tpu_custom_call"),
+           "kernels": text.count("tpu_custom_call"),
+           "indexed_calls": {name: calls.count(name) for name in sorted(set(calls))},
            **{k: round(getattr(m, k) / 1e9, 3) for k in (
                "argument_size_in_bytes", "output_size_in_bytes", "temp_size_in_bytes",
                "generated_code_size_in_bytes")}})

@@ -221,17 +221,49 @@ def test_sequence_length_must_be_whole_lanes():
         ia.indexed_attention(*_operands(100), topk=32)
 
 
-def test_forward_and_backward_share_one_selection_a_layer(tiny):
+@pytest.fixture(scope="module")
+def kernel_calls(tiny):
+    """How often the gradient's jaxpr calls each kernel, by its name."""
+    text = str(jax.make_jaxpr(tiny["grad"])(tiny["params"], tiny["batch"]))
+    return lambda name: len(re.findall(rf"name={name}\b", text))
+
+
+def test_forward_and_backward_share_one_selection_a_layer(tiny, kernel_calls):
     """The selection's thresholds cross ``remat`` as a named residual: the
     gradient program runs the selection kernel once a layer, so the
-    recomputed forward and both backward kernels rebuild the mask of the
-    forward's set; the attention's forward kernel runs twice (recomputed)."""
-    text = str(jax.make_jaxpr(tiny["grad"])(tiny["params"], tiny["batch"]))
-    layers = tiny["cfg"].num_layers
-    assert len(re.findall(r"name=indexed_select\b", text)) == layers
-    assert len(re.findall(r"name=indexed_fwd\b", text)) == 2 * layers
-    assert len(re.findall(r"name=indexed_dq\b", text)) == layers
-    assert len(re.findall(r"name=indexed_dkv\b", text)) == layers
+    recomputed block and both backward kernels rebuild the mask of the
+    forward's set."""
+    assert kernel_calls("indexed_select") == tiny["cfg"].num_layers
+
+
+def test_the_forward_kernel_runs_once_a_layer(tiny, kernel_calls):
+    """The forward kernel's output and log-sum-exp cross ``remat`` as named
+    residuals too (``ATTEND_RESIDUAL``): the recomputed block makes ``q``,
+    ``k``, ``v`` for the backward kernels and does not call ``indexed_fwd``
+    again."""
+    names = ("indexed_fwd", "indexed_dq", "indexed_dkv", "indexed_select")
+    assert {name: kernel_calls(name) for name in names} == dict.fromkeys(
+        names, tiny["cfg"].num_layers)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "mixed"])
+def test_a_block_under_the_policy_has_the_gradients_of_a_block_without_remat(dtype):
+    """What the policy saves is what the recomputation would have made: the
+    same floats, parameters' and input's gradients alike."""
+    cfg = keye_tiny(dtype=dtype)
+    x = jax.random.normal(jax.random.key(4), (2, T, cfg.hidden_size))
+    params, _ = _seeded(Block(cfg, 0), x)
+    policy = jax.checkpoint_policies.save_only_these_names(
+        ia.SELECT_RESIDUAL, ia.ATTEND_RESIDUAL)
+
+    def grads(block):
+        loss = lambda p, x: jnp.sum(jnp.sin(block(cfg, 0).apply(p, x)[0]))  # noqa: E731
+        return jax.jit(jax.grad(loss, (0, 1)))(params, x)
+
+    plain, kept = grads(Block), grads(laguna.nn.remat(Block, policy=policy))
+    for a, b in zip(jax.tree.leaves(plain), jax.tree.leaves(kept)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert any(float(jnp.max(jnp.abs(a))) > 0 for a in jax.tree.leaves(plain))
 
 
 def test_vmap_over_clients_equals_a_loop_over_clients(tiny):

@@ -8,6 +8,8 @@ and backward. What interpret mode cannot show: Mosaic's own refusals
 (only one process may hold the TPU library; see the on-chip-measurement
 guide), and every such test lives in this one file."""
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -94,12 +96,28 @@ def test_grouped_product_compiles_at_the_published_widths(mesh, compiled_kernels
     assert text.count("tpu_custom_call") >= 2            # the product and its transposes
 
 
-def test_indexed_attention_compiles_at_the_published_widths(mesh, compiled_kernels):
+def _kernel_calls(text):
+    """Custom calls of the compiled text by the indexed-attention kernel's name."""
+    calls = re.findall(r'^\s*%?(indexed_\w+?)[.\d]* = .*custom_call_target="tpu_custom_call"',
+                       text, re.M)
+    return {name: calls.count(name) for name in set(calls)}
+
+
+BLOCK_POLICY = (indexed_attention.SELECT_RESIDUAL, indexed_attention.ATTEND_RESIDUAL)
+
+
+@pytest.mark.parametrize("saved,forwards", [(None, 1), (BLOCK_POLICY, 1), (BLOCK_POLICY[:1], 2)],
+                         ids=["no_remat", "block_policy", "thresholds_only"])
+def test_indexed_attention_compiles_at_the_published_widths(mesh, compiled_kernels, saved,
+                                                            forwards):
     """Keye-VL-2.0's attention at the cell's own sizes: two clients' rows of
     16,384 positions, 32 query heads over 4 KV heads of 128, an index of 16
     heads of 64, topk 2,048; the selection kernel holds a query block's
     ``[T, 512]`` keys in VMEM (32 MB: over Mosaic's default limit, inside
-    the kernels' own)."""
+    the kernels' own). Differentiated as it stands, through ``jax.checkpoint``
+    with the block's policy (the forward kernel's residuals are kept: one
+    ``indexed_fwd`` a call site) and with the thresholds' name alone (the
+    kernel runs again in the backward pass: what the second name is for)."""
     T, heads, kv, d, J, e, clients, rows = 16384, 32, 4, 128, 16, 64, 2, 1
 
     def body(q, k, v, qi, ki, w):
@@ -108,11 +126,16 @@ def test_indexed_attention_compiles_at_the_published_widths(mesh, compiled_kerne
                 *a, topk=2048))(q, k, v, qi, ki, w)
             return jnp.sum(o.astype(jnp.float32)), counters
 
-        (_, counters), grads = jax.value_and_grad(loss, (0, 1, 2), has_aux=True)(q, k, v)
-        return _total(grads) + sum(jnp.sum(c) for c in counters.values())
+        if saved is not None:
+            loss = jax.checkpoint(
+                loss, policy=jax.checkpoint_policies.save_only_these_names(*saved))
+        (total, counters), grads = jax.value_and_grad(loss, (0, 1, 2), has_aux=True)(q, k, v)
+        return _total(grads) + total + sum(jnp.sum(c) for c in counters.values())
 
     shape = lambda *s, dtype=jnp.bfloat16: jax.ShapeDtypeStruct((clients, rows, T) + s, dtype)  # noqa: E731
     text = _compile(mesh, body, shape(heads, d), shape(kv, d), shape(kv, d), shape(J, e),
                     shape(e), shape(J, dtype=jnp.float32), specs=(P("workers"),) * 6)
-    assert text.count("tpu_custom_call") == 4            # select, forward, dq, dkv
+    assert _kernel_calls(text) == {"indexed_select": 1, "indexed_fwd": forwards,
+                                   "indexed_dq": 1, "indexed_dkv": 1}
+    assert text.count("tpu_custom_call") == 3 + forwards
     assert "16384,16384" not in text                     # no [T, T] operand, per head or whole
