@@ -21,6 +21,7 @@ from typing import Dict, NamedTuple, Tuple
 
 import numpy as np
 
+from commefficient_tpu.data.augment import ImageAugment
 from commefficient_tpu.data.fed_dataset import FedDataset
 
 CIFAR10_MEAN = np.array([0.4914, 0.4822, 0.4465], np.float32)
@@ -233,16 +234,18 @@ class AugmentPlan(NamedTuple):
     cxs: np.ndarray  # [n] int
 
 
-class CifarAugment:
+class CifarAugment(ImageAugment):
     """pad(4) + random crop + hflip + cutout(8) — cifar10-fast prep, the
     analog of the reference's torchvision transform pipeline
     (``data_utils/fed_cifar.py`` ~L1-120).
 
-    ``plan()`` draws the randomness; ``apply()`` is the vectorized numpy
-    pixel path (the native C++ kernel in native/fedloader.cc and the jnp
-    ``device_augment`` are bit-identical — pinned by
-    tests/test_native_loader.py and tests/test_device_data.py). Calling
-    the object with ``(batch, rng)`` keeps the legacy per-batch API.
+    ``plan()`` draws the randomness; ``apply_pixels()`` is the vectorized
+    numpy pixel path (the native C++ kernel in native/fedloader.cc and the
+    jnp ``device_augment`` are bit-identical — pinned by
+    tests/test_native_loader.py and tests/test_device_data.py). The keyed
+    ``apply`` / ``device_apply`` / ``gather_apply`` the sampler and the
+    session call, and the per-batch ``(batch, rng)`` call, are
+    ``data.augment.ImageAugment``'s over these.
 
     Cutout fill: the reference applies cutout AFTER normalization, so its
     fill of 0.0 is the per-channel MEAN pixel. This pipeline augments
@@ -255,6 +258,7 @@ class CifarAugment:
 
     pad = 4
     cut_half = 4  # cutout8: an 8x8 window [c-4, c+4)
+    Plan = AugmentPlan
 
     def __init__(self, fill_uint8=None):
         if fill_uint8 is None:
@@ -276,7 +280,7 @@ class CifarAugment:
             cxs=rng.integers(0, w, size=n),
         )
 
-    def apply(self, x: np.ndarray, p: AugmentPlan) -> np.ndarray:
+    def apply_pixels(self, x: np.ndarray, p: AugmentPlan) -> np.ndarray:
         """[n, h, w, c] -> augmented copy (crop, then flip, then cutout —
         the order matters: cutout centers are in post-flip coords)."""
         n, h, w, c = x.shape
@@ -298,7 +302,7 @@ class CifarAugment:
         out[mask] = fill
         return out
 
-    def gather_apply(self, data: np.ndarray, idx: np.ndarray, p: AugmentPlan):
+    def gather_pixels(self, data: np.ndarray, idx: np.ndarray, p: AugmentPlan):
         """Fused native gather+augment; None when the C++ lib is absent
         (the sampler then falls back to ``apply`` on a numpy gather)."""
         from commefficient_tpu import native
@@ -308,17 +312,12 @@ class CifarAugment:
             fill=self._fill(data.dtype, data.shape[-1]),
         )
 
-    def device_apply(self, x, *plan):
-        """``apply`` as traced jnp ops for the device-resident data path."""
+    def device_pixels(self, x, *plan):
+        """``apply_pixels`` as traced jnp ops for the device-resident data path."""
         return device_augment(
             x, *plan, pad=self.pad, cut_half=self.cut_half,
             fill=self._fill(np.dtype(x.dtype), x.shape[-1]),
         )
-
-    def __call__(self, batch: Dict[str, np.ndarray], rng: np.random.Generator) -> Dict[str, np.ndarray]:
-        x = batch["x"]
-        p = self.plan(rng, x.shape[0], x.shape[1], x.shape[2])
-        return {**batch, "x": self.apply(x, p)}
 
 
 #: module-level instance — the historical function-style entry point.
@@ -327,7 +326,7 @@ augment_batch = CifarAugment()
 
 def device_augment(x, ys, xs, flips, cys, cxs, *, pad: int = 4,
                    cut_half: int = 4, fill=None):
-    """``CifarAugment.apply`` as traced jnp ops, for the device-resident
+    """``CifarAugment.apply_pixels`` as traced jnp ops, for the device-resident
     data path (the round gathers + augments INSIDE the jitted program, so
     only indices and this plan cross the host->device link).
 

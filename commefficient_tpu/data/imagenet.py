@@ -21,6 +21,7 @@ from typing import NamedTuple, Optional, Tuple
 
 import numpy as np
 
+from commefficient_tpu.data.augment import ImageAugment
 from commefficient_tpu.data.fed_dataset import FedDataset
 
 IMAGENET_MEAN = np.array([0.485, 0.456, 0.406], np.float32)
@@ -79,7 +80,7 @@ def _rrc_pixels(x, p: RRCPlan, xp):
     return top + (bot - top) * wyE
 
 
-class ImageNetAugment:
+class ImageNetAugment(ImageAugment):
     """Random-resized-crop + horizontal flip — the reference's ImageNet
     train transform (``data_utils/fed_imagenet.py`` ~L1-120 uses
     torchvision ``RandomResizedCrop`` + ``RandomHorizontalFlip``), realized
@@ -95,6 +96,8 @@ class ImageNetAugment:
     Note the source here is the size x size decode cache, not the original
     JPEG, so scale fractions are relative to the center-cropped cache.
     """
+
+    Plan = RRCPlan
 
     def __init__(self, scale=(0.08, 1.0), ratio=(3.0 / 4.0, 4.0 / 3.0),
                  attempts: int = 10):
@@ -134,7 +137,7 @@ class ImageNetAugment:
             flips=rng.random(n) < 0.5,
         )
 
-    def apply(self, x: np.ndarray, p: RRCPlan) -> np.ndarray:
+    def apply_pixels(self, x: np.ndarray, p: RRCPlan) -> np.ndarray:
         """[n, h, w, c] -> augmented copy (vectorized numpy path)."""
         val = _rrc_pixels(x, p, np)
         if x.dtype == np.uint8:
@@ -144,15 +147,15 @@ class ImageNetAugment:
         out[p.flips] = out[p.flips, :, ::-1]
         return out
 
-    def gather_apply(self, data: np.ndarray, idx: np.ndarray, p: RRCPlan):
+    def gather_pixels(self, data: np.ndarray, idx: np.ndarray, p: RRCPlan):
         """Fused native gather+augment; None when the C++ lib is absent
         (the sampler then falls back to ``apply`` on a numpy gather)."""
         from commefficient_tpu import native
 
         return native.gather_rrc(data, idx, p)
 
-    def device_apply(self, x, *plan):
-        """``apply`` as traced jnp ops for the device-resident data path."""
+    def device_pixels(self, x, *plan):
+        """``apply_pixels`` as traced jnp ops for the device-resident data path."""
         import jax.numpy as jnp
 
         p = RRCPlan(*plan)
@@ -162,11 +165,6 @@ class ImageNetAugment:
         else:
             out = val.astype(x.dtype)
         return jnp.where(p.flips[:, None, None, None], out[:, :, ::-1, :], out)
-
-    def __call__(self, batch, rng: np.random.Generator):
-        x = batch["x"]
-        p = self.plan(rng, x.shape[0], x.shape[1], x.shape[2])
-        return {**batch, "x": self.apply(x, p)}
 
 
 def _load_imagefolder(

@@ -47,20 +47,13 @@ class FedSampler:
         # fused batch assembly: one flat [W*B] gather (+ augment) per round
         # instead of per-client gather/augment/stack — the native C++
         # kernel when available, vectorized numpy otherwise. Requires a
-        # plan-based augment (data.cifar.CifarAugment) or none.
+        # plan-based augment (data.augment.BatchAugment: CifarAugment,
+        # ImageNetAugment, fedtext.BlockNoise) or none.
         self._planner = augment if hasattr(augment, "plan") else None
-        x = dataset.data.get("x")
         self._fusable = (
             (augment is None or self._planner is not None)
             and all(isinstance(v, np.ndarray) for v in dataset.data.values())
-            and (
-                self._planner is None
-                or (
-                    isinstance(x, np.ndarray)
-                    and x.ndim == 4
-                    and x.dtype in (np.float32, np.uint8)
-                )
-            )
+            and (self._planner is None or self._planner.accepts(dataset.data))
         )
 
     @property
@@ -106,22 +99,26 @@ class FedSampler:
                 for c in clients
             ]
         ).astype(np.int64)
-        batch: Batch = {}
         data = self.dataset.data
+        plan, made = (), None
+        if self._planner is not None:
+            plan = self._planner.plan(rng, W * B, *self._planner.plan_args(data))
+            # fused native gather+augment (planner-specific kernel); None
+            # when the planner has none or the C++ lib is absent
+            made = self._planner.gather_apply(data, flat, plan)
+        rows: Batch = {}
         for k, v in data.items():
-            if k == "x" and self._planner is not None:
-                p = self._planner.plan(rng, W * B, v.shape[1], v.shape[2])
-                # fused native gather+augment (planner-specific kernel);
-                # None when the C++ lib is absent
-                out = self._planner.gather_apply(v, flat, p)
-                if out is None:  # no native lib: numpy gather + apply
-                    out = self._planner.apply(np.ascontiguousarray(v[flat]), p)
-            else:
-                out = native.gather_rows(v, flat)
-                if out is None:
-                    out = v[flat]
-            batch[k] = out.reshape((W, B) + out.shape[1:])
-        return batch
+            if made is not None and k in made:
+                rows[k] = made[k]  # the fused kernel gathered it
+                continue
+            out = native.gather_rows(v, flat)
+            rows[k] = v[flat] if out is None else out
+        if self._planner is not None and made is None:  # numpy gather + apply
+            made = self._planner.apply(rows, *plan)
+        # a key the planner replaces stays in its place, the keys it adds
+        # come after the dataset's
+        rows.update(made or {})
+        return {k: out.reshape((W, B) + out.shape[1:]) for k, out in rows.items()}
 
     def sample_round_indices(self, round_idx: int):
         """(client_ids [W] int32, idx [W, B] int32, plan) — the index-only
@@ -149,8 +146,8 @@ class FedSampler:
         ).astype(np.int32)
         plan = ()
         if self._planner is not None:
-            x = self.dataset.data["x"]
-            plan = tuple(self._planner.plan(rng, W * B, x.shape[1], x.shape[2]))
+            plan = tuple(self._planner.plan(
+                rng, W * B, *self._planner.plan_args(self.dataset.data)))
         return clients.astype(np.int32), flat.reshape(W, B), plan
 
     def epoch(self, epoch_idx: int):

@@ -20,6 +20,7 @@ from commefficient_tpu.models.losses import (
     softmax_cross_entropy,
     classification_loss,
     gpt2_double_heads_loss,
+    block_diffusion_lm_loss,
     causal_lm_loss,
 )
 
@@ -50,5 +51,6 @@ __all__ = [
     "softmax_cross_entropy",
     "classification_loss",
     "gpt2_double_heads_loss",
+    "block_diffusion_lm_loss",
     "causal_lm_loss",
 ]

@@ -67,6 +67,21 @@ above; ``softmax_renormalised``: a softmax over all experts, the largest
 renormalised, no scaling factor, no shared expert). The defaults are
 Laguna's, whose presets build the tree and lower to the program they did
 before the fields were there (``tests/test_decoder_presets.py``).
+
+Since PR 35 a fourth attention kind, ``block_diffusion_attention``
+(``models/sdar.py``), and with it another objective: the decoder reads a
+stream of ``2 T`` positions, a row's noised copy then its clean one, under
+block diffusion's mask (``library_kernels.BlockDiffusionMask``: no ``[2T,
+2T]`` tensor either), both copies at the row's rotary positions ``0..T-1``.
+``LagunaLM`` makes the noised copy from ``input_ids`` and the batch's
+``noise_mask`` (``mask_token`` where it is set), joins and splits the two
+streams (scope ``diffusion_streams``), and gives the loss the noised
+stream's ``T`` positions through the same chunked, rematerialized head,
+each position's negative log-likelihood of its *own* clean token under its
+weight (scope ``diffusion_loss``): ``1 / t`` of its block where the noise
+masked it, 0 elsewhere, so a round does the same work whatever was drawn.
+The clean stream's last-layer output feeds nothing here. Such a layer saves
+what a full-attention layer saves: the block's input.
 """
 
 from __future__ import annotations
@@ -80,7 +95,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from commefficient_tpu.models.losses import IGNORE_INDEX, softmax_cross_entropy_sum
+from commefficient_tpu.models.losses import (
+    IGNORE_INDEX,
+    softmax_cross_entropy_sum,
+    weighted_cross_entropy_sum,
+)
 from commefficient_tpu.ops.pallas.indexed_attention import (
     ATTEND_RESIDUAL,
     SELECT_RESIDUAL,
@@ -188,6 +207,8 @@ class LagunaConfig:
     # uniform router sends (the last resort, every row, needs no entry)
     expert_row_tiers: Tuple[float, ...] = (FAST_ROWS_FACTOR,)
     expert_rows_floored: bool = False  # the product always runs over the first tier's rows
+    block_length: int = 0       # ``block_diffusion_attention`` layers: positions a block,
+    mask_token: int = -1        # and the id a noised position reads
 
     @property
     def num_layers(self) -> int:
@@ -312,7 +333,7 @@ class Attention(nn.Module):
         B, T, E = h.shape
         H, KV, d = c.num_attention_heads_per_layer[self.layer], c.num_key_value_heads, c.head_dim
         kind = c.layer_types[self.layer]
-        sliding = kind == "sliding_attention"
+        sliding, diffusion = kind == "sliding_attention", kind == "block_diffusion_attention"
         w = lambda name, shape: _Kernel(shape, c.initializer_range, name=name)()  # noqa: E731
         with jax.named_scope("attn_proj"):
             q = _dot(h, w("q_proj", (E, H * d)), c.dtype).reshape(B, T, H, d)
@@ -321,7 +342,10 @@ class Attention(nn.Module):
         if c.qk_norm:
             q = RMSNorm(c.rms_norm_eps, d, name="q_norm")(q)
             k = RMSNorm(c.rms_norm_eps, d, name="k_norm")(k)
-        cos, sin, r = (c.rope_sliding if sliding else c.rope_full).tables(T, d)
+        cos, sin, r = (c.rope_sliding if sliding else c.rope_full).tables(
+            T // 2 if diffusion else T, d)
+        if diffusion:   # the noised and the clean copy of a row sit at the row's positions
+            cos, sin = jnp.tile(cos, (2, 1)), jnp.tile(sin, (2, 1))
         q = _rotate(q, cos, sin, r) / math.sqrt(d)
         k = _rotate(k, cos, sin, r)
         q, k, v = q.astype(c.dtype), k.astype(c.dtype), v.astype(c.dtype)
@@ -341,6 +365,11 @@ class Attention(nn.Module):
             # one kernel) and the attention (attn_sparse) open their own scopes
             o, counters = indexed_attention(q, k, v, qi.astype(c.dtype), ki.astype(c.dtype), wi,
                                             topk=c.index_topk)
+        elif diffusion:
+            with jax.named_scope("attn_blockdiff"):
+                o = banded_attention(q, k, v, block_length=c.block_length)
+            # the pairs under the mask, a head: T (T + L) a row of T tokens
+            counters = {"blockdiff_pairs": jnp.float32(B * (T // 2) * (T // 2 + c.block_length))}
         else:
             with jax.named_scope("attn_window") if sliding else jax.named_scope("attn_full"):
                 o = banded_attention(q, k, v, window=c.sliding_window if sliding else None)
@@ -536,15 +565,29 @@ class LagunaLM(nn.Module):
     next-token ``(nll sum, labels kept)``, the head and the cross-entropy
     rematerialized like a block: the ``[B, T, vocab_held]`` logits are not
     kept for the backward pass (with ``head_chunk`` they never exist whole:
-    the head and its cross-entropy run ``head_chunk`` positions at a time)."""
+    the head and its cross-entropy run ``head_chunk`` positions at a time).
+
+    A block-diffusion decoder (module docstring) also takes ``noise``, the
+    batch's ``(noise_mask [B, T] bool, noise_t [B, T] float32)`` (none: no
+    position is noised); its logits are the noised stream's, and with
+    ``lm_labels`` its first result is ``(sum of nll x weight, labels kept)``,
+    with ``diffusion/*`` among the counters."""
 
     cfg: LagunaConfig
 
     @nn.compact
-    def __call__(self, input_ids, lm_labels=None):
+    def __call__(self, input_ids, lm_labels=None, noise=None):
         c = self.cfg
+        diffusion = "block_diffusion_attention" in c.layer_types
+        B, T = input_ids.shape
+        ids = input_ids
+        if diffusion:
+            with jax.named_scope("diffusion_streams"):
+                noised = input_ids if noise is None else jnp.where(
+                    noise[0], jnp.asarray(c.mask_token, input_ids.dtype), input_ids)
+                ids = jnp.concatenate([noised, input_ids], 1)         # [B, 2 T]
         x = nn.Embed(c.vocab_held, c.hidden_size, name="embed", param_dtype=jnp.float32,
-                     embedding_init=nn.initializers.normal(c.initializer_range))(input_ids)
+                     embedding_init=nn.initializers.normal(c.initializer_range))(ids)
         indexed = "indexed_attention" in c.layer_types
         # kept for the backward pass beside a block's input: the selection's
         # thresholds (128 KB a sequence a layer: the recomputed forward attends
@@ -575,8 +618,36 @@ class LagunaLM(nn.Module):
             with jax.named_scope("lm_head"):
                 return _dot(_rms(x, scale, c.rms_norm_eps), head, c.dtype)
 
+        if diffusion:
+            with jax.named_scope("diffusion_streams"):
+                x = x[:, :T]            # the clean stream's last output feeds nothing here
         if lm_labels is None:
             return logits(x, scale, head), totals
+
+        if diffusion:
+            if noise is None:
+                raise ValueError("a block-diffusion loss needs the batch's noise")
+            kept = lm_labels != IGNORE_INDEX
+            masked = noise[0] & kept
+            weight = jnp.where(masked, 1.0 / noise[1], 0.0)
+
+            def weighted_nll_chunk(args):
+                """One chunk's weighted sum, every position of it computed
+                (weight 0 where the noise left the token), logits recomputed
+                in the backward pass."""
+                x, targets, w = args
+                with jax.named_scope("diffusion_loss"):
+                    return weighted_cross_entropy_sum(logits(x, scale, head), targets, w)
+
+            chunk = c.head_chunk or T
+            by_chunk = lambda a: a.reshape(B, T // chunk, chunk, *a.shape[2:]).swapaxes(0, 1)  # noqa: E731
+            sums = jax.lax.map(jax.checkpoint(weighted_nll_chunk),
+                               (by_chunk(x), by_chunk(input_ids), by_chunk(weight)))
+            labelled = jnp.sum(kept, dtype=jnp.float32)
+            totals.update({"diffusion/masked_tokens": jnp.sum(masked, dtype=jnp.float32),
+                           "diffusion/labelled_tokens": labelled,
+                           "diffusion/weight_sum": jnp.sum(weight)})
+            return (jnp.sum(sums), labelled), totals
 
         def nll(x, scale, head):
             with jax.named_scope("lm_head"):
@@ -595,7 +666,6 @@ class LagunaLM(nn.Module):
 
         # position t's target is label t + 1, and the last position has none:
         # the same sum, a chunk of positions at a time
-        B, T = lm_labels.shape
         n = T // c.head_chunk
         targets = jnp.concatenate(
             [lm_labels[:, 1:], jnp.full((B, 1), IGNORE_INDEX, lm_labels.dtype)], 1)

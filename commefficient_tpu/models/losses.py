@@ -102,6 +102,16 @@ def softmax_cross_entropy_sum(logits: jnp.ndarray, labels: jnp.ndarray):
     return jnp.sum(nll * mask), jnp.sum(mask)
 
 
+def weighted_cross_entropy_sum(logits: jnp.ndarray, targets: jnp.ndarray,
+                               weights: jnp.ndarray) -> jnp.ndarray:
+    """``sum(weights * nll)``: each position's negative log-likelihood of its
+    target under its own weight (0 leaves a position out; every ``targets``
+    entry is a valid id). logits [..., V], targets and weights [...]."""
+    logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+    nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
+    return jnp.sum(nll * weights)
+
+
 def classification_loss(apply_fn, prep=None, compute_dtype=None):
     """Build the cv ``loss_fn``: batch = {"x": [B,H,W,C], "y": [B]}.
 
@@ -198,5 +208,31 @@ def causal_lm_loss(apply_fn, compute_dtype=None):
         loss = lm_sum / jnp.maximum(tok_count, 1.0)
         return loss, {"lm_loss": loss, "lm_loss_sum": lm_sum,
                       "token_count": tok_count, **counters}
+
+    return loss_fn
+
+
+def block_diffusion_lm_loss(apply_fn, compute_dtype=None):
+    """Build block diffusion's loss (BD3-LMs, arXiv:2503.09573; MDLM's
+    linear schedule): batch = ``causal_lm_loss``'s keys and the round's noise,
+    ``noise_mask`` ``[B, T]`` bool (the positions the noised copy reads as
+    ``[MASK]``) and ``noise_t`` ``[B, T]`` float32 (each position's own
+    block's ``t``), as ``data.fedtext.BlockNoise`` adds them. The loss is
+    ``sum_i m_i / t_i * nll_i(x_0[i]) / max(#labels kept, 1)``: no shift
+    between position and target, nothing from the clean stream.
+    ``apply_fn(params, input_ids, lm_labels, (noise_mask, noise_t)) ->
+    ((weighted nll sum, labels kept), counters)``; the counters
+    (``moe/*``, ``attn/blockdiff_pairs``, ``diffusion/*``) ride in the aux."""
+    cd = _resolve_compute_dtype(compute_dtype)
+
+    def loss_fn(params, batch, rng=None):
+        if cd is not None:
+            params = _cast_floats(params, cd)
+        (weighted, kept), counters = apply_fn(
+            params, batch["input_ids"], batch["lm_labels"],
+            (batch["noise_mask"], batch["noise_t"]))
+        loss = weighted / jnp.maximum(kept, 1.0)
+        return loss, {"lm_loss": loss, "lm_loss_sum": weighted,
+                      "token_count": kept, **counters}
 
     return loss_fn

@@ -29,6 +29,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental.pallas.ops.tpu.megablox import ops as _megablox
 from jax.experimental.pallas.ops.tpu.splash_attention import (
     splash_attention_kernel as _splash,
@@ -98,13 +99,60 @@ def _typed_call(call):
     return typed
 
 
+class BlockDiffusionMask(_mask._ComputableMask):
+    """Block diffusion's mask (BD3-LMs, arXiv:2503.09573) over a stream of
+    ``2 T`` positions, the noised copy of a row then the clean one, blocks of
+    ``block_length``: with ``b(i) = i // block_length`` a noised query ``i``
+    reads the noised keys of its own block and the clean keys of strictly
+    earlier blocks; a clean query reads the clean keys of its own and earlier
+    blocks, and no noised key. The library evaluates ``mask_function`` on
+    ``q_sequence``'s values and key positions, a tile at a time: in numpy
+    where it builds the tables of the tiles to visit (a tile wholly outside
+    the mask is skipped by the forward and both backward kernels), and inside
+    the kernels on the tiles visited, so the mask is never an array.
+
+    ``q_sequence`` is the library's per-query operand and need not be the
+    position: here it is the query's whole rule as one number, so that the
+    kernels compare and do not divide. A noised query of block ``b`` carries
+    ``c = b L`` (its block's first position), a clean one ``c = -(b + 1) L``;
+    a clean key ``k >= T`` is read where ``k - T < |c|``, a noised key where
+    ``0 <= k - c < L``, which no ``c < 0`` meets."""
+
+    def __init__(self, shape, block_length: int):
+        if shape[0] != shape[1] or shape[0] % 2 or (shape[0] // 2) % block_length:
+            raise ValueError(f"block diffusion mask: {shape} is not two rows of whole blocks "
+                             f"of {block_length}")
+        T, L = shape[0] // 2, block_length
+        self.block_length = L
+
+        def block_diffusion_mask_function(c, k):
+            at = k - c
+            return ((k >= T) & (k - T < abs(c))) | ((k < T) & (at >= 0) & (at < L))
+
+        super().__init__(shape=shape, mask_function=block_diffusion_mask_function)
+        first = np.arange(T, dtype=np.int32) // L * L
+        self.q_sequence = np.concatenate([first, -(first + L)])
+
+    def __eq__(self, other):
+        if not isinstance(other, type(self)):
+            return NotImplemented
+        return self.shape == other.shape and self.block_length == other.block_length
+
+    def __hash__(self):
+        return hash((type(self), self.shape, self.block_length))
+
+
 @functools.lru_cache(maxsize=None)
-def _attention_kernel(T, group, window, blk, interpret):
+def _attention_kernel(T, group, window, blk, interpret, block_length=0):
     """One KV head's multi-query kernel over its ``group`` of query heads.
     Built once per shape, its block-mask tables as concrete arrays: the
     caller's ``custom_vjp`` closes over them, which a tracer may not be."""
-    head = _mask.CausalMask((T, T)) if window is None else _mask.LocalMask(
-        (T, T), (window - 1, 0), 0)
+    if block_length:
+        head = BlockDiffusionMask((T, T), block_length)
+    elif window is None:
+        head = _mask.CausalMask((T, T))
+    else:
+        head = _mask.LocalMask((T, T), (window - 1, 0), 0)
     with jax.ensure_compile_time_eval():
         return _splash.make_splash_mqa_single_device(
             _mask.MultiHeadMask([head] * group),
@@ -115,7 +163,7 @@ def _attention_kernel(T, group, window, blk, interpret):
             interpret=interpret)
 
 
-def banded_attention(q, k, v, *, window=None):
+def banded_attention(q, k, v, *, window=None, block_length=0):
     """Causal grouped-query attention that never forms ``[T, T]`` scores.
 
     ``q`` ``[B, T, H, d]`` (already scaled), ``k``, ``v`` ``[B, T, KV, d]``
@@ -124,14 +172,17 @@ def banded_attention(q, k, v, *, window=None):
     are never repeated in memory. ``window`` keeps ``t - window < s <= t``
     (``None``: all ``s <= t``); blocks wholly outside that band are skipped
     by the kernel's block mask, in the forward and both backward kernels.
-    Running softmax statistics are float32; ``T`` is a multiple of 128."""
+    With ``block_length`` the ``T`` positions are a noised and a clean copy
+    of a row of ``T / 2`` and the mask is ``BlockDiffusionMask``: not causal,
+    and as little an array. Running softmax statistics are float32; ``T`` is
+    a multiple of 128."""
     B, T, H, d = q.shape
     KV = k.shape[2]
     group = H // KV
     if T % 128:
         raise ValueError(f"banded_attention: T={T} is not a multiple of 128 (the kernel's lanes)")
     kernel = _attention_kernel(T, group, window, ATTN_BLOCK if T % ATTN_BLOCK == 0 else 128,
-                               kernels_interpreted())
+                               kernels_interpreted(), block_length)
     q = q.reshape(B, T, KV, group, d).transpose(0, 2, 3, 1, 4)      # [B, KV, group, T, d]
     k, v = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)         # [B, KV, T, d]
     o = _typed_call(jax.vmap(jax.vmap(kernel)))(q, k, v)

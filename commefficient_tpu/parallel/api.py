@@ -978,12 +978,14 @@ class FederatedSession:
         The gather AND the crop/flip/cutout run inside the jitted round, so
         the host->device link carries practically nothing.
 
-        ``augment`` is a plan-based augmenter (data.cifar.CifarAugment,
-        data.imagenet.ImageNetAugment) or None; its ``device_apply(x,
+        ``augment`` is a plan-based augmenter (data.augment.BatchAugment:
+        data.cifar.CifarAugment, data.imagenet.ImageNetAugment,
+        data.fedtext.BlockNoise) or None; its ``device_apply(batch,
         *plan)`` realizes the same plan as the host paths inside the trace,
-        so training is unchanged (bit-identical for the pure index/select
-        CIFAR ops; within 1 uint8 LSB for bilinear RRC — see the
-        augmenters).
+        on the gathered rows' named keys, so training is unchanged
+        (bit-identical for the pure index/select CIFAR ops and for the
+        block noise's comparisons; within 1 uint8 LSB for bilinear RRC —
+        see the augmenters).
         """
         if self.cfg.client_state_hosted:
             raise NotImplementedError(
@@ -1036,12 +1038,11 @@ class FederatedSession:
             # telemetry.trace.ROUND_SCOPES: a name in the op metadata, no op
             with jax.named_scope("data_gather"):
                 flat = idx.reshape(-1)
-                batch = {}
-                for k, v in data.items():
-                    g = v[flat]
-                    if k == "x" and has_aug:
-                        g = augment.device_apply(g, *plan)
-                    batch[k] = g.reshape((W, B) + g.shape[1:])
+                batch = {k: v[flat] for k, v in data.items()}
+                if has_aug:  # the keys it replaces or adds (data/augment.py)
+                    batch.update(augment.device_apply(batch, *plan))
+                batch = {k: g.reshape((W, B) + g.shape[1:])
+                         for k, g in batch.items()}
                 if L:  # fedavg microbatch convention ([W, L, B/L, ...])
                     batch = {
                         k: v.reshape(v.shape[0], L, v.shape[1] // L,

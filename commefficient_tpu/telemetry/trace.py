@@ -171,6 +171,15 @@ MODEL_SCOPES: Tuple[Tuple[str, str], ...] = (
     ("attn_sparse", "the attention kernels over the chosen keys (forward, "
                     "dq, dkv: each rebuilds the mask from the index's "
                     "operands) with the transposes around them"),
+    ("attn_blockdiff", "the block-sparse attention kernels of a "
+                       "block-diffusion layer: a noised and a clean copy of "
+                       "a row under block diffusion's mask"),
+    ("diffusion_streams", "the noised copy of a row's ids, its join with the "
+                          "clean copy into one stream, and the split that "
+                          "keeps the noised stream for the head"),
+    ("diffusion_loss", "the head of a block-diffusion model (lm_head nests "
+                       "under it) and the weighted cross-entropy of the "
+                       "noised stream's positions"),
 )
 
 # Priority order for exclusive assignment (idle is always the remainder).

@@ -1,12 +1,19 @@
-"""lm_train — causal language modelling on a packed federated text set.
+"""lm_train — language modelling on a packed federated text set.
 
 The third workload entry, beside ``cv_train`` and ``gpt2_train``, over the
 same runner, session and sampler: a decoder-only LM (``--model laguna_xs2``:
 one chip's share of Laguna-XS.2, ``models/laguna.py``; ``keye_vl2``: one
 chip's share of Keye-VL-2.0-30B-A3B's language model, ``models/keye.py``;
-``laguna_tiny`` / ``keye_tiny`` for the CPU) trained on ``--dataset_name
-fedtext`` (``data/fedtext.py``: packed documents, one shard of rows per
-client), next-token loss, eval reporting nll -> perplexity.
+``sdar_30b_a3b``: one chip's share of SDAR-30B-A3B-Chat, ``models/sdar.py``;
+``laguna_tiny`` / ``keye_tiny`` / ``sdar_tiny`` for the CPU) trained on
+``--dataset_name fedtext`` (``data/fedtext.py``: packed documents, one shard
+of rows per client), eval reporting nll -> perplexity. The preset decides
+the objective: next-token loss, or for a block-diffusion preset (its
+``block_length`` set) the ``1 / t``-weighted loss on the positions a fresh
+noise masks every round. That noise rides the round's feed
+(``fedtext.BlockNoise``: the sampler's plan, applied in the graph beside the
+gather); eval's is one fixed draw a test row, so its nll is a bound on the
+data's, comparable from epoch to epoch.
 
   python -m commefficient_tpu.train.lm_train --mode uncompressed \
       --num_workers 4 --local_batch_size 2 --max_seq_len 2048   # the chip
@@ -26,15 +33,16 @@ import jax.numpy as jnp
 import numpy as np
 
 from commefficient_tpu.data import FedSampler, load_fed_text
-from commefficient_tpu.models import causal_lm_loss
-from commefficient_tpu.models import keye, laguna
+from commefficient_tpu.data.fedtext import BlockNoise
+from commefficient_tpu.models import block_diffusion_lm_loss, causal_lm_loss
+from commefficient_tpu.models import keye, laguna, sdar
 from commefficient_tpu.models.laguna import LagunaLM
 from commefficient_tpu.models.losses import IGNORE_INDEX, model_dtype
 from commefficient_tpu.parallel import FederatedSession
 from commefficient_tpu.utils import Config, MetricsWriter, TableLogger, parse_args
 from commefficient_tpu.utils.logging import make_logdir
 
-PRESETS = {**laguna.PRESETS, **keye.PRESETS}
+PRESETS = {**laguna.PRESETS, **keye.PRESETS, **sdar.PRESETS}
 DEFAULTS = dict(model="laguna_xs2", dataset_name="fedtext", num_clients=64,
                 local_batch_size=2, max_seq_len=2048, max_grad_norm=1.0, lr_scale=0.01)
 
@@ -45,6 +53,11 @@ def mask_lm(batch, row_mask):
                                             IGNORE_INDEX)}
 
 
+def round_augment(lcfg):
+    """What the preset's objective adds to a round's feed (none: nothing)."""
+    return BlockNoise(lcfg.block_length) if lcfg.block_length else None
+
+
 def build_model_and_data(cfg: Config):
     """``(train, test, lcfg, model, params, loss_fn)``."""
     if cfg.model not in PRESETS:
@@ -52,14 +65,21 @@ def build_model_and_data(cfg: Config):
     if cfg.dataset_name != "fedtext":
         raise ValueError(f"unknown lm dataset {cfg.dataset_name!r} (fedtext)")
     lcfg = PRESETS[cfg.model](dtype=model_dtype(cfg.compute_dtype))
+    noise = round_augment(lcfg)
+    # a block-diffusion preset's [MASK] (the id under <eos>) is in no document
     train, test = load_fed_text(num_clients=cfg.num_clients, seq_len=cfg.max_seq_len,
-                                vocab=lcfg.vocab_held, seed=cfg.seed, doc_median=cfg.doc_median)
+                                vocab=lcfg.vocab_held, seed=cfg.seed, doc_median=cfg.doc_median,
+                                reserved=1 if noise else 0)
+    if noise:   # eval's noise is drawn once, a test row its own
+        test.data.update(noise.apply(test.data, *noise.fixed(len(test), cfg.max_seq_len,
+                                                             cfg.seed)))
     model = LagunaLM(lcfg)
     # shapes only: the real init would run every kernel once on zeros
     shapes = jax.eval_shape(model.init, jax.random.key(cfg.seed),
                             jnp.zeros((1, cfg.max_seq_len), jnp.int32))
     params = _init_params(shapes, cfg.seed, lcfg.initializer_range)
-    return train, test, lcfg, model, params, causal_lm_loss(
+    make_loss = block_diffusion_lm_loss if noise else causal_lm_loss
+    return train, test, lcfg, model, params, make_loss(
         model.apply, compute_dtype=cfg.compute_dtype)
 
 
@@ -90,7 +110,7 @@ class _LmHooks:
 
     def new_accumulator(self):
         return {"loss": 0.0, "held": 0.0, "dropped": 0.0, "selected": 0.0, "causal": 0.0,
-                "ties": 0.0}
+                "ties": 0.0, "masked": 0.0, "labelled": 0.0}
 
     def accumulate(self, acc, loss, metrics):
         acc["loss"] += loss
@@ -100,6 +120,9 @@ class _LmHooks:
         acc["selected"] += float(metrics.get("attn/selected_pairs", 0.0))
         acc["causal"] += float(metrics.get("attn/causal_pairs", 0.0))
         acc["ties"] += float(metrics.get("attn/select_ties", 0.0))
+        # a block-diffusion model's (absent otherwise)
+        acc["masked"] += float(metrics.get("diffusion/masked_tokens", 0.0))
+        acc["labelled"] += float(metrics.get("diffusion/labelled_tokens", 0.0))
 
     def evaluate(self):
         return evaluate_ppl(self.session, self.test_ds, self.eval_batch_size)
@@ -114,6 +137,9 @@ class _LmHooks:
         if acc["causal"]:
             # attn/selected_share: the pairs attended to over the causal pairs
             row.update(selected_share=acc["selected"] / acc["causal"], select_ties=acc["ties"])
+        if acc["labelled"]:
+            # the labelled tokens the rounds' noise masked: the mean t, 0.5 under U[1e-3, 1]
+            row.update(masked_share=acc["masked"] / acc["labelled"])
         return {**row, "val_nll": val["nll"], "val_ppl": val["ppl"],
                 "train_time": train_time, "val_time": val_time}
 
@@ -146,10 +172,15 @@ def train_loop(cfg: Config, session: FederatedSession, sampler: FedSampler, test
 
 def build_session_and_sampler(cfg: Config, train, params, loss_fn):
     session = FederatedSession(cfg, params, loss_fn, mask_batch=mask_lm)
+    # the preset again, for its block length alone: the benchmark's entry calls
+    # this with these four arguments (benchmark/entries/lm_train.py)
+    augment = round_augment(PRESETS[cfg.model]())
     sampler = FedSampler(train, num_workers=cfg.num_workers,
-                         local_batch_size=cfg.sampler_batch_size, seed=cfg.seed)
+                         local_batch_size=cfg.sampler_batch_size, seed=cfg.seed,
+                         augment=augment)
     # the token rows live in HBM (8 MB); rounds ship only [W, B] indices
-    session.maybe_attach_data(train, sampler)
+    # and, for a block-diffusion preset, the round's noise as its plan
+    session.maybe_attach_data(train, sampler, augment)
     return session, sampler
 
 

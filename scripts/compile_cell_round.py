@@ -48,7 +48,7 @@ def main():
     cfg = lm_train.parse_args(
         cell["config_file"]["argv"] + cell["traffic_file"]["argv"]
         + ["--telemetry_level", "0"] + sys.argv[2:], defaults=lm_train.DEFAULTS)
-    _train, _test, _lcfg, _model, params, loss_fn = lm_train.build_model_and_data(cfg)
+    _train, _test, lcfg, _model, params, loss_fn = lm_train.build_model_and_data(cfg)
     flat, unravel = ravel_params(params)
     topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
     mesh = make_mesh(1, 1, 1, devices=topo.devices[:1])
@@ -60,6 +60,9 @@ def main():
     W, B, T = cfg.num_workers, cfg.local_batch_size, cfg.max_seq_len
     batch = {k: jax.ShapeDtypeStruct((W, B, T), jnp.int32, sharding=workers)
              for k in ("input_ids", "lm_labels")}
+    if lm_train.round_augment(lcfg):   # a block-diffusion preset's noise rides the batch
+        batch.update(noise_mask=jax.ShapeDtypeStruct((W, B, T), jnp.bool_, sharding=workers),
+                     noise_t=jax.ShapeDtypeStruct((W, B, T), jnp.float32, sharding=workers))
     t0 = time.time()
     compiled = round_fn.trace(
         state, jax.ShapeDtypeStruct((W,), jnp.int32, sharding=workers), batch,
@@ -72,6 +75,9 @@ def main():
     print({"cell": cell["name"], "D": int(flat.size), "compile_s": round(time.time() - t0, 1),
            "kernels": text.count("tpu_custom_call"),
            "indexed_calls": {name: calls.count(name) for name in sorted(set(calls))},
+           # a buffer with two sequence-length extents, whatever else it holds
+           "square_buffers": sorted(set(re.findall(
+               rf"\[[\d,]*\b(?:{T},{T}|{2 * T},{2 * T})\b[\d,]*\]", text))),
            **{k: round(getattr(m, k) / 1e9, 3) for k in (
                "argument_size_in_bytes", "output_size_in_bytes", "temp_size_in_bytes",
                "generated_code_size_in_bytes")}})

@@ -145,21 +145,35 @@ def expert_chain(tiling, rows=32768, E=2048, F=768, G=8):
     return jax.grad(f, (0, 1, 2, 3)), args
 
 
-def held_loads(seeds, clients, T):
+# --model: (the cell, its tiny preset, the cell's row length and document median)
+MODELS = {"keye_vl2": ("keye_uncompressed", "keye_tiny", 16384, 4096),
+          "sdar_30b_a3b": ("sdar_uncompressed", "sdar_tiny", 8192, 2048)}
+
+
+def held_loads(seeds, clients, model, tiny):
     """``moe/held_assignments`` and ``moe/max_expert_load`` of each layer,
-    for each seed's benchmark weights and each client's first row."""
+    for each seed's benchmark weights and each client's first row (a
+    block-diffusion model's: the row's noised copy under one fixed draw, then
+    its clean one, as its blocks see them)."""
     import numpy as np
 
     from benchmark import weights
     from commefficient_tpu.data.fedtext import load_fed_text
-    from commefficient_tpu.models import keye
     from commefficient_tpu.models.laguna import Block, LagunaLM
+    from commefficient_tpu.train import lm_train
 
-    tiny = T < 16384
-    cfg = keye.keye_tiny() if tiny else keye.keye_vl2()
+    _, small, T, median = MODELS[model]
+    cfg = lm_train.PRESETS[small if tiny else model]()
+    T = 128 if tiny else T
+    noise = lm_train.round_augment(cfg)
     train, _ = load_fed_text(num_clients=clients, seq_len=T, vocab=cfg.vocab_held, seed=42,
-                             doc_median=40 if tiny else 4096)
-    ids = jnp.asarray(train.data["input_ids"][::8][:clients])
+                             doc_median=40 if tiny else median, reserved=1 if noise else 0)
+    rows = {k: v[::8][:clients] for k, v in train.data.items()}
+    ids = rows["input_ids"]
+    if noise:
+        masked = noise.apply(rows, *noise.fixed(clients, T, 42))["noise_mask"]
+        ids = np.concatenate([np.where(masked, cfg.mask_token, ids), ids], 1)
+    ids = jnp.asarray(ids)
     shapes = jax.eval_shape(LagunaLM(cfg).init, jax.random.key(0), jnp.zeros((1, T), jnp.int32))
     leaves, treedef = jax.tree.flatten(shapes)
     names = weights.leaf_names(shapes)
@@ -181,7 +195,7 @@ def held_loads(seeds, clients, T):
             out.append((c["moe/held_assignments"], c["moe/max_expert_load"]))
         return out
 
-    expected = T * cfg.num_experts_per_tok * len(cfg.experts_held) / cfg.num_experts
+    expected = ids.shape[1] * cfg.num_experts_per_tok * len(cfg.experts_held) / cfg.num_experts
     for seed in seeds:
         params = draw(jax.random.key(seed))
         got = np.asarray([[[float(v) for v in lc] for lc in loads(params, row)] for row in ids])
@@ -190,13 +204,13 @@ def held_loads(seeds, clients, T):
             total_over_expected=float(got[:, :, 0].sum() / (expected * got[:, :, 0].size)))
 
 
-def round_times(seeds, count, tiny):
-    """``count`` rounds of ``keye_uncompressed`` a seed, each fenced."""
+def round_times(seeds, count, model, tiny):
+    """``count`` rounds of the model's cell a seed, each fenced."""
     from benchmark import run, weights
     from commefficient_tpu.ops.param_utils import ravel_params
     from commefficient_tpu.utils.platform import configure_compile_cache
 
-    cell = run.load_cell("keye_uncompressed")
+    cell = run.load_cell(MODELS[model][0])
     extra = ()
     if tiny:
         extra = run.apply_tiny(cell)
@@ -228,6 +242,8 @@ def main():
     ap.add_argument("--reps", type=int, default=5)
     ap.add_argument("--T", type=int, default=16384)
     ap.add_argument("--only", default="check,select,attend,xla")
+    ap.add_argument("--model", default="keye_vl2", choices=sorted(MODELS),
+                    help="whose preset and cell `loads` and `rounds` read")
     ap.add_argument("--rehearse", action="store_true",
                     help="walk the script on the CPU at --T 512 (no reading is one of the chip)")
     args = ap.parse_args()
@@ -239,10 +255,10 @@ def main():
     make = operands
     if "rounds" in only:
         round_times([int(x) for x in args.seeds.split(",")], 2 if args.rehearse else 9,
-                    args.rehearse)
+                    args.model, args.rehearse)
     if "loads" in only:
-        held_loads([int(x) for x in args.seeds.split(",")], args.clients,
-                   128 if args.rehearse else T)
+        held_loads([int(x) for x in args.seeds.split(",")], args.clients, args.model,
+                   args.rehearse)
     if "experts" in only:
         rows = 1024 if args.rehearse else 32768
         tilings = ((128, 512, 512), (256, 512, 512), (512, 512, 512), (512, 1024, 512),

@@ -54,6 +54,7 @@ CELL_PATHS = {
     "gpt2_uncompressed": dict(_COMMON),
     "laguna_uncompressed": dict(_COMMON),
     "keye_uncompressed": dict(_COMMON),
+    "sdar_uncompressed": dict(_COMMON),
 }
 
 

@@ -6,7 +6,12 @@ shapes), the tiny preset's loss to the last bit, every gradient to the last
 bit, the lowered program's text, and the rows' bytes, against
 ``tests/golden/laguna_presets.json``, which was written from the commit
 before (``python tests/test_decoder_presets.py`` prints it anew: needed
-after a JAX upgrade moves the lowered text, and only then)."""
+after a JAX upgrade moves the lowered text, and only then).
+
+Since PR 35 (a fourth attention kind, ``block_length`` and ``mask_token`` as
+fields, FedText's ``reserved``) the same is held for Keye's presets, whose
+numbers were written from the commit before too, and the file also keeps
+the trees of SDAR's presets as that PR built them."""
 
 import hashlib
 import json
@@ -19,7 +24,9 @@ import pytest
 
 from benchmark import weights
 from commefficient_tpu.data import load_fed_text
+from commefficient_tpu.models.keye import keye_tiny, keye_vl2
 from commefficient_tpu.models.laguna import LagunaLM, laguna_tiny, laguna_xs2
+from commefficient_tpu.models.sdar import sdar_30b_a3b, sdar_tiny
 from commefficient_tpu.models.losses import causal_lm_loss
 
 GOLDEN = os.path.join(os.path.dirname(os.path.abspath(__file__)), "golden", "laguna_presets.json")
@@ -36,17 +43,18 @@ def _tree(preset):
         weights.leaf_names(shapes), jax.tree.leaves(shapes))]).encode())
 
 
-def _tiny_round():
-    cfg = laguna_tiny(dtype=jnp.float32)
+def _tiny_round(preset=laguna_tiny, prefix=""):
+    cfg = preset(dtype=jnp.float32)
     model = LagunaLM(cfg)
     ids = jax.random.randint(jax.random.key(1), (2, 128), 0, cfg.vocab_held)
     batch = {"input_ids": ids, "lm_labels": jnp.where(jnp.arange(128)[None, :] < 120, ids, -100)}
     params = weights.make(jax.eval_shape(model.init, jax.random.key(0), ids), 3, {"std": 0.02})
     f = jax.jit(jax.value_and_grad(causal_lm_loss(model.apply, "float32"), has_aux=True))
     (loss, _aux), grads = f(params, batch)
-    return {"tiny_loss_bits": int(np.asarray(loss).view(np.uint32)),
-            "tiny_grad_sha": _sha(*(np.asarray(g).tobytes() for g in jax.tree.leaves(grads))),
-            "tiny_lowered_sha": _sha(f.lower(params, batch).as_text().encode())}
+    return {prefix + "tiny_loss_bits": int(np.asarray(loss).view(np.uint32)),
+            prefix + "tiny_grad_sha": _sha(*(np.asarray(g).tobytes()
+                                             for g in jax.tree.leaves(grads))),
+            prefix + "tiny_lowered_sha": _sha(f.lower(params, batch).as_text().encode())}
 
 
 def _fedtext(**kw):
@@ -61,19 +69,42 @@ def golden():
         return json.load(f)
 
 
-@pytest.mark.parametrize("name,preset", [("laguna_xs2", laguna_xs2), ("laguna_tiny", laguna_tiny)])
-def test_the_preset_builds_the_tree_it_built(golden, name, preset):
-    assert _tree(preset) == golden[name]
+PRESETS = {"laguna_xs2": laguna_xs2, "laguna_tiny": laguna_tiny, "keye_vl2": keye_vl2,
+           "keye_tiny": keye_tiny, "sdar_30b_a3b": sdar_30b_a3b, "sdar_tiny": sdar_tiny}
 
 
-@pytest.mark.parametrize("what", ["tiny_loss_bits", "tiny_grad_sha", "tiny_lowered_sha"])
-def test_the_tiny_round_is_the_one_it_was(golden, what):
-    assert _tiny_round()[what] == golden[what]
+@pytest.mark.parametrize("name", PRESETS)
+def test_the_preset_builds_the_tree_it_built(golden, name):
+    assert _tree(PRESETS[name]) == golden[name]
+
+
+def test_sdars_tree_is_keyes_without_the_index(golden):
+    """The same decoder block: what differs is the mask and the objective."""
+    def paths(preset):
+        shapes = jax.eval_shape(LagunaLM(preset()).init, jax.random.key(0),
+                                jnp.zeros((1, 128), jnp.int32))
+        return {n: a.shape for n, a in zip(weights.leaf_names(shapes), jax.tree.leaves(shapes))}
+
+    keye, sdar = paths(keye_vl2), paths(sdar_30b_a3b)
+    assert sdar == {n: s for n, s in keye.items() if "/index_" not in n}
+    assert len(keye) - len(sdar) == 4
+
+
+@pytest.fixture(scope="module")
+def tiny_rounds():
+    return {**_tiny_round(), **_tiny_round(keye_tiny, "keye_")}
+
+
+@pytest.mark.parametrize("what", [p + w for p in ("", "keye_")
+                                  for w in ("tiny_loss_bits", "tiny_grad_sha", "tiny_lowered_sha")])
+def test_the_tiny_round_is_the_one_it_was(golden, tiny_rounds, what):
+    assert tiny_rounds[what] == golden[what]
 
 
 def test_fedtext_at_the_default_median_makes_the_rows_it_made(golden):
     assert _fedtext() == golden["fedtext_sha"] == _fedtext(doc_median=300.0)
     assert _fedtext(doc_median=100.0) != golden["fedtext_sha"]
+    assert _fedtext(reserved=0) == golden["fedtext_sha"] != _fedtext(reserved=1)
 
 
 def test_the_entry_passes_the_median_and_defaults_to_300():
@@ -91,5 +122,6 @@ def test_the_entry_passes_the_median_and_defaults_to_300():
 
 
 if __name__ == "__main__":
-    print(json.dumps({"laguna_xs2": _tree(laguna_xs2), "laguna_tiny": _tree(laguna_tiny),
-                      **_tiny_round(), "fedtext_sha": _fedtext()}))
+    print(json.dumps({**{name: _tree(preset) for name, preset in PRESETS.items()},
+                      **_tiny_round(), **_tiny_round(keye_tiny, "keye_"),
+                      "fedtext_sha": _fedtext()}))

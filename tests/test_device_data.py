@@ -58,7 +58,7 @@ def test_device_augment_matches_numpy_bitexact():
         else:
             x = rng.normal(size=(40, 32, 32, 3)).astype(np.float32)
         p = aug.plan(rng, 40)
-        want = aug.apply(x.copy(), p)
+        want = aug.apply_pixels(x.copy(), p)
         got = np.asarray(
             device_augment(
                 jnp.asarray(x),
@@ -138,14 +138,14 @@ def test_uint8_cutout_fills_dataset_mean():
     aug = CifarAugment()
     x = np.full((1, 32, 32, 3), 200, np.uint8)
     p = aug.plan(np.random.default_rng(0), 1)
-    out = aug.apply(x, p)
+    out = aug.apply_pixels(x, p)
     cut_vals = out[out != 200]
     assert cut_vals.size > 0
     expect = np.round(255.0 * CIFAR10_MEAN).astype(np.uint8)
     assert set(np.unique(cut_vals)) <= set(expect.tolist())
     # float input keeps the 0.0 fill (already-normalized space)
     xf = np.full((1, 32, 32, 3), 5.0, np.float32)
-    outf = aug.apply(xf, p)
+    outf = aug.apply_pixels(xf, p)
     assert set(np.unique(outf)) <= {0.0, 5.0}
 
 
@@ -224,3 +224,122 @@ def test_cv_train_takes_device_data_path_e2e(tmp_path):
         cv_train.build_session_and_sampler = orig
     assert built["session"]._dev_data is not None, "device-data path not taken"
     assert np.isfinite(val["loss"])
+
+
+# ---- a plan on named keys that adds keys: block diffusion's noise (PR 35) -----------------
+
+def _text_ds(num_clients=6, seq_len=128):
+    from commefficient_tpu.data import load_fed_text
+
+    return load_fed_text(num_clients=num_clients, rows_per_client=4, seq_len=seq_len, vocab=300,
+                         seed=5, doc_median=40.0, reserved=1)[0]
+
+
+def _noise_loss():
+    """A loss that reads every key the augmenter adds."""
+
+    def loss_fn(params, batch, rng=None):
+        x = jnp.where(batch["noise_mask"], 298, batch["input_ids"])
+        logits = params["e"][x] @ params["w"]
+        logp = jax.nn.log_softmax(logits, -1)
+        nll = -jnp.take_along_axis(logp, batch["input_ids"][..., None], -1)[..., 0]
+        w = jnp.where(batch["noise_mask"], 1.0 / batch["noise_t"], 0.0)
+        loss = jnp.sum(nll * w) / jnp.maximum(jnp.sum(batch["lm_labels"] != -100), 1)
+        return loss, {"count": jnp.sum(batch["noise_mask"]).astype(jnp.float32)}
+
+    rng = np.random.default_rng(0)
+    return {"e": rng.normal(size=(300, 8)).astype(np.float32),
+            "w": rng.normal(size=(8, 300)).astype(np.float32)}, loss_fn
+
+
+def test_block_noise_host_and_device_paths_give_the_same_batch_bit_for_bit():
+    """The sampler's host batch (``sample_round``) against the device path's
+    own making of it: the rows gathered at ``idx`` and ``device_apply`` on
+    the shipped plan, key by key, dtype and bit."""
+    from commefficient_tpu.data.fedtext import BlockNoise
+
+    ds, aug = _text_ds(), BlockNoise(4)
+    sampler = FedSampler(ds, num_workers=3, local_batch_size=2, seed=9, augment=aug)
+    assert sampler.fusable
+    for r in (0, 1, 7):
+        ids, host = sampler.sample_round(r)
+        ids2, idx, plan = sampler.sample_round_indices(r)
+        assert np.array_equal(ids, ids2) and len(plan) == 2
+        assert list(host) == ["input_ids", "lm_labels", "noise_mask", "noise_t"]
+        flat = idx.reshape(-1)
+        rows = {k: jnp.asarray(v)[flat] for k, v in ds.data.items()}
+        rows.update(jax.jit(aug.device_apply)(rows, *map(jnp.asarray, plan)))
+        for k, want in host.items():
+            got = np.asarray(rows[k]).reshape(want.shape)
+            assert got.dtype == want.dtype, k
+            np.testing.assert_array_equal(got, want)
+        assert host["noise_mask"].any() and not host["noise_mask"][host["lm_labels"] == -100].any()
+
+
+def test_the_same_round_draws_the_same_noise_and_another_round_another():
+    from commefficient_tpu.data.fedtext import BlockNoise
+
+    ds, aug = _text_ds(), BlockNoise(4)
+    a = FedSampler(ds, num_workers=3, local_batch_size=2, seed=9, augment=aug)
+    b = FedSampler(ds, num_workers=3, local_batch_size=2, seed=9, augment=aug)
+    plain = FedSampler(ds, num_workers=3, local_batch_size=2, seed=9)
+    p0, p0_again, p1 = (s.sample_round_indices(r) for s, r in ((a, 0), (b, 0), (a, 1)))
+    assert all(np.array_equal(x, y) for x, y in zip(p0[2], p0_again[2]))
+    assert not np.array_equal(p0[2][1], p1[2][1])
+    # the plan's draws come after the clients' and the rows': a sampler with
+    # no plan draws the same clients and rows
+    ids, idx, none = plain.sample_round_indices(0)
+    assert np.array_equal(ids, p0[0]) and np.array_equal(idx, p0[1]) and none == ()
+    assert not np.array_equal(
+        FedSampler(ds, num_workers=3, local_batch_size=2, seed=10,
+                   augment=aug).sample_round_indices(0)[2][1], p0[2][1])
+
+
+def test_index_path_matches_batch_path_with_block_noise():
+    """Three rounds through the session by host batches and by indices + plan
+    end on the same parameters, bit for bit."""
+    from commefficient_tpu.data.fedtext import BlockNoise
+
+    cfg = Config(mode="uncompressed", num_clients=6, num_workers=3, num_devices=1,
+                 local_batch_size=2, weight_decay=0.0, seed=7)
+    ds, aug = _text_ds(), BlockNoise(4)
+    params, loss_fn = _noise_loss()
+    finals = []
+    for use_idx in (False, True):
+        session = FederatedSession(cfg, params, loss_fn)
+        sampler = FedSampler(ds, num_workers=3, local_batch_size=2, seed=7, augment=aug)
+        if use_idx:
+            assert session.maybe_attach_data(ds, sampler, aug)
+        for r in range(3):
+            if use_idx:
+                metrics = session.train_round_indices(*sampler.sample_round_indices(r), 0.1)
+            else:
+                metrics = session.train_round(*sampler.sample_round(r), 0.1)
+            assert float(metrics["count"]) > 0
+        finals.append(np.asarray(session.state.params_vec))
+    np.testing.assert_array_equal(finals[0], finals[1])
+    assert not np.array_equal(finals[0], np.concatenate(
+        [np.ravel(v) for v in jax.tree.leaves(params)]))
+
+
+def test_cifar_augment_is_an_instance_of_the_keyed_plan():
+    """``CifarAugment`` through the keyed calls the sampler and the session
+    make is its pixel path on ``"x"``, and the per-batch call draws the plan
+    it drew."""
+    from commefficient_tpu.data.augment import BatchAugment
+
+    aug = CifarAugment()
+    assert isinstance(aug, BatchAugment) and aug.reads == ("x",)
+    ds = _toy_ds(64)
+    assert aug.accepts(ds.data) and aug.plan_args(ds.data) == (32, 32)
+    assert not aug.accepts({"x": ds.data["x"].astype(np.float64)})
+    rows = {k: v[:16] for k, v in ds.data.items()}
+    p = aug.plan(np.random.default_rng(4), 16, 32, 32)
+    want = aug.apply_pixels(rows["x"].copy(), p)
+    assert list(aug.apply(rows, *p)) == ["x"]
+    np.testing.assert_array_equal(aug.apply(rows, *p)["x"], want)
+    np.testing.assert_array_equal(np.asarray(aug.device_apply(
+        {k: jnp.asarray(v) for k, v in rows.items()}, *map(jnp.asarray, p))["x"]), want)
+    called = aug(rows, np.random.default_rng(4))
+    np.testing.assert_array_equal(called["x"], want)
+    np.testing.assert_array_equal(called["y"], rows["y"])

@@ -84,3 +84,86 @@ def test_the_seed_alone_decides_the_data():
     # a client's rows do not depend on how many clients there are
     d, _ = load_fed_text(**{**KW, "num_clients": 3})
     assert np.array_equal(d.data["input_ids"], a.data["input_ids"][:12])
+
+
+# ---- rows and draws as they were, and block diffusion's part of the feed (PR 35) ----------
+
+def test_rows_and_draws_without_reserved_ids_are_what_they_were(sets):
+    """``reserved`` 0 (Laguna's and Keye's rows) leaves every row bit for bit,
+    and a sampler without an augmenter draws what it drew (checksums taken on
+    the commit before the parameter and the plan's new shape)."""
+    import zlib
+
+    from commefficient_tpu.data import FedSampler
+
+    train, test = sets
+    assert (zlib.crc32(train.data["input_ids"].tobytes()),
+            zlib.crc32(train.data["lm_labels"].tobytes()),
+            zlib.crc32(test.data["input_ids"].tobytes())) == (3125163985, 494471509, 2285509507)
+    sampler = FedSampler(train, num_workers=2, local_batch_size=2, seed=11)
+    want = {0: ([0, 5], [[1, 2], [20, 22]], 2928073658), 5: ([0, 4], [[3, 0], [16, 18]], 992454782)}
+    for r, (clients, rows, crc) in want.items():
+        ids, idx, plan = sampler.sample_round_indices(r)
+        assert (ids.tolist(), idx.tolist(), plan) == (clients, rows, ())
+        _, batch = sampler.sample_round(r)
+        assert list(batch) == ["input_ids", "lm_labels"]
+        assert zlib.crc32(batch["input_ids"].tobytes()) == crc
+
+
+def test_the_mask_token_occurs_in_no_document():
+    """With one id reserved under ``<eos>`` no document draws it, own band or
+    whole slice; nothing else about the rows changes kind."""
+    train, test = load_fed_text(**{**KW, "vocab": 300, "reserved": 1})
+    for ds in (train, test):
+        ids = ds.data["input_ids"]
+        assert not (ids == 298).any() and (ids == 299).any() and ids.max() == 299
+    assert (load_fed_text(**{**KW, "vocab": 300})[0].data["input_ids"] == 298).any()
+
+
+@pytest.fixture(scope="module")
+def noise():
+    from commefficient_tpu.data.fedtext import BlockNoise
+
+    return BlockNoise(4)
+
+
+def test_block_noise_names_its_keys_and_its_plan_is_small(sets, noise):
+    train, _ = sets
+    assert noise.reads == ("lm_labels",)
+    assert noise.accepts(train.data) and noise.plan_args(train.data) == (256,)
+    t, u = noise.plan(np.random.default_rng(0), 5, 256)
+    assert (t.shape, t.dtype, u.shape, u.dtype) == ((5, 64), np.float32, (5, 256), np.float32)
+    assert 1e-3 <= t.min() and t.max() <= 1.0 and 0.0 <= u.min() and u.max() < 1.0
+    from commefficient_tpu.data.fedtext import BlockNoise
+
+    assert not BlockNoise(7).accepts(train.data)            # 256 is not whole blocks of 7
+
+
+def test_a_blocks_masked_share_follows_its_t(noise):
+    """Each labelled token of a block is masked with probability ``t`` of
+    that block, and carries that ``t``."""
+    labels = np.zeros((400, 256), np.int32)
+    t, u = noise.plan(np.random.default_rng(1), 400, 256)
+    made = noise.apply({"lm_labels": labels}, t, u)
+    assert made["noise_mask"].dtype == np.bool_ and made["noise_t"].dtype == np.float32
+    assert np.array_equal(made["noise_t"], np.repeat(t, 4, axis=1))
+    per_block = made["noise_mask"].reshape(400, 64, 4).mean(-1)
+    for lo, hi in ((0.0, 0.2), (0.4, 0.6), (0.8, 1.0)):
+        band = (t >= lo) & (t < hi)
+        assert abs(per_block[band].mean() - t[band].mean()) < 0.02
+    assert abs(made["noise_mask"].mean() - 0.5) < 0.01      # the mean of U[1e-3, 1]
+
+
+def test_pad_is_never_masked(sets, noise):
+    train, _ = sets
+    rows = {k: v[3::4] for k, v in train.data.items()}       # every client's last row: its tail
+    made = noise.apply(rows, *noise.plan(np.random.default_rng(2), 6, 256))
+    pad = rows["lm_labels"] == -100
+    assert pad.any() and not made["noise_mask"][pad].any() and made["noise_mask"][~pad].any()
+
+
+def test_the_fixed_plan_is_keyed_by_seed_and_row(noise):
+    a, b = noise.fixed(3, 256, 7), noise.fixed(5, 256, 7)
+    assert all(np.array_equal(x, y[:3]) for x, y in zip(a, b))
+    assert not np.array_equal(a[1][0], a[1][1])
+    assert not np.array_equal(a[1], noise.fixed(3, 256, 8)[1])

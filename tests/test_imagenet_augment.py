@@ -2,7 +2,7 @@
 
 The plan-based ImageNetAugment (data/imagenet.py) mirrors CifarAugment's
 contract: ``plan`` draws the randomness once, and the numpy ``apply``, the
-native C++ ``gather_apply`` kernel, and the traced ``device_apply`` realize
+native C++ ``gather_pixels`` kernel, and the traced ``device_pixels`` realize
 the same batch. Bilinear interpolation is float arithmetic, so the native
 and XLA paths may differ from numpy by FMA contraction — pinned here to
 <= 1 uint8 LSB on a small fraction of pixels (the CIFAR paths stay
@@ -61,7 +61,7 @@ def test_identity_crop_is_identity():
         hs=np.full(n, 48, np.int32), ws=np.full(n, 48, np.int32),
         flips=np.zeros(n, bool),
     )
-    np.testing.assert_array_equal(aug.apply(x, p), x)
+    np.testing.assert_array_equal(aug.apply_pixels(x, p), x)
 
 
 def test_flip_semantics():
@@ -75,7 +75,7 @@ def test_flip_semantics():
     )
     flipped = base._replace(flips=np.ones(n, bool))
     np.testing.assert_array_equal(
-        aug.apply(x, flipped), aug.apply(x, base)[:, :, ::-1]
+        aug.apply_pixels(x, flipped), aug.apply_pixels(x, base)[:, :, ::-1]
     )
 
 
@@ -84,8 +84,8 @@ def test_device_apply_matches_numpy(uint8):
     aug = ImageNetAugment()
     x = _toy(n=32, uint8=uint8)
     p = aug.plan(np.random.default_rng(5), 32, 48, 48)
-    want = aug.apply(x, p)
-    got = np.asarray(aug.device_apply(jnp.asarray(x), *map(jnp.asarray, p)))
+    want = aug.apply_pixels(x, p)
+    got = np.asarray(aug.device_pixels(jnp.asarray(x), *map(jnp.asarray, p)))
     if uint8:
         diff = np.abs(got.astype(np.int32) - want.astype(np.int32))
         assert diff.max() <= 1, f"max LSB diff {diff.max()}"
@@ -103,7 +103,7 @@ def test_native_gather_rrc_matches_numpy(uint8):
     idx = rng.integers(0, 64, size=48).astype(np.int64)
     p = aug.plan(rng, 48, 48, 48)
     got = native.gather_rrc(data, idx, p)
-    want = aug.apply(np.ascontiguousarray(data[idx]), p)
+    want = aug.apply_pixels(np.ascontiguousarray(data[idx]), p)
     assert got.dtype == data.dtype
     if uint8:
         diff = np.abs(got.astype(np.int32) - want.astype(np.int32))
@@ -144,7 +144,7 @@ def test_fused_sampler_round_with_rrc():
         [ds.client_batch_indices(int(c), 8, rng2) for c in clients]
     )
     p = aug.plan(rng2, 32, 48, 48)
-    want = aug.apply(np.ascontiguousarray(ds.data["x"][flat]), p)
+    want = aug.apply_pixels(np.ascontiguousarray(ds.data["x"][flat]), p)
     got = batch["x"].reshape(32, 48, 48, 3)
     diff = np.abs(got.astype(np.int32) - want.astype(np.int32))
     assert diff.max() <= 1  # native path may differ by FMA rounding

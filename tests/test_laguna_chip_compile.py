@@ -72,6 +72,26 @@ def test_attention_compiles_at_the_published_widths(mesh, compiled_kernels, head
     assert "2048,2048" not in text                       # no [T, T] operand, per head or whole
 
 
+def test_block_diffusion_attention_compiles_at_the_published_widths(mesh, compiled_kernels):
+    """SDAR's cell: a noised and a clean copy of 8,192 tokens, 32 heads over
+    4, blocks of 4; the mask's comparisons lower inside all three kernels and
+    no ``[2T, 2T]`` or ``[T, T]`` operand exists."""
+    T, d, heads, kv, clients, rows = 8192, 128, 32, 4, 2, 1
+
+    def body(q, k, v):
+        def loss(q, k, v):
+            o = jax.vmap(lambda *a: library_kernels.banded_attention(*a, block_length=4))(q, k, v)
+            return jnp.sum(o.astype(jnp.float32))
+
+        return _total(jax.grad(loss, (0, 1, 2))(q, k, v))
+
+    q = jax.ShapeDtypeStruct((clients, rows, 2 * T, heads, d), jnp.bfloat16)
+    k = jax.ShapeDtypeStruct((clients, rows, 2 * T, kv, d), jnp.bfloat16)
+    text = _compile(mesh, body, q, k, k, specs=(P("workers"),) * 3)
+    assert text.count("tpu_custom_call") >= 3            # forward, dq, dkv
+    assert "16384,16384" not in text and "8192,8192" not in text
+
+
 @pytest.mark.parametrize("rows,width,tiling", [
     (4096, 512, library_kernels.GMM_TILING),        # Laguna-XS.2's fast branch
     (36864, 768, (512, 1024, 1024)),                # Keye-VL-2.0's first tier and tile

@@ -41,7 +41,7 @@ def test_gather_augment_matches_numpy_bitexact():
     idx = rng.integers(0, data.shape[0], size=96).astype(np.int64)
     p = aug.plan(rng, 96)
     got = native.gather_augment(data, idx, p, fill=aug._fill(data.dtype, 3))
-    want = aug.apply(np.ascontiguousarray(data[idx]), p)
+    want = aug.apply_pixels(np.ascontiguousarray(data[idx]), p)
     assert got.dtype == np.float32
     np.testing.assert_array_equal(got, want)
 
@@ -56,7 +56,7 @@ def test_gather_augment_uint8_matches_numpy():
     idx = rng.integers(0, 100, size=64).astype(np.int64)
     p = aug.plan(rng, 64)
     got = native.gather_augment(data, idx, p, fill=aug._fill(data.dtype, 3))
-    want = aug.apply(np.ascontiguousarray(data[idx]), p)
+    want = aug.apply_pixels(np.ascontiguousarray(data[idx]), p)
     assert got.dtype == np.uint8
     np.testing.assert_array_equal(got, want)
 
@@ -84,7 +84,7 @@ def test_vectorized_augment_matches_legacy_loop():
     aug = CifarAugment()
     x = _toy_images(n=40)
     p = aug.plan(np.random.default_rng(3), 40)
-    got = aug.apply(x, p)
+    got = aug.apply_pixels(x, p)
     n, h, w, _ = x.shape
     padded = np.pad(x, ((0, 0), (4, 4), (4, 4), (0, 0)), mode="reflect")
     for i in range(n):

@@ -185,16 +185,25 @@ def keye_op_names():
     return _lm_op_names("keye_tiny")
 
 
-# the scopes only an indexed-attention model opens, and the ones it never does
+@pytest.fixture(scope="module")
+def sdar_op_names():
+    return _lm_op_names("sdar_tiny")
+
+
+# the scopes only an indexed-attention model opens, and the ones it never does;
+# the same for a block-diffusion model
 INDEXED_ONLY = ("attn_index", "attn_select", "attn_sparse")
-NEVER_INDEXED = ("attn_full", "attn_window", "mlp_dense")
+DIFFUSION_ONLY = ("attn_blockdiff", "diffusion_streams", "diffusion_loss")
+NEVER_INDEXED = ("attn_full", "attn_window", "mlp_dense") + DIFFUSION_ONLY
+NEVER_DIFFUSION = ("attn_full", "attn_window", "mlp_dense") + INDEXED_ONLY
 
 
 def _under(names, scope):
     return [n for n in names if re.search(r"\b" + scope + r"\b", n)]
 
 
-@pytest.mark.parametrize("scope", [n for n in MODEL_NAMES if n not in INDEXED_ONLY])
+@pytest.mark.parametrize("scope", [n for n in MODEL_NAMES
+                                   if n not in INDEXED_ONLY + DIFFUSION_ONLY])
 def test_model_scopes_sit_under_client_grad_forward_and_backward(laguna_op_names, scope):
     """Each is in the round the LM entry compiles, always inside
     ``client_grad``, once wrapped by ``jvp(`` alone and once by
@@ -230,9 +239,38 @@ def test_model_scopes_of_an_indexed_model(keye_op_names, scope):
         assert all("attn_index/" in n for n in under if n.startswith("jit("))
 
 
-@pytest.mark.parametrize("scope", INDEXED_ONLY)
-def test_laguna_opens_no_scope_of_the_index(laguna_op_names, scope):
+@pytest.mark.parametrize("scope", [n for n in MODEL_NAMES if n not in NEVER_DIFFUSION])
+def test_model_scopes_of_a_block_diffusion_model(sdar_op_names, scope):
+    """The same at ``sdar_tiny``: its own three scopes beside the expert
+    layer's, the projections' and the head's (``lm_head`` nests under
+    ``diffusion_loss``), forward and backward. ``diffusion_streams`` holds
+    integer work in the forward pass (the noised ids, the join) and the
+    split's slice, whose transpose is a pad in the backward pass."""
+    under = _under(sdar_op_names, scope)
+    assert under
+    assert not [n for n in under if "client_grad" not in n and n.startswith("jit(")
+                and not re.match(r"jit\(wrapped\)/(diffusion_loss|lm_head)/", n)]
+    assert any("transpose(" not in n for n in under)
+    assert any("transpose(" in n for n in under)
+    if scope == "lm_head":
+        assert all("diffusion_loss/" in n for n in under if n.startswith("jit("))
+
+
+@pytest.mark.parametrize("scope", INDEXED_ONLY + DIFFUSION_ONLY)
+def test_laguna_opens_no_scope_of_the_index_or_of_block_diffusion(laguna_op_names, scope):
     assert not _under(laguna_op_names, scope)
+
+
+@pytest.mark.parametrize("scope", NEVER_DIFFUSION)
+def test_a_block_diffusion_model_opens_no_other_attention_kind(sdar_op_names, scope):
+    assert not _under(sdar_op_names, scope)
+
+
+def test_the_noise_is_applied_under_data_gather(sdar_op_names):
+    """The plan's comparison (``u < t``, the pad's) runs in the compiled
+    round, beside the gather."""
+    under = _under(sdar_op_names, "data_gather")
+    assert any(n.endswith("/lt") for n in under) and any("gather" in n for n in under)
 
 
 @pytest.mark.parametrize("scope", NEVER_INDEXED)
@@ -240,13 +278,14 @@ def test_an_indexed_model_opens_none_of_lagunas_attention_kinds(keye_op_names, s
     assert not _under(keye_op_names, scope)
 
 
-@pytest.mark.parametrize("names", ["laguna_op_names", "keye_op_names"])
+@pytest.mark.parametrize("names", ["laguna_op_names", "keye_op_names", "sdar_op_names"])
 def test_the_round_scopes_still_close_on_the_lm_round(names, request):
     rx = re.compile("|".join(NAMES))
     bare = {n for n in request.getfixturevalue(names)
             if n.startswith("jit(") and not rx.search(n)}
     # the chunked head's loop: a cast and two index broadcasts the compiler
     # lifts out of the body keep the model's scope and lose the round's
-    lifted = {n for n in bare if n.startswith("jit(wrapped)/lm_head/")}
+    # (a block-diffusion model's head is that loop under ``diffusion_loss``)
+    lifted = {n for n in bare if re.match(r"jit\(wrapped\)/(lm_head|diffusion_loss)/", n)}
     assert bare - lifted <= {"jit(wrapped)/add"}, bare
-    assert len(lifted) <= 4 and (not lifted or names == "keye_op_names"), lifted
+    assert len(lifted) <= 4 and (not lifted or names != "laguna_op_names"), lifted
