@@ -33,14 +33,17 @@ backward pass is written out (``custom_vjp``) so that it branches the same
 way and keeps no residual of the branch not taken.
 
 Attention never forms ``[T, T]`` scores (``library_kernels.banded_attention``).
-Each block is rematerialized in the backward pass (``remat``): what is saved
-per block is its input, the float32 residual stream ``[B, T, hidden]``. A
-decoder with ``indexed_attention`` layers saves two more things a block, by
-name (``ops/pallas/indexed_attention.py``): the selection's thresholds
-(``SELECT_RESIDUAL``, two ``[T]`` rows a sequence) and the attention kernel's
-output and log-sum-exp (``ATTEND_RESIDUAL``, ``[B, T, heads x head_dim]`` in
-the compute dtype and ``[B, heads, T]`` float32), so its backward pass runs
-neither the selection nor the forward attention kernel a second time.
+Each block is rematerialized in the backward pass (``remat``) under one
+policy, whatever its layers: saved per block are its input, the float32
+residual stream ``[B, T, hidden]``, and what its kernels name. Every
+attention kernel, the library's and this repo's, names its output and
+log-sum-exp (``ATTEND_RESIDUAL``, ``[B, T, heads x head_dim]`` in the compute
+dtype and ``[B, heads, T]`` float32): no backward pass runs a forward
+attention kernel a second time. An ``indexed_attention`` layer names its
+selection's thresholds too (``SELECT_RESIDUAL``, two ``[T]`` rows a sequence).
+A preset whose memory cannot hold the pair from a layer's forward pass to its
+backward says so (``attn_residuals_kept`` off: the library's kernels are built
+without the name and the policy finds nothing of theirs to keep).
 
 Precision under ``compute_dtype`` ``mixed`` (``dtype`` bfloat16): bfloat16
 operands into every product with float32 accumulation; the residual stream,
@@ -81,7 +84,7 @@ each position's negative log-likelihood of its *own* clean token under its
 weight (scope ``diffusion_loss``): ``1 / t`` of its block where the noise
 masked it, 0 elsewhere, so a round does the same work whatever was drawn.
 The clean stream's last-layer output feeds nothing here. Such a layer saves
-what a full-attention layer saves: the block's input.
+what a full-attention layer saves, over its ``2 T`` positions.
 """
 
 from __future__ import annotations
@@ -202,6 +205,9 @@ class LagunaConfig:
     index_head_dim: int = 0     # their width (one shared key head of that width),
     index_topk: int = 0         # and the keys a query attends to
     head_chunk: int = 0         # positions whose logits live at once (0: a row's, whole)
+    # the library attention kernels' output and log-sum-exp cross a block's
+    # ``remat`` by name (off: the forward kernel runs twice a layer; memory)
+    attn_residuals_kept: bool = True
     expert_tiling: Tuple[int, int, int] = GMM_TILING   # the grouped product's tile
     # the expert layer's branches, each this many times the held rows a
     # uniform router sends (the last resort, every row, needs no entry)
@@ -243,8 +249,13 @@ def laguna_xs2(**kw) -> LagunaConfig:
     """One chip of 32 that share each layer: layers 0-4 of 40 (the dense
     layer, then one whole period: sliding x 3, full), experts 0-7 of 256,
     rows 0-12,543 of the 100,352-row vocabulary; every width as published.
-    D = 389.6M."""
-    return from_published(PUBLISHED, layers=5, experts_held=range(8), vocab_held=12544, **kw)
+    D = 389.6M. The attention kernels' residuals are not kept across
+    ``remat``: four clients' rows of 16,384 positions x 288 heads x 128 x 2 B
+    are 1.21 GB, and with them the benchmark's cell held 16,502,302,208 B of
+    the chip's 16.9 GB where it holds 15,413,011,968 without (TPU v5e,
+    PR 36), for 0.026 s of a 0.738 s round."""
+    return from_published(PUBLISHED, layers=5, experts_held=range(8), vocab_held=12544,
+                          attn_residuals_kept=False, **kw)
 
 
 def laguna_tiny(**kw) -> LagunaConfig:
@@ -367,12 +378,14 @@ class Attention(nn.Module):
                                             topk=c.index_topk)
         elif diffusion:
             with jax.named_scope("attn_blockdiff"):
-                o = banded_attention(q, k, v, block_length=c.block_length)
+                o = banded_attention(q, k, v, block_length=c.block_length,
+                                     residuals_named=c.attn_residuals_kept)
             # the pairs under the mask, a head: T (T + L) a row of T tokens
             counters = {"blockdiff_pairs": jnp.float32(B * (T // 2) * (T // 2 + c.block_length))}
         else:
             with jax.named_scope("attn_window") if sliding else jax.named_scope("attn_full"):
-                o = banded_attention(q, k, v, window=c.sliding_window if sliding else None)
+                o = banded_attention(q, k, v, window=c.sliding_window if sliding else None,
+                                     residuals_named=c.attn_residuals_kept)
         with jax.named_scope("attn_proj"):
             if c.output_gate:
                 gate = jax.nn.sigmoid(_dot(h, w("g_proj", (E, H)), c.dtype))  # [B, T, H]
@@ -588,13 +601,13 @@ class LagunaLM(nn.Module):
                 ids = jnp.concatenate([noised, input_ids], 1)         # [B, 2 T]
         x = nn.Embed(c.vocab_held, c.hidden_size, name="embed", param_dtype=jnp.float32,
                      embedding_init=nn.initializers.normal(c.initializer_range))(ids)
-        indexed = "indexed_attention" in c.layer_types
-        # kept for the backward pass beside a block's input: the selection's
-        # thresholds (128 KB a sequence a layer: the recomputed forward attends
-        # to the same set) and the attention kernel's own residuals (its output
+        # kept for the backward pass beside a block's input, where a block's
+        # kernels name them: the attention kernel's own residuals (its output
         # and log-sum-exp: the recomputed forward does not run the kernel again)
+        # and an indexed layer's thresholds (128 KB a sequence a layer: the
+        # recomputed forward attends to the same set)
         block = nn.remat(Block, policy=jax.checkpoint_policies.save_only_these_names(
-            SELECT_RESIDUAL, ATTEND_RESIDUAL)) if indexed else nn.remat(Block)
+            SELECT_RESIDUAL, ATTEND_RESIDUAL))
         per_layer, attended = [], []
         for i in range(c.num_layers):
             x, counters, pairs = block(c, i, name=f"layer_{i}")(x)

@@ -35,12 +35,12 @@ backward pass and the index's operands take no cotangent.
 Under ``remat`` a block's policy is expected to save two names
 (``save_only_these_names(SELECT_RESIDUAL, ATTEND_RESIDUAL)``,
 ``models/laguna.py::LagunaLM``). ``SELECT_RESIDUAL`` is the thresholds above.
-``ATTEND_RESIDUAL`` is what the forward kernel leaves its own backward pass:
-the output as the kernel wrote it (``o_t`` ``[B, KV, G, d, T]``, the compute
-dtype) and the log-sum-exp (``[B, H, T]`` float32). With both kept the
-backward pass recomputes ``q``, ``k``, ``v`` (its kernels read them) and
-rebuilds ``o`` from the saved ``o_t``, and does not run ``indexed_fwd``
-again: the kernel runs once a layer, not twice. At the Keye cell's shape
+``ATTEND_RESIDUAL`` (``library_kernels``' kernels name theirs by it too) is
+what the forward kernel leaves its own backward pass: the output as it wrote
+it (``o_t`` ``[B, KV, G, d, T]``, the compute dtype) and the log-sum-exp
+(``[B, H, T]`` float32). With both kept the backward pass recomputes ``q``,
+``k``, ``v`` (its kernels read them), rebuilds ``o`` from the saved ``o_t``
+and does not run ``indexed_fwd`` again: once a layer. At the Keye cell's shape
 (two clients' rows of 16,384, 32 heads of 128, bfloat16) that is 128 KB +
 272 MB a layer (``o_t`` 268 MB, ``lse`` 4.2 MB) held from the forward pass to
 the layer's backward, against a kernel of 0.038 s a layer. A policy that

@@ -39,6 +39,7 @@ from jax.experimental.pallas.ops.tpu.splash_attention import (
 )
 
 from commefficient_tpu.ops.pallas.countsketch_kernels import kernels_interpreted
+from commefficient_tpu.ops.pallas.indexed_attention import ATTEND_RESIDUAL
 
 GMM_TILING = (128, 512, 512)   # rows, contraction, columns (scripts/laguna_probe.py)
 ATTN_BLOCK = 512               # q and kv block of every splash kernel, forward and backward
@@ -143,10 +144,13 @@ class BlockDiffusionMask(_mask._ComputableMask):
 
 
 @functools.lru_cache(maxsize=None)
-def _attention_kernel(T, group, window, blk, interpret, block_length=0):
+def _attention_kernel(T, group, window, blk, interpret, block_length=0, residuals_named=True):
     """One KV head's multi-query kernel over its ``group`` of query heads.
     Built once per shape, its block-mask tables as concrete arrays: the
-    caller's ``custom_vjp`` closes over them, which a tracer may not be."""
+    caller's ``custom_vjp`` closes over them, which a tracer may not be.
+    Whatever the mask, with ``residuals_named`` the forward kernel's output
+    and log-sum-exp carry the name ``ATTEND_RESIDUAL`` (the library's
+    ``residual_checkpoint_name``)."""
     if block_length:
         head = BlockDiffusionMask((T, T), block_length)
     elif window is None:
@@ -160,10 +164,11 @@ def _attention_kernel(T, group, window, blk, interpret, block_length=0):
                 block_q=blk, block_kv=blk, block_kv_compute=blk,
                 block_q_dkv=blk, block_kv_dkv=blk, block_kv_dkv_compute=blk,
                 block_q_dq=blk, block_kv_dq=blk),
+            residual_checkpoint_name=ATTEND_RESIDUAL if residuals_named else None,
             interpret=interpret)
 
 
-def banded_attention(q, k, v, *, window=None, block_length=0):
+def banded_attention(q, k, v, *, window=None, block_length=0, residuals_named=True):
     """Causal grouped-query attention that never forms ``[T, T]`` scores.
 
     ``q`` ``[B, T, H, d]`` (already scaled), ``k``, ``v`` ``[B, T, KV, d]``
@@ -175,14 +180,26 @@ def banded_attention(q, k, v, *, window=None, block_length=0):
     With ``block_length`` the ``T`` positions are a noised and a clean copy
     of a row of ``T / 2`` and the mask is ``BlockDiffusionMask``: not causal,
     and as little an array. Running softmax statistics are float32; ``T`` is
-    a multiple of 128."""
+    a multiple of 128.
+
+    What the forward kernel leaves its own backward kernels, the output
+    ``[B, KV, group, T, d]`` in ``q``'s dtype and the log-sum-exp
+    ``[B, KV, group, T]`` float32, is named ``ATTEND_RESIDUAL``
+    (``ops/pallas/indexed_attention.py`` defines the name, one for every
+    attention kernel's own residuals): under a ``remat`` whose policy saves
+    that name the backward pass recomputes ``q``, ``k``, ``v`` and does not
+    run ``splash_mqa_fwd`` a second time; under any other policy, and with no
+    ``remat``, the name changes nothing. ``residuals_named`` off builds the
+    kernel without the name, for a caller whose memory cannot hold the pair
+    from a layer's forward pass to its backward: the same floats, the
+    forward kernel twice."""
     B, T, H, d = q.shape
     KV = k.shape[2]
     group = H // KV
     if T % 128:
         raise ValueError(f"banded_attention: T={T} is not a multiple of 128 (the kernel's lanes)")
     kernel = _attention_kernel(T, group, window, ATTN_BLOCK if T % ATTN_BLOCK == 0 else 128,
-                               kernels_interpreted(), block_length)
+                               kernels_interpreted(), block_length, residuals_named)
     q = q.reshape(B, T, KV, group, d).transpose(0, 2, 3, 1, 4)      # [B, KV, group, T, d]
     k, v = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)         # [B, KV, T, d]
     o = _typed_call(jax.vmap(jax.vmap(kernel)))(q, k, v)

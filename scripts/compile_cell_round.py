@@ -2,9 +2,10 @@
 v5e chip (no chip attached, nothing runs) and print XLA's memory analysis:
 what the TPU compiler and Mosaic refuse, and whether the round's temporaries
 fit beside its state, before a chip minute is spent. Beside it, how many
-times the compiled round calls each of the indexed-attention kernels
-(`indexed_fwd` once a layer where the block's `remat` keeps its residuals,
-twice where it recomputes them; nothing for a cell without such layers).
+times the compiled round calls each attention kernel, this repo's
+(`indexed_*`) and the library's (`splash_mqa_*`): a forward kernel
+(`indexed_fwd`, `splash_mqa_fwd*`) once a layer where the block's `remat`
+keeps its residuals, twice where it recomputes them.
 
     JAX_PLATFORMS=cpu python scripts/compile_cell_round.py laguna_uncompressed [--num_workers 2 ...]
 
@@ -70,11 +71,12 @@ def main():
     ).lower(lowering_platforms=("tpu",)).compile()
     m = compiled.memory_analysis()
     text = compiled.as_text()
-    calls = re.findall(r'^\s*%?(indexed_\w+?)[.\d]* = .*custom_call_target="tpu_custom_call"',
-                       text, re.M)
+    calls = re.findall(
+        r'^\s*%?((?:indexed|splash_mqa)_\w+?)[.\d]* = .*custom_call_target="tpu_custom_call"',
+        text, re.M)
     print({"cell": cell["name"], "D": int(flat.size), "compile_s": round(time.time() - t0, 1),
            "kernels": text.count("tpu_custom_call"),
-           "indexed_calls": {name: calls.count(name) for name in sorted(set(calls))},
+           "attention_calls": {name: calls.count(name) for name in sorted(set(calls))},
            # a buffer with two sequence-length extents, whatever else it holds
            "square_buffers": sorted(set(re.findall(
                rf"\[[\d,]*\b(?:{T},{T}|{2 * T},{2 * T})\b[\d,]*\]", text))),

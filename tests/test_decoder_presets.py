@@ -11,7 +11,16 @@ after a JAX upgrade moves the lowered text, and only then).
 Since PR 35 (a fourth attention kind, ``block_length`` and ``mask_token`` as
 fields, FedText's ``reserved``) the same is held for Keye's presets, whose
 numbers were written from the commit before too, and the file also keeps
-the trees of SDAR's presets as that PR built them."""
+the trees of SDAR's presets as that PR built them.
+
+PR 36 re-wrote one number, Laguna's ``tiny_lowered_sha``: the library's
+attention kernels now name their output and log-sum-exp and every decoder's
+``remat`` policy keeps them, so ``laguna_tiny``'s gradient calls the forward
+kernel once a layer and lowers to another text (its loss and its gradients
+are the bits they were). ``laguna_xs2`` opts out for its memory
+(``attn_residuals_kept`` off); ``laguna_tiny`` has no such reason and keeps
+the decoder's default, and with the preset's setting it still lowers to the
+old text, which the file keeps as ``tiny_recomputed_lowered_sha``."""
 
 import hashlib
 import json
@@ -78,6 +87,14 @@ def test_the_preset_builds_the_tree_it_built(golden, name):
     assert _tree(PRESETS[name]) == golden[name]
 
 
+def test_only_the_preset_short_of_memory_gives_up_its_attention_residuals():
+    """``laguna_xs2``'s cell holds 15.4 GB of the chip's 16.9 without them
+    and 16.5 with (``models/laguna.py::laguna_xs2``); every other preset
+    keeps the decoder's default."""
+    assert {name for name, preset in PRESETS.items()
+            if not preset().attn_residuals_kept} == {"laguna_xs2"}
+
+
 def test_sdars_tree_is_keyes_without_the_index(golden):
     """The same decoder block: what differs is the mask and the objective."""
     def paths(preset):
@@ -90,13 +107,20 @@ def test_sdars_tree_is_keyes_without_the_index(golden):
     assert len(keye) - len(sdar) == 4
 
 
+def _laguna_tiny_as_xs2(**kw):
+    """``laguna_tiny`` with ``laguna_xs2``'s one setting that is not a size."""
+    return laguna_tiny(attn_residuals_kept=laguna_xs2().attn_residuals_kept, **kw)
+
+
 @pytest.fixture(scope="module")
 def tiny_rounds():
-    return {**_tiny_round(), **_tiny_round(keye_tiny, "keye_")}
+    recomputed = _tiny_round(_laguna_tiny_as_xs2)
+    return {**_tiny_round(), **_tiny_round(keye_tiny, "keye_"),
+            "tiny_recomputed_lowered_sha": recomputed["tiny_lowered_sha"]}
 
 
-@pytest.mark.parametrize("what", [p + w for p in ("", "keye_")
-                                  for w in ("tiny_loss_bits", "tiny_grad_sha", "tiny_lowered_sha")])
+@pytest.mark.parametrize("what", [p + w for p in ("", "keye_") for w in (
+    "tiny_loss_bits", "tiny_grad_sha", "tiny_lowered_sha")] + ["tiny_recomputed_lowered_sha"])
 def test_the_tiny_round_is_the_one_it_was(golden, tiny_rounds, what):
     assert tiny_rounds[what] == golden[what]
 
@@ -124,4 +148,6 @@ def test_the_entry_passes_the_median_and_defaults_to_300():
 if __name__ == "__main__":
     print(json.dumps({**{name: _tree(preset) for name, preset in PRESETS.items()},
                       **_tiny_round(), **_tiny_round(keye_tiny, "keye_"),
+                      "tiny_recomputed_lowered_sha":
+                          _tiny_round(_laguna_tiny_as_xs2)["tiny_lowered_sha"],
                       "fedtext_sha": _fedtext()}))

@@ -2,7 +2,8 @@
 attention kernels compiled for a described v5e chip
 (no chip attached, nothing runs): at the published widths, inside a
 ``shard_map`` that checks varying axes, under ``vmap`` over clients, forward
-and backward. What interpret mode cannot show: Mosaic's own refusals
+and backward, and how often a forward attention kernel is called under the
+block's ``remat`` policy. What interpret mode cannot show: Mosaic's own refusals
 (tiling, fast memory) and the ``vma`` typing of the library's
 ``out_shape``s. The topology is described inside a fixture, never at import
 (only one process may hold the TPU library; see the on-chip-measurement
@@ -54,41 +55,73 @@ def _compile(mesh, body, *structs, specs):
     return compiled.as_text()
 
 
-@pytest.mark.parametrize("heads,window", [(64, 512), (48, None)])
-def test_attention_compiles_at_the_published_widths(mesh, compiled_kernels, heads, window):
-    T, d, kv, clients, rows = 2048, 128, 8, 2, 2
+def _kernel_calls(text):
+    """Custom calls of the compiled text by the attention kernel's name,
+    this repo's (``indexed_*``) and the library's (``splash_mqa_*``)."""
+    calls = re.findall(
+        r'^\s*%?((?:indexed|splash_mqa)_\w+?)[.\d]* = .*custom_call_target="tpu_custom_call"',
+        text, re.M)
+    return {name: calls.count(name) for name in set(calls)}
 
+
+BLOCK_POLICY = (indexed_attention.SELECT_RESIDUAL, indexed_attention.ATTEND_RESIDUAL)
+# how a block differentiates its attention: as it stands, under the policy
+# ``LagunaLM`` gives every block (the forward kernel's residuals cross by
+# name: one forward kernel a call site), under ``remat`` with no name saved
+# (the kernel runs again in the backward pass: what the name is for)
+REMATS = pytest.mark.parametrize("saved,forwards", [(None, 1), (BLOCK_POLICY, 1), ((), 2)],
+                                 ids=["no_remat", "block_policy", "nothing_saved"])
+
+
+def _library_attention_grads(saved, **mask):
+    """Per-worker body: ``banded_attention`` under ``mask`` and its three
+    gradients, the forward pass's result kept alive beside them."""
     def body(q, k, v):
         def loss(q, k, v):
-            o = jax.vmap(lambda *a: library_kernels.banded_attention(*a, window=window))(q, k, v)
+            o = jax.vmap(lambda *a: library_kernels.banded_attention(*a, **mask))(q, k, v)
             return jnp.sum(o.astype(jnp.float32))
 
-        return _total(jax.grad(loss, (0, 1, 2))(q, k, v))
+        if saved is not None:
+            loss = jax.checkpoint(
+                loss, policy=jax.checkpoint_policies.save_only_these_names(*saved))
+        total, grads = jax.value_and_grad(loss, (0, 1, 2))(q, k, v)
+        return _total(grads) + total
 
+    return body
+
+
+def _library_calls(forwards):
+    return {"splash_mqa_fwd_residuals": forwards, "splash_mqa_dq_no_residuals": 1,
+            "splash_mqa_dkv_no_residuals": 1}
+
+
+@REMATS
+@pytest.mark.parametrize("heads,window", [(64, 512), (48, None)])
+def test_attention_compiles_at_the_published_widths(mesh, compiled_kernels, heads, window,
+                                                    saved, forwards):
+    """Laguna-XS.2's two kinds of layer at the cell's sizes, with the
+    forward kernel's output and log-sum-exp named (``ATTEND_RESIDUAL``)."""
+    T, d, kv, clients, rows = 2048, 128, 8, 2, 2
     q = jax.ShapeDtypeStruct((clients, rows, T, heads, d), jnp.bfloat16)
     k = jax.ShapeDtypeStruct((clients, rows, T, kv, d), jnp.bfloat16)
-    text = _compile(mesh, body, q, k, k, specs=(P("workers"),) * 3)
-    assert text.count("tpu_custom_call") >= 3            # forward, dq, dkv
+    text = _compile(mesh, _library_attention_grads(saved, window=window), q, k, k,
+                    specs=(P("workers"),) * 3)
+    assert _kernel_calls(text) == _library_calls(forwards)
     assert "2048,2048" not in text                       # no [T, T] operand, per head or whole
 
 
-def test_block_diffusion_attention_compiles_at_the_published_widths(mesh, compiled_kernels):
+@REMATS
+def test_block_diffusion_attention_compiles_at_the_published_widths(mesh, compiled_kernels,
+                                                                    saved, forwards):
     """SDAR's cell: a noised and a clean copy of 8,192 tokens, 32 heads over
     4, blocks of 4; the mask's comparisons lower inside all three kernels and
     no ``[2T, 2T]`` or ``[T, T]`` operand exists."""
     T, d, heads, kv, clients, rows = 8192, 128, 32, 4, 2, 1
-
-    def body(q, k, v):
-        def loss(q, k, v):
-            o = jax.vmap(lambda *a: library_kernels.banded_attention(*a, block_length=4))(q, k, v)
-            return jnp.sum(o.astype(jnp.float32))
-
-        return _total(jax.grad(loss, (0, 1, 2))(q, k, v))
-
     q = jax.ShapeDtypeStruct((clients, rows, 2 * T, heads, d), jnp.bfloat16)
     k = jax.ShapeDtypeStruct((clients, rows, 2 * T, kv, d), jnp.bfloat16)
-    text = _compile(mesh, body, q, k, k, specs=(P("workers"),) * 3)
-    assert text.count("tpu_custom_call") >= 3            # forward, dq, dkv
+    text = _compile(mesh, _library_attention_grads(saved, block_length=4), q, k, k,
+                    specs=(P("workers"),) * 3)
+    assert _kernel_calls(text) == _library_calls(forwards)
     assert "16384,16384" not in text and "8192,8192" not in text
 
 
@@ -114,16 +147,6 @@ def test_grouped_product_compiles_at_the_published_widths(mesh, compiled_kernels
         jax.ShapeDtypeStruct((held, hidden, width), jnp.float32),
         jax.ShapeDtypeStruct((1, held), jnp.int32), specs=(P("workers"), P(), P("workers")))
     assert text.count("tpu_custom_call") >= 2            # the product and its transposes
-
-
-def _kernel_calls(text):
-    """Custom calls of the compiled text by the indexed-attention kernel's name."""
-    calls = re.findall(r'^\s*%?(indexed_\w+?)[.\d]* = .*custom_call_target="tpu_custom_call"',
-                       text, re.M)
-    return {name: calls.count(name) for name in set(calls)}
-
-
-BLOCK_POLICY = (indexed_attention.SELECT_RESIDUAL, indexed_attention.ATTEND_RESIDUAL)
 
 
 @pytest.mark.parametrize("saved,forwards", [(None, 1), (BLOCK_POLICY, 1), (BLOCK_POLICY[:1], 2)],

@@ -5,8 +5,13 @@ attention forward and its three gradients under it; no ``[2T, 2T]``
 operand; the clean stream against the same decoder run on the clean row
 alone; loss and every gradient leaf; the loss's weights and counters; the
 expert shares of the whole layer; the presets against the published keys,
-the cut's D and the required operations."""
+the cut's D and the required operations. Since PR 36 also what a block's
+``remat`` keeps of the library's attention kernel, for this decoder and for
+``laguna_tiny`` (one parametrised test for both; Keye's kernel is
+``test_keye.py``'s)."""
 
+import collections
+import dataclasses
 import json
 import os
 import re
@@ -20,8 +25,8 @@ from benchmark import flops_sdar, weights
 from benchmark.reference import sdar as ref
 from commefficient_tpu.data.fedtext import BlockNoise
 from commefficient_tpu.models import laguna, sdar
-from commefficient_tpu.models.laguna import Block, LagunaLM
-from commefficient_tpu.models.losses import block_diffusion_lm_loss
+from commefficient_tpu.models.laguna import Block, LagunaLM, laguna_tiny
+from commefficient_tpu.models.losses import block_diffusion_lm_loss, causal_lm_loss
 from commefficient_tpu.models.sdar import sdar_30b_a3b, sdar_tiny
 from commefficient_tpu.ops.pallas import library_kernels
 from commefficient_tpu.ops.pallas.library_kernels import BlockDiffusionMask, banded_attention
@@ -271,6 +276,79 @@ def test_vmap_over_clients_equals_a_loop_over_clients(tiny):
     stacked = {k: jnp.stack([c[k] for c in clients]) for k in clients[0]}
     together = jax.vmap(lambda b: loss(params, b)[0])(stacked)
     np.testing.assert_allclose(together, [loss(params, c)[0] for c in clients], rtol=1e-6)
+
+
+# ---- what a block's remat keeps of the library's attention kernel ----------------------------
+
+def _kernel_calls(jaxpr):
+    """Pallas calls of a jaxpr by the kernel's name, every sub-jaxpr walked
+    call site by call site (the printed text shows a repeated one once)."""
+    counts = collections.Counter()
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            counts[eqn.params["name"]] += 1
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else (value,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    counts.update(_kernel_calls(sub))
+    return counts
+
+
+def _tiny_gradient(cfg):
+    """A tiny round of the decoder ``cfg``: the kernels its gradient calls,
+    its loss and its gradient's leaves."""
+    model = LagunaLM(cfg)
+    if cfg.block_length:
+        batch, loss = _batch(cfg), block_diffusion_lm_loss(model.apply, "float32")
+    else:
+        ids = jax.random.randint(jax.random.key(1), (2, T), 0, cfg.vocab_held)
+        batch = {"input_ids": ids, "lm_labels": jnp.where(jnp.arange(T)[None, :] < 120, ids, -100)}
+        loss = causal_lm_loss(model.apply, "float32")
+    params, _ = _seeded(model, batch["input_ids"])
+    traced = jax.jit(jax.value_and_grad(loss, has_aux=True)).trace(params, batch)
+    (value, _aux), grads = traced.lower().compile()(params, batch)
+    return dict(layers=cfg.num_layers, calls=_kernel_calls(traced.jaxpr.jaxpr), loss=value,
+                grads=jax.tree.leaves(grads))
+
+
+@pytest.fixture(scope="module", params=[sdar_tiny, laguna_tiny], ids=lambda p: p.__name__)
+def kept_and_recomputed(request):
+    """A decoder whose library kernels name their residuals (the default, and
+    both presets') and the same decoder with ``attn_residuals_kept`` off
+    (``laguna_xs2``'s setting: plain ``remat``'s program)."""
+    cfg = request.param(dtype=jnp.float32)
+    assert cfg.attn_residuals_kept
+    return (_tiny_gradient(cfg),
+            _tiny_gradient(dataclasses.replace(cfg, attn_residuals_kept=False)))
+
+
+ATTENTION_KERNELS = ("splash_mqa_fwd_residuals", "splash_mqa_dq_no_residuals",
+                     "splash_mqa_dkv_no_residuals")
+
+
+def test_the_forward_kernel_runs_once_a_layer(kept_and_recomputed):
+    """``test_keye.py``'s test of the same name for the library's kernel: its
+    output and log-sum-exp cross the block's ``remat`` under the name
+    ``ATTEND_RESIDUAL``, so the gradient calls ``splash_mqa_fwd`` once a
+    layer, and ``dq`` and ``dkv`` once; built without the name
+    (``laguna_xs2``'s setting) the forward kernel is called twice a layer."""
+    kept, recomputed = kept_and_recomputed
+    once = dict.fromkeys(ATTENTION_KERNELS, kept["layers"])
+    assert {k: kept["calls"][k] for k in once} == once
+    assert {k: recomputed["calls"][k] for k in once} == {
+        **once, "splash_mqa_fwd_residuals": 2 * kept["layers"]}
+
+
+def test_the_kept_residuals_are_the_recomputed_ones(kept_and_recomputed):
+    """Loss and every gradient leaf, bit for bit: what is kept between the
+    passes is what the second forward kernel would have written."""
+    kept, recomputed = kept_and_recomputed
+    assert np.asarray(kept["loss"]).tobytes() == np.asarray(recomputed["loss"]).tobytes()
+    assert len(kept["grads"]) == len(recomputed["grads"]) > 0
+    for a, b in zip(kept["grads"], recomputed["grads"]):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert any(float(jnp.max(jnp.abs(a))) > 0 for a in kept["grads"])
 
 
 # ---- the shares of the whole layer -------------------------------------------------------
