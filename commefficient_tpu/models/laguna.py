@@ -350,16 +350,17 @@ class Attention(nn.Module):
             q = _dot(h, w("q_proj", (E, H * d)), c.dtype).reshape(B, T, H, d)
             k = _dot(h, w("k_proj", (E, KV * d)), c.dtype).reshape(B, T, KV, d)
             v = _dot(h, w("v_proj", (E, KV * d)), c.dtype).reshape(B, T, KV, d)
-        if c.qk_norm:
-            q = RMSNorm(c.rms_norm_eps, d, name="q_norm")(q)
-            k = RMSNorm(c.rms_norm_eps, d, name="k_norm")(k)
-        cos, sin, r = (c.rope_sliding if sliding else c.rope_full).tables(
-            T // 2 if diffusion else T, d)
-        if diffusion:   # the noised and the clean copy of a row sit at the row's positions
-            cos, sin = jnp.tile(cos, (2, 1)), jnp.tile(sin, (2, 1))
-        q = _rotate(q, cos, sin, r) / math.sqrt(d)
-        k = _rotate(k, cos, sin, r)
-        q, k, v = q.astype(c.dtype), k.astype(c.dtype), v.astype(c.dtype)
+        with jax.named_scope("attn_qk_prep"):
+            if c.qk_norm:
+                q = RMSNorm(c.rms_norm_eps, d, name="q_norm")(q)
+                k = RMSNorm(c.rms_norm_eps, d, name="k_norm")(k)
+            cos, sin, r = (c.rope_sliding if sliding else c.rope_full).tables(
+                T // 2 if diffusion else T, d)
+            if diffusion:   # the noised and the clean copy of a row sit at the row's positions
+                cos, sin = jnp.tile(cos, (2, 1)), jnp.tile(sin, (2, 1))
+            q = _rotate(q, cos, sin, r) / math.sqrt(d)
+            k = _rotate(k, cos, sin, r)
+            q, k, v = q.astype(c.dtype), k.astype(c.dtype), v.astype(c.dtype)
         counters = None
         if kind == "indexed_attention":
             J, e = c.index_heads, c.index_head_dim
@@ -372,10 +373,10 @@ class Attention(nn.Module):
                 proj = _dot(x, w("index_proj", (E, J * e + e + J)), c.dtype)
                 qi = proj[..., :J * e].reshape(B, T, J, e)
                 ki, wi = proj[..., J * e:J * e + e], proj[..., J * e + e:]
+                qi, ki = qi.astype(c.dtype), ki.astype(c.dtype)
             # the selection (attn_index/attn_select: scores and selection in
             # one kernel) and the attention (attn_sparse) open their own scopes
-            o, counters = indexed_attention(q, k, v, qi.astype(c.dtype), ki.astype(c.dtype), wi,
-                                            topk=c.index_topk)
+            o, counters = indexed_attention(q, k, v, qi, ki, wi, topk=c.index_topk)
         elif diffusion:
             with jax.named_scope("attn_blockdiff"):
                 o = banded_attention(q, k, v, block_length=c.block_length,
@@ -448,13 +449,17 @@ def make_routed_experts(tiers, dtype, tiling=GMM_TILING):
             apply = _expert_rows(tok, sizes, rows, dtype, tiling)
             return lambda *a: (apply(*a), jnp.float32(rows))
 
-        return either(tok, sizes, taking, h, wrow, gate, up, down)
+        # opened inside the mapped function: a loop's body is named from the
+        # scopes opened inside it down
+        with jax.named_scope("moe_loop"):
+            return either(tok, sizes, taking, h, wrow, gate, up, down)
 
     def backward_one(h, tok, wrow, sizes, gate, up, down, ct):
         def pull(rows):
             return lambda *a: jax.vjp(_expert_rows(tok, sizes, rows, dtype, tiling), *a)[1](ct)
 
-        return either(tok, sizes, pull, h, wrow, gate, up, down)
+        with jax.named_scope("moe_loop"):
+            return either(tok, sizes, pull, h, wrow, gate, up, down)
 
     forward = jax.custom_batching.sequential_vmap(forward_one)
     backward = jax.custom_batching.sequential_vmap(backward_one)
@@ -496,8 +501,8 @@ class MoE(nn.Module):
         B, T, E = h.shape
         N, K, G, F = B * T, c.num_experts_per_tok, len(c.experts_held), c.moe_intermediate_size
         std = c.initializer_range
-        h = h.reshape(N, E)
         with jax.named_scope("moe_route"):
+            h = h.reshape(N, E)
             router = _Kernel((E, c.num_experts), std, name="router")()
             logits = jnp.dot(h, router, precision=jax.lax.Precision.HIGHEST)
             if c.router == "sigmoid_scaled_shared":
@@ -522,18 +527,25 @@ class MoE(nn.Module):
         tiers = [_tier_rows(N, K, G, c.num_experts, f, c.expert_tiling[0])
                  for f in c.expert_row_tiers]
         apply = make_routed_experts(tiers, c.dtype, c.expert_tiling)
-        given = _floored(sizes, tiers[0]) if c.expert_rows_floored else sizes
-        routed_y, taken = apply(h, tok, wrow, given, *experts)
-        y = y + routed_y
-        routed = jnp.sum(slot < G).astype(jnp.float32)
-        counters = {
-            "moe/held_assignments": routed,
-            "moe/max_expert_load": jnp.max(sizes).astype(jnp.float32),
-            # routed to a held expert and not among the rows the branch that
-            # ran gave the product (the held ones come first in sorted order)
-            "moe/dropped": routed - jnp.minimum(routed, taken),
-        }
-        return y.reshape(B, T, E), counters
+        with jax.named_scope("moe_dispatch"):
+            given = _floored(sizes, tiers[0]) if c.expert_rows_floored else sizes
+        # the loop over clients that ``sequential_vmap`` makes of the call: its
+        # own op, each client's slices and updates (the body's scopes nest)
+        with jax.named_scope("moe_loop"):
+            routed_y, taken = apply(h, tok, wrow, given, *experts)
+        with jax.named_scope("moe_combine"):
+            y = y + routed_y
+        with jax.named_scope("moe_dispatch"):
+            routed = jnp.sum(slot < G).astype(jnp.float32)
+            counters = {
+                "moe/held_assignments": routed,
+                "moe/max_expert_load": jnp.max(sizes).astype(jnp.float32),
+                # routed to a held expert and not among the rows the branch that
+                # ran gave the product (the held ones come first in sorted order)
+                "moe/dropped": routed - jnp.minimum(routed, taken),
+            }
+        with jax.named_scope("moe_combine"):
+            return y.reshape(B, T, E), counters
 
 
 class _Experts(nn.Module):
@@ -561,14 +573,19 @@ class Block(nn.Module):
     @nn.compact
     def __call__(self, x):
         c = self.cfg
-        a, attended = Attention(c, self.layer, name="attn")(
-            RMSNorm(c.rms_norm_eps, c.hidden_size, name="attn_norm")(x))
-        x = x + a
-        h = RMSNorm(c.rms_norm_eps, c.hidden_size, name="mlp_norm")(x)
+        with jax.named_scope("block_norm"):
+            h = RMSNorm(c.rms_norm_eps, c.hidden_size, name="attn_norm")(x)
+        a, attended = Attention(c, self.layer, name="attn")(h)
+        with jax.named_scope("residual_add"):
+            x = x + a
+        with jax.named_scope("block_norm"):
+            h = RMSNorm(c.rms_norm_eps, c.hidden_size, name="mlp_norm")(x)
         if c.mlp_layer_types[self.layer] == "dense":
-            return x + SwiGLU(c, c.intermediate_size, name="mlp")(h), None, attended
-        y, counters = MoE(c, name="moe")(h)
-        return x + y, counters, attended
+            y, counters = SwiGLU(c, c.intermediate_size, name="mlp")(h), None
+        else:
+            y, counters = MoE(c, name="moe")(h)
+        with jax.named_scope("residual_add"):
+            return x + y, counters, attended
 
 
 class LagunaLM(nn.Module):
@@ -599,8 +616,9 @@ class LagunaLM(nn.Module):
                 noised = input_ids if noise is None else jnp.where(
                     noise[0], jnp.asarray(c.mask_token, input_ids.dtype), input_ids)
                 ids = jnp.concatenate([noised, input_ids], 1)         # [B, 2 T]
-        x = nn.Embed(c.vocab_held, c.hidden_size, name="embed", param_dtype=jnp.float32,
-                     embedding_init=nn.initializers.normal(c.initializer_range))(ids)
+        with jax.named_scope("embed"):
+            x = nn.Embed(c.vocab_held, c.hidden_size, name="embed", param_dtype=jnp.float32,
+                         embedding_init=nn.initializers.normal(c.initializer_range))(ids)
         # kept for the backward pass beside a block's input, where a block's
         # kernels name them: the attention kernel's own residuals (its output
         # and log-sum-exp: the recomputed forward does not run the kernel again)
@@ -640,9 +658,6 @@ class LagunaLM(nn.Module):
         if diffusion:
             if noise is None:
                 raise ValueError("a block-diffusion loss needs the batch's noise")
-            kept = lm_labels != IGNORE_INDEX
-            masked = noise[0] & kept
-            weight = jnp.where(masked, 1.0 / noise[1], 0.0)
 
             def weighted_nll_chunk(args):
                 """One chunk's weighted sum, every position of it computed
@@ -652,15 +667,21 @@ class LagunaLM(nn.Module):
                 with jax.named_scope("diffusion_loss"):
                     return weighted_cross_entropy_sum(logits(x, scale, head), targets, w)
 
-            chunk = c.head_chunk or T
-            by_chunk = lambda a: a.reshape(B, T // chunk, chunk, *a.shape[2:]).swapaxes(0, 1)  # noqa: E731
-            sums = jax.lax.map(jax.checkpoint(weighted_nll_chunk),
-                               (by_chunk(x), by_chunk(input_ids), by_chunk(weight)))
-            labelled = jnp.sum(kept, dtype=jnp.float32)
-            totals.update({"diffusion/masked_tokens": jnp.sum(masked, dtype=jnp.float32),
-                           "diffusion/labelled_tokens": labelled,
-                           "diffusion/weight_sum": jnp.sum(weight)})
-            return (jnp.sum(sums), labelled), totals
+            # the weights, the chunks' reshapes and the loop's own op carry
+            # the name from here; the body's ops from the scope inside it
+            with jax.named_scope("diffusion_loss"):
+                kept = lm_labels != IGNORE_INDEX
+                masked = noise[0] & kept
+                weight = jnp.where(masked, 1.0 / noise[1], 0.0)
+                chunk = c.head_chunk or T
+                by_chunk = lambda a: a.reshape(B, T // chunk, chunk, *a.shape[2:]).swapaxes(0, 1)  # noqa: E731
+                sums = jax.lax.map(jax.checkpoint(weighted_nll_chunk),
+                                   (by_chunk(x), by_chunk(input_ids), by_chunk(weight)))
+                labelled = jnp.sum(kept, dtype=jnp.float32)
+                totals.update({"diffusion/masked_tokens": jnp.sum(masked, dtype=jnp.float32),
+                               "diffusion/labelled_tokens": labelled,
+                               "diffusion/weight_sum": jnp.sum(weight)})
+                return (jnp.sum(sums), labelled), totals
 
         def nll(x, scale, head):
             with jax.named_scope("lm_head"):
@@ -680,9 +701,10 @@ class LagunaLM(nn.Module):
         # position t's target is label t + 1, and the last position has none:
         # the same sum, a chunk of positions at a time
         n = T // c.head_chunk
-        targets = jnp.concatenate(
-            [lm_labels[:, 1:], jnp.full((B, 1), IGNORE_INDEX, lm_labels.dtype)], 1)
-        sums, kept = jax.lax.map(jax.checkpoint(nll_chunk), (
-            x.reshape(B, n, c.head_chunk, -1).swapaxes(0, 1),
-            targets.reshape(B, n, c.head_chunk).swapaxes(0, 1)))
-        return (jnp.sum(sums), jnp.sum(kept)), totals
+        with jax.named_scope("lm_head"):    # the targets, the chunks and the loop's own op
+            targets = jnp.concatenate(
+                [lm_labels[:, 1:], jnp.full((B, 1), IGNORE_INDEX, lm_labels.dtype)], 1)
+            sums, kept = jax.lax.map(jax.checkpoint(nll_chunk), (
+                x.reshape(B, n, c.head_chunk, -1).swapaxes(0, 1),
+                targets.reshape(B, n, c.head_chunk).swapaxes(0, 1)))
+            return (jnp.sum(sums), jnp.sum(kept)), totals

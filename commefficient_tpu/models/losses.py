@@ -205,7 +205,8 @@ def causal_lm_loss(apply_fn, compute_dtype=None):
         if cd is not None:
             params = _cast_floats(params, cd)
         (lm_sum, tok_count), counters = apply_fn(params, batch["input_ids"], batch["lm_labels"])
-        loss = lm_sum / jnp.maximum(tok_count, 1.0)
+        with jax.named_scope("lm_head"):    # telemetry.trace.MODEL_SCOPES: the loss's mean
+            loss = lm_sum / jnp.maximum(tok_count, 1.0)
         return loss, {"lm_loss": loss, "lm_loss_sum": lm_sum,
                       "token_count": tok_count, **counters}
 
@@ -231,7 +232,8 @@ def block_diffusion_lm_loss(apply_fn, compute_dtype=None):
         (weighted, kept), counters = apply_fn(
             params, batch["input_ids"], batch["lm_labels"],
             (batch["noise_mask"], batch["noise_t"]))
-        loss = weighted / jnp.maximum(kept, 1.0)
+        with jax.named_scope("diffusion_loss"):
+            loss = weighted / jnp.maximum(kept, 1.0)
         return loss, {"lm_loss": loss, "lm_loss_sum": weighted,
                       "token_count": kept, **counters}
 

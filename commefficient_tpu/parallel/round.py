@@ -209,11 +209,16 @@ def resolve_client_path(cfg: Config, comp) -> str:
 def _client_value_and_grad(loss_fn: Callable, unravel: Callable, params_vec,
                            batch):
     """``((loss, aux), gradient pytree)`` of one client's batch: the
-    closure both client paths share, and all they share. When the loss
-    shards its compute over model/seq axes (tensor.build_tp_flat_loss),
-    the vma transpose totals the gradient over those axes by itself."""
-    return jax.value_and_grad(loss_fn, has_aux=True)(unravel(params_vec),
-                                                     batch)
+    closure both client paths share, and all they share. The split of the
+    [D] vector into the model's leaves is named ``param_unravel``
+    (telemetry.trace.MODEL_SCOPES: it nests under the caller's
+    ``client_grad``); the gradient is taken with respect to the leaves, so
+    the name has no backward wrapping. When the loss shards its compute
+    over model/seq axes (tensor.build_tp_flat_loss), the vma transpose
+    totals the gradient over those axes by itself."""
+    with jax.named_scope("param_unravel"):
+        params = unravel(params_vec)
+    return jax.value_and_grad(loss_fn, has_aux=True)(params, batch)
 
 
 def make_grad_one(cfg: Config, loss_fn: Callable, unravel: Callable):

@@ -134,8 +134,10 @@ ROUND_SCOPES: Tuple[Tuple[str, str], ...] = (
                        ">= 1 only"),
 )
 
-# The scopes a model opens inside ``client_grad`` (models/laguna.py, and the
-# loss beside it): the same vocabulary and the same tests as ROUND_SCOPES
+# The scopes a model opens inside ``client_grad`` (models/laguna.py and the
+# loss beside it; ``param_unravel`` is parallel/round.py's, listed here
+# because it nests under ``client_grad``): the same vocabulary and the same
+# tests as ROUND_SCOPES
 # (no name contains another, here or across the two lists; the source opens
 # no scope outside them), kept apart because every one nests under
 # ``client_grad``: the benchmark's ``round.unscoped_s_per_round`` pattern is
@@ -180,6 +182,25 @@ MODEL_SCOPES: Tuple[Tuple[str, str], ...] = (
     ("diffusion_loss", "the head of a block-diffusion model (lm_head nests "
                        "under it) and the weighted cross-entropy of the "
                        "noised stream's positions"),
+    ("block_norm", "the float32 RMS norm of the residual stream on a block's "
+                   "input, twice a layer (the final norm stays in lm_head)"),
+    ("attn_qk_prep", "what is done to q, k, v between the projections and the "
+                     "attention kernel: the heads' norms, the rotary tables "
+                     "and the rotation, 1 / sqrt(d), the casts"),
+    ("residual_add", "the residual stream's adds, twice a layer (what XLA "
+                     "does not fuse into a neighbour)"),
+    ("embed", "the embedding's gather and, transposed, the scatter-add of "
+              "its gradient (a whole word in a pattern: the module is "
+              "named embed too)"),
+    ("moe_loop", "the client-after-client loop the expert layer's "
+                 "sequential_vmap makes: its own op, each client's slices and "
+                 "updates, the tier cond / switch; moe_dispatch, moe_experts "
+                 "and moe_combine nest under it"),
+    ("param_unravel", "the split of the [D] vector into the model's leaves "
+                      "(parallel/round.py::_client_value_and_grad): it is "
+                      "no model's, and sits here because it nests under "
+                      "client_grad; the gradient is taken with respect to its "
+                      "output, so it has no backward pass"),
 )
 
 # Priority order for exclusive assignment (idle is always the remainder).

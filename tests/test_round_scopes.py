@@ -198,8 +198,34 @@ NEVER_INDEXED = ("attn_full", "attn_window", "mlp_dense") + DIFFUSION_ONLY
 NEVER_DIFFUSION = ("attn_full", "attn_window", "mlp_dense") + INDEXED_ONLY
 
 
+# the one model scope with no backward pass (the gradient is taken with
+# respect to the split's output), and the three that the expert layer's mapped
+# function opens, which therefore always sit under ``moe_loop``
+NO_BACKWARD = ("param_unravel",)
+IN_THE_EXPERT_LOOP = ("moe_dispatch", "moe_experts", "moe_combine")
+
+
 def _under(names, scope):
     return [n for n in names if re.search(r"\b" + scope + r"\b", n)]
+
+
+def _the_expert_loop_wraps(names, scope):
+    """What ``make_routed_experts``' mapped functions open sits under
+    ``moe_loop``: every op of ``moe_experts``; of ``moe_dispatch`` and
+    ``moe_combine`` the rows' gather and scatter-add (the sort, the counts,
+    the counters and ``y + routed_y`` are ``MoE.__call__``'s, outside the
+    loop). ``moe_loop`` itself is opened twice: around the call (the loop's
+    op, each client's slices and updates) and inside the mapped function (a
+    loop's body is named from the scopes opened inside it down)."""
+    under = _under(names, scope)
+    if scope == "moe_experts":
+        assert all("moe_loop" in n for n in under)
+    elif scope in IN_THE_EXPERT_LOOP:
+        assert any("moe_loop" in n for n in under)
+    elif scope == "moe_loop":
+        assert any(re.search(r"moe_loop/while/body/(dynamic_slice|dynamic_update_slice)$", n)
+                   for n in under)
+        assert any(re.search(r"moe_loop/while/body/.*moe_loop/", n) for n in under)
 
 
 @pytest.mark.parametrize("scope", [n for n in MODEL_NAMES
@@ -215,8 +241,9 @@ def test_model_scopes_sit_under_client_grad_forward_and_backward(laguna_op_names
     assert under
     outside = [n for n in under if "client_grad" not in n and n.startswith("jit(")]
     assert not outside, outside
-    assert any("transpose(" in n for n in under)
+    assert any("transpose(" in n for n in under) == (scope not in NO_BACKWARD)
     assert any("transpose(" not in n for n in under)
+    _the_expert_loop_wraps(laguna_op_names, scope)
 
 
 @pytest.mark.parametrize("scope", [n for n in MODEL_NAMES if n not in NEVER_INDEXED])
@@ -224,17 +251,22 @@ def test_model_scopes_of_an_indexed_model(keye_op_names, scope):
     """The same at ``keye_tiny``: its own three scopes beside the ones it
     shares with Laguna, and none of Laguna's attention kinds. The
     selection runs once, in the forward pass (its thresholds cross ``remat``
-    as a residual), so ``attn_select`` alone has no backward wrapping, and it
-    nests under ``attn_index``, whose projections are recomputed."""
+    as a residual), so ``attn_select`` has no backward wrapping (nor has
+    ``param_unravel``), and it nests under ``attn_index``, whose projections
+    are recomputed."""
     under = _under(keye_op_names, scope)
     assert under
     # (the chunked head is a loop, whose body the compiler names from the
     # scope down: ``jit(wrapped)/lm_head/...``; the loop's own op carries the
-    # whole path, and a trace's union under ``client_grad`` holds its span)
+    # whole path, and a trace's union under ``client_grad`` holds its span;
+    # what a trace holds under a model's name and outside ``client_grad`` is
+    # ``model.outside_client_grad_s_per_round``, benchmark/layers/)
     assert not [n for n in under if "client_grad" not in n and n.startswith("jit(")
                 and not n.startswith(f"jit(wrapped)/{scope}/")]
     assert any("transpose(" not in n for n in under)
-    assert any("transpose(" in n for n in under) == (scope != "attn_select")
+    assert any("transpose(" in n for n in under) == (
+        scope not in ("attn_select",) + NO_BACKWARD)
+    _the_expert_loop_wraps(keye_op_names, scope)
     if scope == "attn_select":
         assert all("attn_index/" in n for n in under if n.startswith("jit("))
 
@@ -251,7 +283,8 @@ def test_model_scopes_of_a_block_diffusion_model(sdar_op_names, scope):
     assert not [n for n in under if "client_grad" not in n and n.startswith("jit(")
                 and not re.match(r"jit\(wrapped\)/(diffusion_loss|lm_head)/", n)]
     assert any("transpose(" not in n for n in under)
-    assert any("transpose(" in n for n in under)
+    assert any("transpose(" in n for n in under) == (scope not in NO_BACKWARD)
+    _the_expert_loop_wraps(sdar_op_names, scope)
     if scope == "lm_head":
         assert all("diffusion_loss/" in n for n in under if n.startswith("jit("))
 
@@ -285,7 +318,36 @@ def test_the_round_scopes_still_close_on_the_lm_round(names, request):
             if n.startswith("jit(") and not rx.search(n)}
     # the chunked head's loop: a cast and two index broadcasts the compiler
     # lifts out of the body keep the model's scope and lose the round's
-    # (a block-diffusion model's head is that loop under ``diffusion_loss``)
+    # (a block-diffusion model's head is that loop under ``diffusion_loss``);
+    # ``model.outside_client_grad_s_per_round`` reads such ops in a trace
     lifted = {n for n in bare if re.match(r"jit\(wrapped\)/(lm_head|diffusion_loss)/", n)}
     assert bare - lifted <= {"jit(wrapped)/add"}, bare
     assert len(lifted) <= 4 and (not lifted or names != "laguna_op_names"), lifted
+
+
+# scalar bookkeeping and two call ops, by name with the layer's number out
+# of it: the counters' maxima and sums over the layers (``LagunaLM``), the
+# block's ``remat`` call itself (its body's ops carry their scopes), and the
+# two sums ``ops/pallas/indexed_attention.py`` makes of an indexed layer's
+# pair counts
+UNNAMED_UNDER_CLIENT_GRAD = {
+    "jvp(LagunaLM)/reduce_max",
+    "jvp(LagunaLM)/reduce_sum",
+    "transpose(jvp(LagunaLM))/vmap(client_grad)/jvp(LagunaLM)/remat2",
+    "jvp(LagunaLM)/layer_N/attn/reduce_sum",
+}
+
+
+@pytest.mark.parametrize("names", ["laguna_op_names", "keye_op_names", "sdar_op_names"])
+def test_the_model_scopes_close_on_client_grad(names, request):
+    """Every op of the lowered tiny round whose name holds ``client_grad``
+    holds a name of ``MODEL_SCOPES`` (``embed`` as a whole word: the module is
+    named so too), but for the allow-list: what
+    ``model.unnamed_s_per_round`` reads in a cell is these and the ops the
+    compiler makes without a name, nothing the source left bare."""
+    assert len(UNNAMED_UNDER_CLIENT_GRAD) <= 6
+    rx = re.compile("|".join(r"\b" + n + r"\b" for n in MODEL_NAMES))
+    bare = {re.sub(r"layer_\d+", "layer_N", n).split("vmap(client_grad)/", 1)[1]
+            for n in request.getfixturevalue(names)
+            if "client_grad" in n and not rx.search(n)}
+    assert bare <= UNNAMED_UNDER_CLIENT_GRAD, bare - UNNAMED_UNDER_CLIENT_GRAD
