@@ -75,7 +75,10 @@ Since PR 35 a fourth attention kind, ``block_diffusion_attention``
 (``models/sdar.py``), and with it another objective: the decoder reads a
 stream of ``2 T`` positions, a row's noised copy then its clean one, under
 block diffusion's mask (``library_kernels.BlockDiffusionMask``: no ``[2T,
-2T]`` tensor either), both copies at the row's rotary positions ``0..T-1``.
+2T]`` tensor either; since PR 38 the kernels visit its full tiles unmasked
+and load the straddling tiles' masks as a few stored blocks, where the
+causal and sliding layers' masks are compared on every tile), both copies
+at the row's rotary positions ``0..T-1``.
 ``LagunaLM`` makes the noised copy from ``input_ids`` and the batch's
 ``noise_mask`` (``mask_token`` where it is set), joins and splits the two
 streams (scope ``diffusion_streams``), and gives the loss the noised

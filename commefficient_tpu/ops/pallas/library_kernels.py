@@ -100,39 +100,46 @@ def _typed_call(call):
     return typed
 
 
-class BlockDiffusionMask(_mask._ComputableMask):
+class BlockDiffusionMask(_mask.Mask):
     """Block diffusion's mask (BD3-LMs, arXiv:2503.09573) over a stream of
     ``2 T`` positions, the noised copy of a row then the clean one, blocks of
     ``block_length``: with ``b(i) = i // block_length`` a noised query ``i``
     reads the noised keys of its own block and the clean keys of strictly
     earlier blocks; a clean query reads the clean keys of its own and earlier
-    blocks, and no noised key. The library evaluates ``mask_function`` on
-    ``q_sequence``'s values and key positions, a tile at a time: in numpy
-    where it builds the tables of the tiles to visit (a tile wholly outside
-    the mask is skipped by the forward and both backward kernels), and inside
-    the kernels on the tiles visited, so the mask is never an array.
+    blocks, and no noised key.
 
-    ``q_sequence`` is the library's per-query operand and need not be the
-    position: here it is the query's whole rule as one number, so that the
-    kernels compare and do not divide. A noised query of block ``b`` carries
-    ``c = b L`` (its block's first position), a clean one ``c = -(b + 1) L``;
-    a clean key ``k >= T`` is read where ``k - T < |c|``, a noised key where
-    ``0 <= k - c < L``, which no ``c < 0`` meets."""
+    The library reads the mask a tile at a time, in numpy, once, where it
+    builds the tables of the tiles to visit: a tile wholly outside the mask
+    is skipped by the forward and both backward kernels, a tile wholly
+    inside it is visited with no mask at all (the library's
+    ``should_not_mask``), and a tile that straddles a boundary loads its
+    mask as one of the few distinct blocks the tables keep (three at
+    ``T`` = 8,192 and tiles of 512: the noised diagonal, the strict and the
+    inclusive clean one). No rule is evaluated inside the kernels, and the
+    mask is never a ``[2T, 2T]`` array. A plain ``Mask`` with neither
+    ``q_sequence`` nor ``mask_function``: the library's computable form
+    would evaluate the rule on every element of every tile visited."""
 
     def __init__(self, shape, block_length: int):
         if shape[0] != shape[1] or shape[0] % 2 or (shape[0] // 2) % block_length:
             raise ValueError(f"block diffusion mask: {shape} is not two rows of whole blocks "
                              f"of {block_length}")
-        T, L = shape[0] // 2, block_length
-        self.block_length = L
+        self._shape = tuple(shape)
+        self.block_length = block_length
 
-        def block_diffusion_mask_function(c, k):
-            at = k - c
-            return ((k >= T) & (k - T < abs(c))) | ((k < T) & (at >= 0) & (at < L))
+    @property
+    def shape(self):
+        return self._shape
 
-        super().__init__(shape=shape, mask_function=block_diffusion_mask_function)
-        first = np.arange(T, dtype=np.int32) // L * L
-        self.q_sequence = np.concatenate([first, -(first + L)])
+    def __getitem__(self, idx):
+        """``[q_slice, kv_slice]`` -> that part of the mask, bool."""
+        q, k = (np.arange(*s.indices(n)) for s, n in zip(idx, self._shape))
+        T, L = self._shape[0] // 2, self.block_length
+        q_clean, k_clean = q[:, None] >= T, k[None, :] >= T
+        bq, bk = q[:, None] % T // L, k[None, :] % T // L
+        # a clean key of an earlier block (of its own too, for a clean query),
+        # or a noised key of a noised query's own block
+        return (k_clean & (bk < bq + q_clean)) | (~k_clean & ~q_clean & (bk == bq))
 
     def __eq__(self, other):
         if not isinstance(other, type(self)):
@@ -145,18 +152,25 @@ class BlockDiffusionMask(_mask._ComputableMask):
 
 @functools.lru_cache(maxsize=None)
 def _attention_kernel(T, group, window, blk, interpret, block_length=0, residuals_named=True):
-    """One KV head's multi-query kernel over its ``group`` of query heads.
-    Built once per shape, its block-mask tables as concrete arrays: the
-    caller's ``custom_vjp`` closes over them, which a tracer may not be.
-    Whatever the mask, with ``residuals_named`` the forward kernel's output
-    and log-sum-exp carry the name ``ATTEND_RESIDUAL`` (the library's
-    ``residual_checkpoint_name``)."""
+    """One KV head's multi-query kernel over its ``group`` of query heads,
+    under the mask the arguments name. Built once per shape."""
     if block_length:
         head = BlockDiffusionMask((T, T), block_length)
     elif window is None:
         head = _mask.CausalMask((T, T))
     else:
         head = _mask.LocalMask((T, T), (window - 1, 0), 0)
+    return mqa_kernel(head, group, blk, interpret, residuals_named)
+
+
+def mqa_kernel(head, group, blk, interpret, residuals_named=True):
+    """The library's multi-query kernel over ``group`` query heads that all
+    read under the mask ``head``, tiles of ``blk`` in the forward and both
+    backward kernels, its block-mask tables as concrete arrays: the caller's
+    ``custom_vjp`` closes over them, which a tracer may not be. Whatever the
+    mask, with ``residuals_named`` the forward kernel's output and
+    log-sum-exp carry the name ``ATTEND_RESIDUAL`` (the library's
+    ``residual_checkpoint_name``)."""
     with jax.ensure_compile_time_eval():
         return _splash.make_splash_mqa_single_device(
             _mask.MultiHeadMask([head] * group),
@@ -176,11 +190,14 @@ def banded_attention(q, k, v, *, window=None, block_length=0, residuals_named=Tr
     each KV head is one multi-query kernel call over its group, so K and V
     are never repeated in memory. ``window`` keeps ``t - window < s <= t``
     (``None``: all ``s <= t``); blocks wholly outside that band are skipped
-    by the kernel's block mask, in the forward and both backward kernels.
-    With ``block_length`` the ``T`` positions are a noised and a clean copy
-    of a row of ``T / 2`` and the mask is ``BlockDiffusionMask``: not causal,
-    and as little an array. Running softmax statistics are float32; ``T`` is
-    a multiple of 128.
+    by the kernel's block mask, in the forward and both backward kernels,
+    and the kernels compare positions on every tile they visit (the
+    library's computable ``CausalMask`` and ``LocalMask``). With
+    ``block_length`` the ``T`` positions are a noised and a clean copy of a
+    row of ``T / 2`` and the mask is ``BlockDiffusionMask``: not causal, and
+    as little an array; its tables hand the kernels the straddling tiles'
+    masks as a few stored blocks, and its full tiles go unmasked. Running
+    softmax statistics are float32; ``T`` is a multiple of 128.
 
     What the forward kernel leaves its own backward kernels, the output
     ``[B, KV, group, T, d]`` in ``q``'s dtype and the log-sum-exp

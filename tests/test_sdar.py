@@ -8,7 +8,9 @@ expert shares of the whole layer; the presets against the published keys,
 the cut's D and the required operations. Since PR 36 also what a block's
 ``remat`` keeps of the library's attention kernel, for this decoder and for
 ``laguna_tiny`` (one parametrised test for both; Keye's kernel is
-``test_keye.py``'s)."""
+``test_keye.py``'s). Since PR 38 the library's tables of the mask (full
+tiles unmasked, three stored blocks) and the kernels' floats against the
+computable form the mask had before, kept here as a witness."""
 
 import collections
 import dataclasses
@@ -20,6 +22,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental.pallas.ops.tpu.splash_attention import (
+    splash_attention_mask as _splash_mask,
+    splash_attention_mask_info as _mask_info,
+)
 
 from benchmark import flops_sdar, weights
 from benchmark.reference import sdar as ref
@@ -98,9 +104,10 @@ def _dense_mask(t, block_length):
 
 @pytest.mark.parametrize("t,block_length", [(128, 1), (128, 4), (128, 128), (256, 4), (384, 6)])
 def test_the_kernels_mask_is_the_four_rules(t, block_length):
-    """What the library reads off the mask, a tile at a time in numpy and in
-    the kernels on ``q_sequence``'s codes, is the dense mask of the rules;
-    the reference's own comparisons give the same."""
+    """What the library reads off the mask, a tile at a time in numpy where
+    it builds the kernels' tables and stores the straddling tiles' blocks,
+    is the dense mask of the rules; the reference's own comparisons give
+    the same."""
     mask = BlockDiffusionMask((2 * t, 2 * t), block_length)
     want = _dense_mask(t, block_length)
     got = mask[:, :]
@@ -121,6 +128,77 @@ def test_tiles_wholly_outside_the_mask_are_not_visited():
     some, every = tiles.any((2, 3)), tiles.all((2, 3))
     assert some.sum() == 288 and (some & ~every).sum() == 48
     assert not some[16:, :16].any()                      # no clean query reads a noised key
+
+
+@pytest.mark.parametrize("is_dkv", [False, True], ids=["fwd_dq", "dkv"])
+def test_the_library_visits_full_tiles_unmasked_and_stores_three_blocks(is_dkv):
+    """The kernel's mask at the cell's shape (2T = 16,384, tiles of 512, a
+    group of 8) as the library's tables hold it: no rule for the kernels to
+    evaluate, 240 full and 48 partial tiles a head, and the partial ones'
+    masks as 3 distinct stored blocks (the noised diagonal, the strict and
+    the inclusive clean one)."""
+    head = BlockDiffusionMask((16384, 16384), 4)
+    info, mask_function = _mask_info.process_mask(
+        _splash_mask.MultiHeadMask([head] * 8), (512, 512), is_dkv=is_dkv)
+    assert mask_function is None and info.q_sequence is None
+    block_mask = np.asarray(info.block_mask)
+    assert block_mask.shape[0] == 1                        # one table for the group's heads
+    assert (block_mask == 2).sum() == 240 and (block_mask == 1).sum() == 48
+    assert info.partial_mask_blocks.shape == (3, 512, 512)
+
+
+class _ComputableBlockDiffusionMask(_splash_mask._ComputableMask):
+    """The witness: ``BlockDiffusionMask`` as it stood before PR 38, the rule
+    as the library's ``mask_function`` on ``q_sequence``'s codes (a noised
+    query of block ``b`` carries ``b L``, a clean one ``-(b + 1) L``),
+    evaluated inside the kernels on every element of every tile visited."""
+
+    def __init__(self, shape, block_length):
+        T, L = shape[0] // 2, block_length
+        self.block_length = L
+
+        def block_diffusion_mask_function(c, k):
+            at = k - c
+            return ((k >= T) & (k - T < abs(c))) | ((k < T) & (at >= 0) & (at < L))
+
+        super().__init__(shape=shape, mask_function=block_diffusion_mask_function)
+        first = np.arange(T, dtype=np.int32) // L * L
+        self.q_sequence = np.concatenate([first, -(first + L)])
+
+    def __eq__(self, other):
+        if not isinstance(other, type(self)):
+            return NotImplemented
+        return self.shape == other.shape and self.block_length == other.block_length
+
+    def __hash__(self):
+        return hash((type(self), self.shape, self.block_length))
+
+
+@pytest.fixture
+def fresh_kernels():
+    library_kernels._attention_kernel.cache_clear()
+    yield
+    library_kernels._attention_kernel.cache_clear()
+
+
+@pytest.mark.parametrize("t", [512, 192], ids=["tiles_of_512", "tiles_of_128"])
+def test_stored_blocks_give_the_computable_masks_floats(t, monkeypatch, fresh_kernels):
+    """The output and the q, k and v gradients of ``banded_attention`` are
+    bit for bit those of the same kernels built from the computable form."""
+    q, k, v, ct = _qkv(t)
+
+    def attend():
+        out = banded_attention(q, k, v, block_length=4)
+        grads = jax.grad(lambda *a: jnp.sum(banded_attention(*a, block_length=4) * ct),
+                         (0, 1, 2))(q, k, v)
+        return out, *grads
+
+    stored = attend()
+    monkeypatch.setattr(library_kernels, "BlockDiffusionMask", _ComputableBlockDiffusionMask)
+    library_kernels._attention_kernel.cache_clear()
+    computed = attend()
+    for a, b in zip(stored, computed):
+        np.testing.assert_array_equal(a, b)
 
 
 def test_a_mask_of_half_blocks_is_refused():
