@@ -101,6 +101,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from commefficient_tpu.models.decoder_parts import RMSNorm, _dot, _Kernel, _rms
+from commefficient_tpu.models.kda import KimiDeltaAttention
+from commefficient_tpu.models.latent_attention import LatentAttention
 from commefficient_tpu.models.losses import (
     IGNORE_INDEX,
     softmax_cross_entropy_sum,
@@ -218,6 +221,14 @@ class LagunaConfig:
     expert_rows_floored: bool = False  # the product always runs over the first tier's rows
     block_length: int = 0       # ``block_diffusion_attention`` layers: positions a block,
     mask_token: int = -1        # and the id a noised position reads
+    kda_heads: int = 0          # ``kda_attention`` layers (``models/kda.py``): heads,
+    kda_head_dim: int = 0       # their key and value width,
+    kda_conv: int = 0           # the short convolution's taps,
+    kda_chunk: int = 64         # and the positions the rule takes at a time
+    kv_lora_rank: int = 0       # ``latent_attention`` layers (``models/latent_attention.py``):
+    qk_nope_head_dim: int = 0   # the latent's width, a head's key width from it,
+    qk_rope_head_dim: int = 0   # the key width every head shares,
+    v_head_dim: int = 0         # and a head's value width
 
     @property
     def num_layers(self) -> int:
@@ -277,50 +288,12 @@ PRESETS = {"laguna_xs2": laguna_xs2, "laguna_tiny": laguna_tiny}
 
 # ---- pieces -------------------------------------------------------------------
 
-def _dot(x, w, dtype):
-    """Operands in ``dtype``, float32 accumulation and result."""
-    return jnp.dot(x.astype(dtype), w.astype(dtype), preferred_element_type=jnp.float32)
-
-
 def _rotate(x, cos, sin, r):
     """``x`` ``[B, T, H, d]`` float32: the first ``r`` dims rotated."""
     half = r // 2
     x1, x2, rest = x[..., :half], x[..., half:r], x[..., r:]
     c, s = cos[None, :, None, :], sin[None, :, None, :]
     return jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s, rest], -1)
-
-
-def _rms(x, scale, eps):
-    x = x.astype(jnp.float32)
-    return x * jax.lax.rsqrt(jnp.mean(jnp.square(x), -1, keepdims=True) + eps) * scale
-
-
-class RMSNorm(nn.Module):
-    """The ``scale`` leaf (float32, ones); ``__call__(x)`` norms, ``scale()``
-    hands the leaf out for a caller that norms inside a plain function."""
-
-    eps: float
-    width: int
-
-    def setup(self):
-        self.weight = self.param("scale", nn.initializers.ones, (self.width,), jnp.float32)
-
-    def scale(self):
-        return self.weight
-
-    def __call__(self, x):
-        return _rms(x, self.weight, self.eps)
-
-
-class _Kernel(nn.Module):
-    """A bias-free projection's ``kernel`` leaf, float32."""
-
-    shape: Tuple[int, ...]
-    std: float
-
-    @nn.compact
-    def __call__(self):
-        return self.param("kernel", nn.initializers.normal(self.std), self.shape, jnp.float32)
 
 
 class SwiGLU(nn.Module):
@@ -569,6 +542,11 @@ class _Experts(nn.Module):
                 self.param("down_proj", init, (G, F, E), jnp.float32))
 
 
+# the mixers whose layer kind is a module of its own (every other kind is
+# ``Attention``'s)
+MIXERS = {"kda_attention": KimiDeltaAttention, "latent_attention": LatentAttention}
+
+
 class Block(nn.Module):
     cfg: LagunaConfig
     layer: int
@@ -578,7 +556,8 @@ class Block(nn.Module):
         c = self.cfg
         with jax.named_scope("block_norm"):
             h = RMSNorm(c.rms_norm_eps, c.hidden_size, name="attn_norm")(x)
-        a, attended = Attention(c, self.layer, name="attn")(h)
+        mixer = MIXERS.get(c.layer_types[self.layer], Attention)
+        a, attended = mixer(c, self.layer, name="attn")(h)
         with jax.named_scope("residual_add"):
             x = x + a
         with jax.named_scope("block_norm"):
@@ -642,9 +621,11 @@ class LagunaLM(nn.Module):
                   for k in (per_layer[0] if per_layer else ())}
         # over the indexed layers: the pairs attended to, of the causal ones, and
         # the queries whose threshold tied (``attn/selected_share`` is the ratio
-        # of the first two, taken where the sums over clients and rounds end)
-        totals.update({f"attn/{k}": jnp.sum(jnp.stack([p[k] for p in attended]))
-                       for k in (attended[0] if attended else ())})
+        # of the first two, taken where the sums over clients and rounds end);
+        # a recurrent layer's counters come named (``kda/*``)
+        totals.update({k if "/" in k else f"attn/{k}":
+                       jnp.sum(jnp.stack([p[k] for p in attended if k in p]))
+                       for k in dict.fromkeys(k for p in attended for k in p)})
         scale = RMSNorm(c.rms_norm_eps, c.hidden_size, name="final_norm").scale()
         head = _Kernel((c.hidden_size, c.vocab_held), c.initializer_range, name="lm_head")()
 

@@ -185,8 +185,11 @@ def mqa_kernel(head, group, blk, interpret, residuals_named=True):
 def banded_attention(q, k, v, *, window=None, block_length=0, residuals_named=True):
     """Causal grouped-query attention that never forms ``[T, T]`` scores.
 
-    ``q`` ``[B, T, H, d]`` (already scaled), ``k``, ``v`` ``[B, T, KV, d]``
-    -> ``[B, T, H, d]``. Query head ``j`` reads KV head ``j // (H / KV)``:
+    ``q`` ``[B, T, H, d]`` (already scaled), ``k`` ``[B, T, KV, d]``, ``v``
+    ``[B, T, KV, d_v]`` -> ``[B, T, H, d_v]`` (``d_v`` may be under ``d``:
+    the library's kernels size their value blocks and output by ``v`` alone,
+    in the forward and both backward kernels). Query head ``j`` reads KV
+    head ``j // (H / KV)``:
     each KV head is one multi-query kernel call over its group, so K and V
     are never repeated in memory. ``window`` keeps ``t - window < s <= t``
     (``None``: all ``s <= t``); blocks wholly outside that band are skipped
@@ -220,7 +223,7 @@ def banded_attention(q, k, v, *, window=None, block_length=0, residuals_named=Tr
     q = q.reshape(B, T, KV, group, d).transpose(0, 2, 3, 1, 4)      # [B, KV, group, T, d]
     k, v = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)         # [B, KV, T, d]
     o = _typed_call(jax.vmap(jax.vmap(kernel)))(q, k, v)
-    return o.transpose(0, 3, 1, 2, 4).reshape(B, T, H, d)
+    return o.transpose(0, 3, 1, 2, 4).reshape(B, T, H, v.shape[-1])
 
 
 def grouped_product(rows, w, sizes, tiling=GMM_TILING):

@@ -203,6 +203,30 @@ MODEL_SCOPES: Tuple[Tuple[str, str], ...] = (
                       "output, so it has no backward pass"),
 )
 
+# Model scopes opened since the three closing metrics were written
+# (benchmark/layers/model.unnamed_s_per_round.json,
+# model.outside_client_grad_s_per_round.json, round.nameless_s_per_round.json
+# name every scope of MODEL_SCOPES in their patterns, and a program change
+# does not edit the benchmark's files): the recurrent and the latent mixers'.
+# Each has a per-layer metric of its own. A stopgap: the benchmark change
+# that appends these names to those three patterns moves them into
+# MODEL_SCOPES and deletes this tuple (ROADMAP.md, D20). Until
+# then the closing metrics, which no recurrent cell reports, read these ops
+# as named by ``client_grad`` alone; read the two tuples together
+# (``MODEL_SCOPES + LATER_MODEL_SCOPES``) and add no reader that keeps them
+# apart.
+LATER_MODEL_SCOPES: Tuple[Tuple[str, str], ...] = (
+    ("attn_kda", "the chunked gated delta rule of a KDA layer: the pairs of "
+                 "positions factored at a boundary, the triangular solve, the "
+                 "products outside the state and the scan that carries the "
+                 "state from chunk to chunk"),
+    ("kda_prep", "a KDA layer's work around the rule: the short "
+                 "convolutions and SiLU, the L2 norms, the decay gate, beta "
+                 "and the gated output norm"),
+    ("attn_latent", "the attention kernels of a latent-attention layer "
+                    "(keys and values decompressed from the latent)"),
+)
+
 # Priority order for exclusive assignment (idle is always the remainder).
 # Exposed collective first — it is the scarce signal the overlap work
 # (PR 16) exists to shrink; then the post-dispatch phases, then the

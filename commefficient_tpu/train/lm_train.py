@@ -5,7 +5,9 @@ same runner, session and sampler: a decoder-only LM (``--model laguna_xs2``:
 one chip's share of Laguna-XS.2, ``models/laguna.py``; ``keye_vl2``: one
 chip's share of Keye-VL-2.0-30B-A3B's language model, ``models/keye.py``;
 ``sdar_30b_a3b``: one chip's share of SDAR-30B-A3B-Chat, ``models/sdar.py``;
-``laguna_tiny`` / ``keye_tiny`` / ``sdar_tiny`` for the CPU) trained on
+``kimi_linear_48b``: one chip's share of Kimi-Linear-48B-A3B-Instruct,
+``models/kimi_linear.py``; ``laguna_tiny`` / ``keye_tiny`` / ``sdar_tiny`` /
+``kimi_linear_tiny`` for the CPU) trained on
 ``--dataset_name fedtext`` (``data/fedtext.py``: packed documents, one shard
 of rows per client), eval reporting nll -> perplexity. The preset decides
 the objective: next-token loss, or for a block-diffusion preset (its
@@ -35,14 +37,14 @@ import numpy as np
 from commefficient_tpu.data import FedSampler, load_fed_text
 from commefficient_tpu.data.fedtext import BlockNoise
 from commefficient_tpu.models import block_diffusion_lm_loss, causal_lm_loss
-from commefficient_tpu.models import keye, laguna, sdar
+from commefficient_tpu.models import keye, kimi_linear, laguna, sdar
 from commefficient_tpu.models.laguna import LagunaLM
 from commefficient_tpu.models.losses import IGNORE_INDEX, model_dtype
 from commefficient_tpu.parallel import FederatedSession
 from commefficient_tpu.utils import Config, MetricsWriter, TableLogger, parse_args
 from commefficient_tpu.utils.logging import make_logdir
 
-PRESETS = {**laguna.PRESETS, **keye.PRESETS, **sdar.PRESETS}
+PRESETS = {**laguna.PRESETS, **keye.PRESETS, **sdar.PRESETS, **kimi_linear.PRESETS}
 DEFAULTS = dict(model="laguna_xs2", dataset_name="fedtext", num_clients=64,
                 local_batch_size=2, max_seq_len=2048, max_grad_norm=1.0, lr_scale=0.01)
 
@@ -110,7 +112,7 @@ class _LmHooks:
 
     def new_accumulator(self):
         return {"loss": 0.0, "held": 0.0, "dropped": 0.0, "selected": 0.0, "causal": 0.0,
-                "ties": 0.0, "masked": 0.0, "labelled": 0.0}
+                "ties": 0.0, "masked": 0.0, "labelled": 0.0, "kept": 0.0, "slots": 0.0}
 
     def accumulate(self, acc, loss, metrics):
         acc["loss"] += loss
@@ -123,6 +125,9 @@ class _LmHooks:
         # a block-diffusion model's (absent otherwise)
         acc["masked"] += float(metrics.get("diffusion/masked_tokens", 0.0))
         acc["labelled"] += float(metrics.get("diffusion/labelled_tokens", 0.0))
+        # a recurrent model's (absent otherwise)
+        acc["kept"] += float(metrics.get("kda/state_kept", 0.0))
+        acc["slots"] += float(metrics.get("kda/state_slots", 0.0))
 
     def evaluate(self):
         return evaluate_ppl(self.session, self.test_ds, self.eval_batch_size)
@@ -140,6 +145,9 @@ class _LmHooks:
         if acc["labelled"]:
             # the labelled tokens the rounds' noise masked: the mean t, 0.5 under U[1e-3, 1]
             row.update(masked_share=acc["masked"] / acc["labelled"])
+        if acc["slots"]:
+            # the mean share of a key channel's state that survives a chunk's decay
+            row.update(kda_state_kept_share=acc["kept"] / acc["slots"])
         return {**row, "val_nll": val["nll"], "val_ppl": val["ppl"],
                 "train_time": train_time, "val_time": val_time}
 

@@ -44,7 +44,9 @@ sys.path.insert(0, ROOT)
 from benchmark import reduce  # noqa: E402
 from benchmark.run import RUN_AHEAD  # noqa: E402
 from benchmark.layers.scope_self_time import self_seconds  # noqa: E402
-from commefficient_tpu.telemetry.trace import MODEL_SCOPES, ROUND_SCOPES  # noqa: E402
+from commefficient_tpu.telemetry.trace import (  # noqa: E402
+    LATER_MODEL_SCOPES, MODEL_SCOPES, ROUND_SCOPES)
+MODEL_SCOPES = MODEL_SCOPES + LATER_MODEL_SCOPES   # every model name the look reads
 
 WHOLE_WORDS = {"encode", "embed"}   # as the benchmark's patterns have them
 NAME_RX = {n: re.compile(rf"\b{n}\b" if n in WHOLE_WORDS else n)

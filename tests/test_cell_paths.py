@@ -55,6 +55,7 @@ CELL_PATHS = {
     "laguna_uncompressed": dict(_COMMON),
     "keye_uncompressed": dict(_COMMON),
     "sdar_uncompressed": dict(_COMMON),
+    "kimi_linear_uncompressed": dict(_COMMON),
 }
 
 

@@ -20,11 +20,23 @@ kernel once a layer and lowers to another text (its loss and its gradients
 are the bits they were). ``laguna_xs2`` opts out for its memory
 (``attn_residuals_kept`` off); ``laguna_tiny`` has no such reason and keeps
 the decoder's default, and with the preset's setting it still lowers to the
-old text, which the file keeps as ``tiny_recomputed_lowered_sha``."""
+old text, which the file keeps as ``tiny_recomputed_lowered_sha``.
+
+The gradients' two pins are taken in a child process held to one
+core, single-threaded Eigen and AVX2 code (``PINNED_CPU``): XLA's CPU backend
+splits a reduction by the cores the process may run on and the vector
+instructions it compiles for, so one lowered text gave three ``laguna_tiny``
+gradients on 1, 4 and 8 cores of one machine (and another on the machine the
+file was written on), while the loss's bits and the lowered text held. Held
+so, the pin is the same wherever the suite runs; it still holds every
+gradient to the last bit. The file also keeps the trees of Kimi-Linear's
+presets."""
 
 import hashlib
 import json
 import os
+import subprocess
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -34,11 +46,16 @@ import pytest
 from benchmark import weights
 from commefficient_tpu.data import load_fed_text
 from commefficient_tpu.models.keye import keye_tiny, keye_vl2
+from commefficient_tpu.models.kimi_linear import kimi_linear_48b, kimi_linear_tiny
 from commefficient_tpu.models.laguna import LagunaLM, laguna_tiny, laguna_xs2
 from commefficient_tpu.models.sdar import sdar_30b_a3b, sdar_tiny
 from commefficient_tpu.models.losses import causal_lm_loss
 
-GOLDEN = os.path.join(os.path.dirname(os.path.abspath(__file__)), "golden", "laguna_presets.json")
+HERE = os.path.dirname(os.path.abspath(__file__))
+GOLDEN = os.path.join(HERE, "golden", "laguna_presets.json")
+# what the gradients' pins are computed under (module docstring)
+PINNED_CPU = {"JAX_PLATFORMS": "cpu",
+              "XLA_FLAGS": "--xla_cpu_multi_thread_eigen=false --xla_cpu_max_isa=AVX2"}
 
 
 def _sha(*chunks):
@@ -66,6 +83,25 @@ def _tiny_round(preset=laguna_tiny, prefix=""):
             prefix + "tiny_lowered_sha": _sha(f.lower(params, batch).as_text().encode())}
 
 
+def _pinned_grad_shas():
+    """Both tiny rounds' ``*tiny_grad_sha``, from this file run as a child
+    on one core under ``PINNED_CPU``."""
+    env = {**os.environ, **PINNED_CPU,
+           "PYTHONPATH": os.pathsep.join([os.path.dirname(HERE), os.environ.get("PYTHONPATH", "")])}
+    # the core is set before the child imports JAX, which sizes its threads by it
+    start = (f"import os, runpy, sys; os.sched_setaffinity(0, {{{min(os.sched_getaffinity(0))}}}); "
+             f"sys.argv = [{os.path.abspath(__file__)!r}, '--grad-shas']; "
+             f"runpy.run_path(sys.argv[0], run_name='__main__')")
+    done = subprocess.run([sys.executable, "-c", start], env=env, capture_output=True, text=True,
+                          timeout=900, check=True)
+    return json.loads(done.stdout.strip().splitlines()[-1])
+
+
+def _grad_shas():
+    rounds = {**_tiny_round(), **_tiny_round(keye_tiny, "keye_")}
+    return {k: v for k, v in rounds.items() if k.endswith("grad_sha")}
+
+
 def _fedtext(**kw):
     train, test = load_fed_text(num_clients=4, seq_len=256, vocab=512, seed=7, **kw)
     return _sha(train.data["input_ids"].tobytes(), train.data["lm_labels"].tobytes(),
@@ -79,7 +115,8 @@ def golden():
 
 
 PRESETS = {"laguna_xs2": laguna_xs2, "laguna_tiny": laguna_tiny, "keye_vl2": keye_vl2,
-           "keye_tiny": keye_tiny, "sdar_30b_a3b": sdar_30b_a3b, "sdar_tiny": sdar_tiny}
+           "keye_tiny": keye_tiny, "sdar_30b_a3b": sdar_30b_a3b, "sdar_tiny": sdar_tiny,
+           "kimi_linear_48b": kimi_linear_48b, "kimi_linear_tiny": kimi_linear_tiny}
 
 
 @pytest.mark.parametrize("name", PRESETS)
@@ -116,7 +153,8 @@ def _laguna_tiny_as_xs2(**kw):
 def tiny_rounds():
     recomputed = _tiny_round(_laguna_tiny_as_xs2)
     return {**_tiny_round(), **_tiny_round(keye_tiny, "keye_"),
-            "tiny_recomputed_lowered_sha": recomputed["tiny_lowered_sha"]}
+            "tiny_recomputed_lowered_sha": recomputed["tiny_lowered_sha"],
+            **_pinned_grad_shas()}
 
 
 @pytest.mark.parametrize("what", [p + w for p in ("", "keye_") for w in (
@@ -146,8 +184,11 @@ def test_the_entry_passes_the_median_and_defaults_to_300():
 
 
 if __name__ == "__main__":
-    print(json.dumps({**{name: _tree(preset) for name, preset in PRESETS.items()},
-                      **_tiny_round(), **_tiny_round(keye_tiny, "keye_"),
-                      "tiny_recomputed_lowered_sha":
-                          _tiny_round(_laguna_tiny_as_xs2)["tiny_lowered_sha"],
-                      "fedtext_sha": _fedtext()}))
+    if sys.argv[1:] == ["--grad-shas"]:
+        print(json.dumps(_grad_shas()))
+    else:
+        print(json.dumps({**{name: _tree(preset) for name, preset in PRESETS.items()},
+                          **_tiny_round(), **_tiny_round(keye_tiny, "keye_"),
+                          "tiny_recomputed_lowered_sha":
+                              _tiny_round(_laguna_tiny_as_xs2)["tiny_lowered_sha"],
+                          "fedtext_sha": _fedtext(), **_pinned_grad_shas()}))

@@ -20,11 +20,11 @@ from test_device_data import _mlp_loss, _toy_ds, augment_batch
 import commefficient_tpu
 from commefficient_tpu.data import FedSampler
 from commefficient_tpu.parallel import FederatedSession, make_mesh
-from commefficient_tpu.telemetry.trace import MODEL_SCOPES, ROUND_SCOPES
+from commefficient_tpu.telemetry.trace import LATER_MODEL_SCOPES, MODEL_SCOPES, ROUND_SCOPES
 from commefficient_tpu.utils.config import Config
 
 NAMES = tuple(name for name, _ in ROUND_SCOPES)
-MODEL_NAMES = tuple(name for name, _ in MODEL_SCOPES)
+MODEL_NAMES = tuple(name for name, _ in MODEL_SCOPES + LATER_MODEL_SCOPES)
 BASE = dict(num_clients=16, num_workers=8, num_devices=1, local_batch_size=4,
             weight_decay=5e-4, max_grad_norm=1.0, seed=1)
 MODES = {
@@ -190,12 +190,19 @@ def sdar_op_names():
     return _lm_op_names("sdar_tiny")
 
 
+@pytest.fixture(scope="module")
+def kimi_op_names():
+    return _lm_op_names("kimi_linear_tiny")
+
+
 # the scopes only an indexed-attention model opens, and the ones it never does;
 # the same for a block-diffusion model
 INDEXED_ONLY = ("attn_index", "attn_select", "attn_sparse")
 DIFFUSION_ONLY = ("attn_blockdiff", "diffusion_streams", "diffusion_loss")
-NEVER_INDEXED = ("attn_full", "attn_window", "mlp_dense") + DIFFUSION_ONLY
-NEVER_DIFFUSION = ("attn_full", "attn_window", "mlp_dense") + INDEXED_ONLY
+RECURRENT_ONLY = ("attn_kda", "kda_prep", "attn_latent")
+NEVER_INDEXED = ("attn_full", "attn_window", "mlp_dense") + DIFFUSION_ONLY + RECURRENT_ONLY
+NEVER_DIFFUSION = ("attn_full", "attn_window", "mlp_dense") + INDEXED_ONLY + RECURRENT_ONLY
+NEVER_RECURRENT = ("attn_full", "attn_window") + INDEXED_ONLY + DIFFUSION_ONLY
 
 
 # the one model scope with no backward pass (the gradient is taken with
@@ -229,7 +236,7 @@ def _the_expert_loop_wraps(names, scope):
 
 
 @pytest.mark.parametrize("scope", [n for n in MODEL_NAMES
-                                   if n not in INDEXED_ONLY + DIFFUSION_ONLY])
+                                   if n not in INDEXED_ONLY + DIFFUSION_ONLY + RECURRENT_ONLY])
 def test_model_scopes_sit_under_client_grad_forward_and_backward(laguna_op_names, scope):
     """Each is in the round the LM entry compiles, always inside
     ``client_grad``, once wrapped by ``jvp(`` alone and once by
@@ -289,7 +296,7 @@ def test_model_scopes_of_a_block_diffusion_model(sdar_op_names, scope):
         assert all("diffusion_loss/" in n for n in under if n.startswith("jit("))
 
 
-@pytest.mark.parametrize("scope", INDEXED_ONLY + DIFFUSION_ONLY)
+@pytest.mark.parametrize("scope", INDEXED_ONLY + DIFFUSION_ONLY + RECURRENT_ONLY)
 def test_laguna_opens_no_scope_of_the_index_or_of_block_diffusion(laguna_op_names, scope):
     assert not _under(laguna_op_names, scope)
 
@@ -311,7 +318,28 @@ def test_an_indexed_model_opens_none_of_lagunas_attention_kinds(keye_op_names, s
     assert not _under(keye_op_names, scope)
 
 
-@pytest.mark.parametrize("names", ["laguna_op_names", "keye_op_names", "sdar_op_names"])
+@pytest.mark.parametrize("scope", [n for n in MODEL_NAMES if n not in NEVER_RECURRENT])
+def test_model_scopes_of_a_recurrent_model(kimi_op_names, scope):
+    """The same at ``kimi_linear_tiny``: KDA's two scopes and the latent
+    layer's kernels beside the projections', the norms', the dense and the
+    expert layer's and the head's, each forward and backward (the rule and
+    its preparation are recomputed inside the block's backward pass)."""
+    under = _under(kimi_op_names, scope)
+    assert under
+    assert not [n for n in under if "client_grad" not in n and n.startswith("jit(")
+                and not n.startswith(f"jit(wrapped)/{scope}/")]
+    assert any("transpose(" not in n for n in under)
+    assert any("transpose(" in n for n in under) == (scope not in NO_BACKWARD)
+    _the_expert_loop_wraps(kimi_op_names, scope)
+
+
+@pytest.mark.parametrize("scope", NEVER_RECURRENT)
+def test_a_recurrent_model_opens_no_other_attention_kind(kimi_op_names, scope):
+    assert not _under(kimi_op_names, scope)
+
+
+@pytest.mark.parametrize("names", ["laguna_op_names", "keye_op_names", "sdar_op_names",
+                                   "kimi_op_names"])
 def test_the_round_scopes_still_close_on_the_lm_round(names, request):
     rx = re.compile("|".join(NAMES))
     bare = {n for n in request.getfixturevalue(names)
@@ -338,7 +366,8 @@ UNNAMED_UNDER_CLIENT_GRAD = {
 }
 
 
-@pytest.mark.parametrize("names", ["laguna_op_names", "keye_op_names", "sdar_op_names"])
+@pytest.mark.parametrize("names", ["laguna_op_names", "keye_op_names", "sdar_op_names",
+                                   "kimi_op_names"])
 def test_the_model_scopes_close_on_client_grad(names, request):
     """Every op of the lowered tiny round whose name holds ``client_grad``
     holds a name of ``MODEL_SCOPES`` (``embed`` as a whole word: the module is
