@@ -216,10 +216,10 @@ MODEL_SCOPES: Tuple[Tuple[str, str], ...] = (
 # (``MODEL_SCOPES + LATER_MODEL_SCOPES``) and add no reader that keeps them
 # apart.
 LATER_MODEL_SCOPES: Tuple[Tuple[str, str], ...] = (
-    ("attn_kda", "the chunked gated delta rule of a KDA layer: the pairs of "
-                 "positions factored at a boundary, the triangular solve, the "
-                 "products outside the state and the scan that carries the "
-                 "state from chunk to chunk"),
+    ("attn_kda", "the chunked gated delta rule of a KDA layer: its two "
+                 "kernels (kda_fwd, kda_bwd; ops/pallas/delta_rule.py), the "
+                 "decay summed within each chunk and the head-major "
+                 "transposes around them"),
     ("kda_prep", "a KDA layer's work around the rule: the short "
                  "convolutions and SiLU, the L2 norms, the decay gate, beta "
                  "and the gated output norm"),

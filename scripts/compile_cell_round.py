@@ -5,7 +5,8 @@ fit beside its state, before a chip minute is spent. Beside it, how many
 times the compiled round calls each attention kernel, this repo's
 (`indexed_*`) and the library's (`splash_mqa_*`): a forward kernel
 (`indexed_fwd`, `splash_mqa_fwd*`) once a layer where the block's `remat`
-keeps its residuals, twice where it recomputes them.
+keeps its residuals, twice where it recomputes them; and each delta-rule
+kernel (`kda_fwd` twice a KDA layer, `kda_bwd` once).
 
     JAX_PLATFORMS=cpu python scripts/compile_cell_round.py laguna_uncompressed [--num_workers 2 ...]
 
@@ -34,7 +35,8 @@ from jax.sharding import NamedSharding  # noqa: E402
 from jax.sharding import PartitionSpec as P  # noqa: E402
 
 from benchmark import run  # noqa: E402
-from commefficient_tpu.ops.pallas import indexed_attention, library_kernels  # noqa: E402
+from commefficient_tpu.ops.pallas import (  # noqa: E402
+    delta_rule, indexed_attention, library_kernels)
 from commefficient_tpu.ops.param_utils import ravel_params  # noqa: E402
 from commefficient_tpu.parallel.mesh import make_mesh  # noqa: E402
 from commefficient_tpu.parallel.round import build_round_fn, init_state  # noqa: E402
@@ -45,6 +47,7 @@ def main():
     jax.config.update("jax_enable_compilation_cache", False)
     # the default backend here is the CPU: take the chip's branch
     library_kernels.kernels_interpreted = indexed_attention.kernels_interpreted = lambda: False
+    delta_rule.kernels_interpreted = lambda: False
     cell = run.load_cell(sys.argv[1])
     cfg = lm_train.parse_args(
         cell["config_file"]["argv"] + cell["traffic_file"]["argv"]
@@ -74,9 +77,13 @@ def main():
     calls = re.findall(
         r'^\s*%?((?:indexed|splash_mqa)_\w+?)[.\d]* = .*custom_call_target="tpu_custom_call"',
         text, re.M)
+    rule = re.findall(
+        r'^\s*%?\w*?(kda_(?:fwd|bwd))_*[.\d]* = .*custom_call_target="tpu_custom_call"',
+        text, re.M)
     print({"cell": cell["name"], "D": int(flat.size), "compile_s": round(time.time() - t0, 1),
            "kernels": text.count("tpu_custom_call"),
            "attention_calls": {name: calls.count(name) for name in sorted(set(calls))},
+           "delta_rule_calls": {name: rule.count(name) for name in sorted(set(rule))},
            # a buffer with two sequence-length extents, whatever else it holds
            "square_buffers": sorted(set(re.findall(
                rf"\[[\d,]*\b(?:{T},{T}|{2 * T},{2 * T})\b[\d,]*\]", text))),

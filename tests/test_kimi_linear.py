@@ -22,7 +22,7 @@ import pytest
 from benchmark import flops_kimi, weights
 from benchmark.reference import kimi_linear as ref
 from commefficient_tpu.models import kimi_linear
-from commefficient_tpu.models.kda import chunked_delta_rule
+from commefficient_tpu.models.kda import delta_rule
 from commefficient_tpu.models.kimi_linear import kimi_linear_48b, kimi_linear_tiny
 from commefficient_tpu.models.laguna import Block, LagunaLM
 from commefficient_tpu.models.latent_attention import LatentAttention
@@ -111,7 +111,7 @@ DECAYS = ["near_zero", "gated", "minus_20"]
 @pytest.mark.parametrize("t,chunk", [(128, 64), (100, 16), (70, 64)], ids=lambda x: str(x))
 def test_the_chunked_rule_is_the_recurrence(decay, t, chunk):
     q, k, v, g, beta = _rule_inputs(t, decay)
-    o, counters = jax.jit(lambda *a: chunked_delta_rule(*a, chunk=chunk, dtype=jnp.float32))(
+    o, counters = jax.jit(lambda *a: delta_rule(*a, chunk=chunk))(
         q, k, v, g, beta)
     want = _recurrence(q, k, v, g, beta)
     assert np.all(np.isfinite(o)) and o.shape == want.shape
@@ -131,8 +131,8 @@ def test_the_chunked_rules_gradients_are_the_recurrences(decay):
     def loss(rule):
         return lambda *a: jnp.sum(rule(*a) * ct)
 
-    chunked = jax.jit(jax.grad(loss(lambda *a: chunked_delta_rule(
-        *a, chunk=16, dtype=jnp.float32)[0]), argnums=(0, 1, 2, 3, 4)))(q, k, v, g, beta)
+    chunked = jax.jit(jax.grad(loss(lambda *a: delta_rule(*a, chunk=16)[0]),
+                               argnums=(0, 1, 2, 3, 4)))(q, k, v, g, beta)
     plain = jax.grad(loss(_recurrence), argnums=(0, 1, 2, 3, 4))(q, k, v, g, beta)
     for name, a, b in zip("qkvgb", chunked, plain):
         assert np.all(np.isfinite(a)), name
@@ -140,28 +140,121 @@ def test_the_chunked_rules_gradients_are_the_recurrences(decay):
                                    err_msg=name)
 
 
+def _exp_arguments(fn, *args):
+    """The largest argument of every ``exp`` ``fn`` evaluates on ``args``,
+    in the order they run (nested jaxprs, ``jnp``'s own jitted helpers too)."""
+    largest = []
+
+    def run(jaxpr, consts, *vals):
+        env = dict(zip(jaxpr.constvars, consts))
+        env.update(zip(jaxpr.invars, vals))
+        read = lambda v: v.val if hasattr(v, "val") else env[v]  # noqa: E731
+        for eqn in jaxpr.eqns:
+            ins = [read(v) for v in eqn.invars]
+            inner = eqn.params.get("jaxpr") or eqn.params.get("call_jaxpr")
+            if inner is not None:
+                closed = inner if hasattr(inner, "jaxpr") else None
+                outs = run(closed.jaxpr if closed else inner, closed.consts if closed else [], *ins)
+            else:
+                if eqn.primitive.name == "exp":
+                    largest.append(float(jnp.max(ins[0])))
+                outs = eqn.primitive.bind(*ins, **eqn.params)
+                outs = outs if eqn.primitive.multiple_results else [outs]
+            env.update(zip(eqn.outvars, outs))
+        return [read(v) for v in jaxpr.outvars]
+
+    closed = jax.make_jaxpr(fn)(*args)
+    run(closed.jaxpr, closed.consts, *args)
+    return largest
+
+
 def test_no_exponent_of_the_rule_is_positive():
     """At ``-20`` a position a chunk's decay is ``-1,280``: a product split
-    at the chunk's start would read ``exp(1,280)``. Every ``exp`` the rule
-    lowers takes an argument the decays make ``<= 0``: none overflows, and
-    the outputs, the state's share and every gradient are finite."""
+    at the chunk's start would read ``exp(1,280)``. Every ``exp`` the
+    kernels' chunk functions evaluate, forward and backward, takes an
+    argument ``<= 0``, and the rule's outputs, the state's share and every
+    gradient are finite."""
+    from commefficient_tpu.ops.pallas import delta_rule as kernels
+
     q, k, v, g, beta = _rule_inputs(128, "minus_20")
-    jaxpr = jax.make_jaxpr(lambda g: chunked_delta_rule(q, k, v, g, beta, chunk=64,
-                                                        dtype=jnp.float32))(g)
-    exps = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "exp"]
-    assert exps
-    grads = jax.grad(lambda *a: jnp.sum(chunked_delta_rule(*a, chunk=64, dtype=jnp.float32)[0]),
-                     argnums=(0, 1, 2, 3, 4))(q, k, v, g, beta)
-    assert all(np.all(np.isfinite(x)) for x in grads)
+    one = lambda x: x[0, :64, 0]  # noqa: E731          the first chunk of one head
+    chunk = (one(q), one(k), one(v), jnp.cumsum(one(g), 0), one(beta)[:, None])
+    draws = jax.random.normal(jax.random.key(3), (3, 16, 16))
+    state, dO = draws[0][:8], jax.random.normal(jax.random.key(4), (64, 8))
+    fwd = _exp_arguments(kernels._fwd_chunk, *chunk, state)
+    bwd = _exp_arguments(kernels._bwd_chunk, *chunk, state, dO, draws[1][:8])
+    assert len(fwd) > 3 and len(bwd) > 3
+    assert max(fwd + bwd) <= 0.0
+    rule = lambda *a: delta_rule(*a, chunk=64)  # noqa: E731
+    o, counters = rule(q, k, v, g, beta)
+    grads = jax.grad(lambda *a: jnp.sum(rule(*a)[0]), argnums=(0, 1, 2, 3, 4))(q, k, v, g, beta)
+    assert all(np.all(np.isfinite(x)) for x in (o, counters["kda/state_kept"], *grads))
 
 
 def test_the_rule_under_bfloat16_operands_stays_near_the_recurrence():
     q, k, v, g, beta = _rule_inputs(128, "gated")
-    o, _ = chunked_delta_rule(q.astype(jnp.bfloat16), k.astype(jnp.bfloat16),
-                              v.astype(jnp.bfloat16), g, beta, chunk=64, dtype=jnp.bfloat16)
+    o, _ = delta_rule(q.astype(jnp.bfloat16), k.astype(jnp.bfloat16), v.astype(jnp.bfloat16),
+                      g, beta, chunk=64)
     want = _recurrence(q, k, v, g, beta)
     err = float(jnp.linalg.norm(o - want) / jnp.linalg.norm(want))
     assert 1e-5 < err < 3e-2
+
+
+def _restarted(q, k, v, g, beta, chunk):
+    """The recurrence with the state dropped at every chunk's start: each
+    chunk a row of its own."""
+    b, t = q.shape[:2]
+    split = lambda x: x.reshape(b * (t // chunk), chunk, *x.shape[2:])  # noqa: E731
+    return _recurrence(*map(split, (q, k, v, g, beta))).reshape(b, t, *v.shape[2:])
+
+
+def test_the_state_carried_across_chunks_is_the_recurrences():
+    """Under a decay near 0 a chunk keeps almost all of the state the last
+    one left: the rule reads the recurrence, and a rule that restarted the
+    state at each chunk would miss it by far more than the tolerance."""
+    q, k, v, g, beta = _rule_inputs(128, "near_zero")
+    o, _ = jax.jit(lambda *a: delta_rule(*a, chunk=16))(q, k, v, g, beta)
+    want = _recurrence(q, k, v, g, beta)
+    tol = 2e-5 * float(jnp.max(jnp.abs(want)))
+    np.testing.assert_allclose(o, want, atol=tol)
+    dropped = float(jnp.max(jnp.abs(_restarted(q, k, v, g, beta, 16) - want)))
+    assert dropped > 1000 * tol
+
+
+def test_a_row_that_is_not_whole_chunks_and_its_gradients():
+    """70 positions in chunks of 64: the second chunk is padded with positions
+    that write and decay nothing, in the output and in every gradient."""
+    q, k, v, g, beta = _rule_inputs(70, "gated", seed=2)
+    ct = jax.random.normal(jax.random.key(8), v.shape)
+    loss = lambda rule: lambda *a: jnp.sum(rule(*a) * ct)  # noqa: E731
+    o, _ = delta_rule(q, k, v, g, beta, chunk=64)
+    want = _recurrence(q, k, v, g, beta)
+    np.testing.assert_allclose(o, want, atol=2e-5 * float(jnp.max(jnp.abs(want))))
+    got = jax.grad(loss(lambda *a: delta_rule(*a, chunk=64)[0]), argnums=(0, 1, 2, 3, 4))(
+        q, k, v, g, beta)
+    plain = jax.grad(loss(_recurrence), argnums=(0, 1, 2, 3, 4))(q, k, v, g, beta)
+    for name, a, b in zip("qkvgb", got, plain):
+        assert a.shape == b.shape, name
+        np.testing.assert_allclose(a, b, atol=3e-5 * float(jnp.max(jnp.abs(b))) + 1e-12,
+                                   err_msg=name)
+
+
+def test_heads_a_grid_step_leave_the_rule_as_it_is(monkeypatch):
+    """The chip runs several heads a grid step (the CPU one): the output and
+    the five gradients do not depend on how many."""
+    from commefficient_tpu.ops.pallas import delta_rule as kernels
+
+    q, k, v, g, beta = _rule_inputs(64, "gated")
+    ct = jax.random.normal(jax.random.key(9), v.shape)
+
+    def run():
+        loss = lambda *a: jnp.sum(delta_rule(*a, chunk=16)[0] * ct)  # noqa: E731
+        return jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4)))(q, k, v, g, beta)
+
+    one = run()
+    monkeypatch.setattr(kernels, "_heads_per_step", lambda N: 2)
+    for a, b in zip(jax.tree.leaves(run()), jax.tree.leaves(one)):
+        np.testing.assert_array_equal(a, b)
 
 
 # ---- latent attention ----------------------------------------------------------------------
@@ -502,3 +595,29 @@ def test_the_entry_reports_the_state_kept_share(tiny):
     assert "kda_state_kept_share" not in hooks.epoch_row(
         epoch=0, lr=0.1, acc=plain, val={"nll": 1.0, "ppl": 2.7}, train_time=1.0,
         val_time=1.0, steps_per_epoch=1)
+
+
+def test_inside_a_shard_map_the_rule_is_the_same():
+    """Inside the round's ``shard_map``, on operands that vary over the mesh,
+    the CPU runs the kernels' own chunk functions as a scan (Pallas's
+    interpreter is refused there): the output and the five gradients are
+    the interpreted kernels'."""
+    from jax.sharding import Mesh
+    from jax.sharding import PartitionSpec as P
+
+    args = _rule_inputs(100, "gated")
+    ct = jax.random.normal(jax.random.key(9), args[2].shape)
+
+    def grads(*a):
+        loss = lambda *a: jnp.sum(delta_rule(*a, chunk=16)[0] * ct)  # noqa: E731
+        return jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4))(*a)
+
+    mesh = Mesh(np.array(jax.devices()[:1]), ("workers",))
+    inside = jax.jit(jax.shard_map(
+        lambda *a: jax.tree.map(lambda x: x[None], grads(*(x[0] for x in a))),
+        mesh=mesh, in_specs=(P("workers"),) * 5, out_specs=P("workers")))
+    stacked = [x[None] for x in args]
+    assert "pallas_call" not in str(jax.make_jaxpr(inside)(*stacked))
+    assert "pallas_call" in str(jax.make_jaxpr(grads)(*args))
+    for a, b in zip(jax.tree.leaves(inside(*stacked)), jax.tree.leaves(jax.jit(grads)(*args))):
+        np.testing.assert_allclose(a[0], b, rtol=2e-6, atol=1e-7 * float(jnp.max(jnp.abs(b))))

@@ -1,5 +1,6 @@
-"""The Laguna cell's two library kernels and the Keye cell's four indexed-
-attention kernels compiled for a described v5e chip
+"""The Laguna cell's two library kernels, the Keye cell's four indexed-
+attention kernels and the Kimi cell's two delta-rule kernels compiled for a
+described v5e chip
 (no chip attached, nothing runs): at the published widths, inside a
 ``shard_map`` that checks varying axes, under ``vmap`` over clients, forward
 and backward, and how often a forward attention kernel is called under the
@@ -18,7 +19,8 @@ import pytest
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from commefficient_tpu.ops.pallas import indexed_attention, library_kernels
+from commefficient_tpu.models import kda
+from commefficient_tpu.ops.pallas import delta_rule, indexed_attention, library_kernels
 
 
 @pytest.fixture(scope="module")
@@ -37,6 +39,7 @@ def compiled_kernels(monkeypatch):
     # the default backend here is the CPU: take the chip's branch
     monkeypatch.setattr(library_kernels, "kernels_interpreted", lambda: False)
     monkeypatch.setattr(indexed_attention, "kernels_interpreted", lambda: False)
+    monkeypatch.setattr(delta_rule, "kernels_interpreted", lambda: False)
     library_kernels._attention_kernel.cache_clear()
     yield
     library_kernels._attention_kernel.cache_clear()
@@ -182,3 +185,85 @@ def test_indexed_attention_compiles_at_the_published_widths(mesh, compiled_kerne
                                    "indexed_dq": 1, "indexed_dkv": 1}
     assert text.count("tpu_custom_call") == 3 + forwards
     assert "16384,16384" not in text                     # no [T, T] operand, per head or whole
+
+
+# ---- Kimi Delta Attention's rule --------------------------------------------------------------
+
+KDA_SHAPE = dict(chunk=64, T=4096, heads=8, d=128, clients=2, rows=1)
+
+
+def _kda_structs():
+    c = KDA_SHAPE
+    x = jax.ShapeDtypeStruct((c["clients"], c["rows"], c["T"], c["heads"], c["d"]), jnp.bfloat16)
+    return (x, x, x, jax.ShapeDtypeStruct(x.shape, jnp.float32),
+            jax.ShapeDtypeStruct(x.shape[:-1], jnp.float32))
+
+
+def _kda_grads(saved):
+    """Per-worker body: the rule under ``vmap`` over clients and its five
+    gradients, the forward pass's result kept alive beside them."""
+    def body(*args):
+        def loss(*args):
+            o = jax.vmap(lambda *a: kda.delta_rule(*a, chunk=KDA_SHAPE["chunk"])[0])(*args)
+            return jnp.sum(o)
+
+        if saved is not None:
+            loss = jax.checkpoint(
+                loss, policy=jax.checkpoint_policies.save_only_these_names(*saved))
+        total, grads = jax.value_and_grad(loss, tuple(range(5)))(*args)
+        return _total(grads) + total
+
+    return body
+
+
+def _kda_calls(text):
+    calls = re.findall(
+        r'^\s*%?\w*?(kda_(?:fwd|bwd))_*[.\d]* = .*custom_call_target="tpu_custom_call"',
+        text, re.M)
+    return {name: calls.count(name) for name in set(calls)}
+
+
+@pytest.mark.parametrize("saved,forwards", [(None, 1), (BLOCK_POLICY, 2), ((), 2)],
+                         ids=["no_remat", "block_policy", "nothing_saved"])
+def test_the_delta_rule_kernels_compile_at_the_cells_widths(mesh, compiled_kernels, saved,
+                                                            forwards):
+    """Kimi's cell: chunks of 64, 8 heads of 128, rows of 4,096, two clients
+    under ``vmap``. Under the block's ``remat`` the forward kernel runs in the
+    forward pass and again in the block's recompute (the custom VJP's
+    residual is the chunks' starting states, which no policy names); the
+    backward kernel once."""
+    text = _compile(mesh, _kda_grads(saved), *_kda_structs(), specs=(P("workers"),) * 5)
+    assert _kda_calls(text) == {"kda_fwd": forwards, "kda_bwd": 1}
+    assert "4096,4096" not in text and "64,64,64" not in text
+
+
+def test_the_delta_rule_kernels_multiply_float32_at_float32_precision(mesh, compiled_kernels,
+                                                                     monkeypatch):
+    """The Mosaic text the chip's compiler is handed: every product of two
+    float32 operands (the solve, the state's products, ``P W``, their
+    gradients) asks for float32 contraction, and every product of bfloat16
+    operands (the pair factors, ``P U~``, ``K^^T U``) does not."""
+    from jax._src.pallas.mosaic import lowering
+
+    modules = []
+    lower = lowering.lower_jaxpr_to_module
+
+    def keep(*args, **kw):
+        modules.append(lower(*args, **kw))
+        return modules[-1]
+
+    monkeypatch.setattr(lowering, "lower_jaxpr_to_module", keep)
+    sharded = [jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=NamedSharding(mesh, P("workers")))
+               for s in _kda_structs()]
+    jax.jit(jax.shard_map(_kda_grads(None), mesh=mesh, in_specs=(P("workers"),) * 5,
+                          out_specs=P("workers"))).trace(*sharded).lower(
+        lowering_platforms=("tpu",))
+    assert len(modules) == 2                                   # kda_fwd, then kda_bwd
+    heads = delta_rule._heads_per_step(KDA_SHAPE["clients"] * KDA_SHAPE["heads"])
+    for module, exact in zip(modules, (15, 26)):
+        products = re.findall(r"tpu\.matmul .*: vector<[\dx]+x(\w+)>, vector<[\dx]+x(\w+)>",
+                              str(module))
+        at_fp32 = re.findall(r"tpu\.matmul .*contract_precision<fp32>", str(module))
+        f32 = [p for p in products if p == ("f32", "f32")]
+        assert all(p in (("f32", "f32"), ("bf16", "bf16")) for p in products)
+        assert len(at_fp32) == len(f32) == exact * heads
