@@ -14,6 +14,11 @@ and print their gaps against the same reference — the UPPER readings:
 - any other name in ``--stand_ins`` is a precision of ``reference/ops.py``:
   ``bfloat16`` is the reference at the products the configuration states.
 
+Each stand-in's trace is let go before the next stand-in is followed, and
+each seed's copies and reference before the next seed is built, so that one
+process holds one seed's host copies of the flat vector at a time; each
+reading carries the process's host memory (``run.host_memory``).
+
 Every reading goes through ``compare.judge`` with the cell's limits. The
 exit code is 1 where a run of the program as configured is not correct, or
 the control or ``half_batch`` passes every limit. A state left unchanged
@@ -99,16 +104,22 @@ def main(argv=None, rehearsal=None) -> int:
         gc.collect()
         jax.clear_caches()
         t1 = time.time()
-        ref = run.reference_trace(cell, leaves, shapes, batches, algo, got["p0"])
+        # the program's last parameters are read for each leaf's change alone
+        # (and by --leaf): taken now, they need not be held while the reference runs
+        p3 = got.pop("p3")
+        prog = compare.Followed(losses, got["p0"], got["p1"], p3 if args.leaf else None,
+                                got["bank1"], lr, change=compare.changes(p3, got["p0"], leaves))
+        del p3
+        ref = run.reference_trace(cell, leaves, shapes, batches, algo, got["p0"], first=False)
         t2 = time.time()
-        prog = compare.Followed(losses, got["p0"], got["p1"], got["p3"], got["bank1"], lr)
         limits = compare.load_limits(cell["name"])
 
         def record(what, stand, **more):
             read = compare.readings(stand, ref, leaves)
             read["gaps"]["feed"] = feed
             rec = {"cell": cell["name"], "seed": seed_arg, "what": what, **read,
-                   "correct": compare.judge(read["gaps"], limits)[0], **more}
+                   "correct": compare.judge(read["gaps"], limits)[0], **more,
+                   "host_memory": run.host_memory()}
             if args.leaf:
                 rec["leaf"] = leaf_norms(stand, ref, leaves, args.leaf)
             emit(rec)
@@ -118,10 +129,10 @@ def main(argv=None, rehearsal=None) -> int:
                     ref_losses=ref.losses, losses=losses)
         if not ok and not args.program_argv:
             verdicts.append(f"seed {seed_arg}: the program is not correct")
-        if n >= args.controls:
-            continue
+        del prog
         banked = got["bank1"] is not None
-        for what in filter(None, args.stand_ins.split(",")):
+        stand_ins = args.stand_ins.split(",") if n < args.controls else []
+        for what in filter(None, stand_ins):
             kw, fed = {}, batches
             if what == "half_batch":
                 fed = [{k: np.concatenate([v[:, : v.shape[1] // 2]] * 2, axis=1)
@@ -134,8 +145,12 @@ def main(argv=None, rehearsal=None) -> int:
             tr = run.reference_trace(cell, leaves, shapes, fed, algo, got["p0"], **kw)
             ok = record(what, compare.from_reference(tr, got["p0"], lr, banked),
                         seconds=time.time() - t3)
+            del tr, fed  # each stand-in's trace goes before the next is followed
             if ok and what in ("control", "half_batch"):
                 verdicts.append(f"seed {seed_arg}: {what} passes every limit")
+        # and the seed's copies and reference before the next seed is built
+        del got, ref, batches
+        gc.collect()
     for v in verdicts:
         print("calibrate:", v, file=sys.stderr)
     return 1 if verdicts else 0
@@ -147,12 +162,13 @@ def leaf_norms(stand, ref, leaves, name):
     import numpy as np
 
     a, b = next((a, b) for n, a, b in leaves if n == name)
-    norm = lambda v: float(np.sqrt(np.sum(np.square(v[a:b], dtype=np.float64))))  # noqa: E731
-    out = {}
+    norm = lambda v: float(np.sqrt(np.sum(np.square(v, dtype=np.float64))))  # noqa: E731
+    out, p0 = {}, stand.p0[a:b]
     if stand.bank1 is None or stand.bank1.ndim == 1:
-        g = stand.bank1 if stand.bank1 is not None else (stand.p0 - stand.p1) / np.float32(stand.lr)
-        out.update(grad_prog=norm(g), grad_ref=norm(ref.grad1), grad_diff=norm(g - ref.grad1))
-    d, d_ref = stand.p3 - stand.p0, ref.params[-1] - stand.p0
+        g = stand.bank1[a:b] if stand.bank1 is not None else (p0 - stand.p1[a:b]) / np.float32(stand.lr)
+        r = ref.grad1[a:b]
+        out.update(grad_prog=norm(g), grad_ref=norm(r), grad_diff=norm(g - r))
+    d, d_ref = stand.p3[a:b] - p0, ref.params[-1][a:b] - p0
     out.update(delta_prog=norm(d), delta_ref=norm(d_ref), delta_diff=norm(d - d_ref))
     return out
 

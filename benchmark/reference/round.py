@@ -12,7 +12,8 @@ algebra the traffic mix names (``reference.server``, ``"module:name"``):
   ``p -= delta``.
 
 A mix with another algebra brings a class of its own, in a file of its own,
-with ``__init__(d, spec)`` and ``step(g, lr) -> (delta, table or None)``.
+with ``__init__(d, spec)`` and ``step(g, lr) -> (delta, table or None)``;
+``step`` may write ``delta`` into ``g``'s own buffer.
 
 Client gradients run on the device through ``jax.grad`` of the model's plain
 loss, one client at a time so that the float32 activations fit; the server's
@@ -22,6 +23,7 @@ imports the system under test.
 
 from __future__ import annotations
 
+from functools import partial
 from typing import Callable, NamedTuple, Optional
 
 import jax
@@ -43,16 +45,19 @@ class Algo(NamedTuple):
 
 
 class DenseServer:
-    """``m = rho m + g``; ``p -= lr m`` (``rho`` may be 0)."""
+    """``m = rho m + g``; ``p -= lr m`` (``rho`` may be 0: then there is no
+    ``m``). ``m`` is updated in place and ``lr m`` written into ``g``."""
 
     def __init__(self, d, spec):
         self.rho = np.float32(spec.get("rho", 0.0))
-        self.m = np.zeros(d, np.float32)
+        self.m = np.zeros(d, np.float32) if self.rho else None
 
     def step(self, g, lr):
         """``(what to take off the parameters, the table if there is one)``."""
-        self.m = self.rho * self.m + g
-        return lr * self.m, None
+        if self.m is not None:
+            self.m *= self.rho
+            self.m += g
+        return np.multiply(g if self.m is None else self.m, lr, out=g), None
 
 
 class SketchServer:
@@ -79,7 +84,7 @@ class Trace(NamedTuple):
     losses: list          # mean client loss of each round, before its update
     grad1: np.ndarray     # round 1's mean clipped gradient, flat [D]
     table1: object        # its sketch (sketch mode), else None
-    params: list          # flat parameters after each round
+    params: list          # flat parameters [after round 1 (or None), after the last]
 
 
 def make_client_grad(loss_fn: Callable, unflatten: Callable, algo: Algo,
@@ -107,15 +112,40 @@ def make_client_grad(loss_fn: Callable, unflatten: Callable, algo: Algo,
     return run
 
 
-def run_rounds(client_grad: Callable, p0: np.ndarray, rounds: list, algo: Algo) -> Trace:
+PIECE = 1 << 22  # elements a device-to-host copy
+
+
+@partial(jax.jit, static_argnums=2)
+def _piece(x, start, size):
+    return jax.lax.dynamic_slice_in_dim(x, start, size)
+
+
+def to_host(x) -> np.ndarray:
+    """A flat device vector as a new host array, copied ``PIECE`` elements
+    at a time, so that no second host buffer of its size is made."""
+    out = np.empty(x.shape, np.float32)
+    for s in range(0, x.shape[0], PIECE):
+        n = min(PIECE, x.shape[0] - s)
+        out[s:s + n] = np.asarray(_piece(x, s, n))
+    return out
+
+
+def run_rounds(client_grad: Callable, p0: np.ndarray, rounds: list, algo: Algo,
+               on_round: Optional[Callable] = None, first: bool = True) -> Trace:
     """Follow ``rounds`` (a list of per-round batches ``{k: [W, B, ...]}``)
-    from ``p0``."""
-    p = np.asarray(p0, np.float32).copy()
+    from ``p0``; ``on_round(i)`` is called after round ``i`` (from 1).
+    ``first``: keep the parameters after round 1 (a stand-in's ``p1``).
+
+    Of ``[D]`` vectors the host holds the parameters (updated in place),
+    round 1's gradient, the parameters after round 1 where kept, and the
+    round's mean gradient, which the server's step may overwrite (besides a
+    server's own state)."""
+    p = np.array(p0, np.float32)
     d = p.size
     server = resolve(algo.server)(d, algo.spec)
     lr = np.float32(algo.lr)
-    losses, params, grad1, table1 = [], [], None, None
-    for batch in rounds:
+    losses, params, grad1, table1 = [], [None], None, None
+    for i, batch in enumerate(rounds, 1):
         W = next(iter(batch.values())).shape[0]
         p_dev = jnp.asarray(p)
         total, loss_sum = jnp.zeros(d, jnp.float32), 0.0
@@ -123,11 +153,18 @@ def run_rounds(client_grad: Callable, p0: np.ndarray, rounds: list, algo: Algo) 
             g, loss = client_grad(p_dev, {k: jnp.asarray(v[w]) for k, v in batch.items()})
             total = total + g
             loss_sum += float(loss)
-        g = np.asarray(total) / np.float32(W)
+        g = to_host(total)
+        del total, p_dev
+        g /= np.float32(W)
         losses.append(loss_sum / W)
+        if i == 1:
+            grad1 = g.copy()
         delta, table = server.step(g, lr)
-        p = p - delta
-        if grad1 is None:
-            grad1, table1 = g, table
-        params.append(p.copy())
+        p -= delta
+        del g, delta
+        if i == 1:
+            table1, params = table, [p.copy() if first else None]
+        if on_round is not None:
+            on_round(i)
+    params.append(p)
     return Trace(losses, grad1, table1, params)

@@ -313,7 +313,8 @@ def make_unflatten(leaves, shapes):
     return unflatten
 
 
-def reference_trace(cell, leaves, shapes, batches, algo, p0, *, precision="float32"):
+def reference_trace(cell, leaves, shapes, batches, algo, p0, *, precision="float32",
+                    on_round=None, first=True):
     from benchmark.reference import round as ref_round
 
     ref_c = cell["config_file"]["reference"]
@@ -325,7 +326,26 @@ def reference_trace(cell, leaves, shapes, batches, algo, p0, *, precision="float
 
     client_grad = ref_round.make_client_grad(
         loss_fn, make_unflatten(leaves, shapes), algo, precision)
-    return ref_round.run_rounds(client_grad, p0, batches, algo)
+    return ref_round.run_rounds(client_grad, p0, batches, algo, on_round, first)
+
+
+def host_memory() -> dict:
+    """This process's host memory in bytes: its peak resident size
+    (``ru_maxrss``) and, where ``/proc`` gives them, the resident size now
+    (``VmRSS``) and the peak again (``VmHWM``). The harness's own reading,
+    not a metric."""
+    import resource
+
+    out = {"maxrss": resource.getrusage(resource.RUSAGE_SELF).ru_maxrss * 1024}
+    try:
+        with open("/proc/self/status") as f:
+            for line in f:
+                key, _, value = line.partition(":")
+                if key in ("VmRSS", "VmHWM"):
+                    out[key] = int(value.split()[0]) * 1024
+    except OSError:
+        pass
+    return out
 
 
 def memory_peak(devices, chips) -> int:
@@ -447,6 +467,7 @@ def main(argv=None, rehearsal=None) -> int:
     gc.disable()
     t_window = time.time()
     setup_s = t_window - T_START
+    host = {"window_start": host_memory()}
     elapsed, losses, raised = drive(rounds, session, seconds=args.seconds)
     gc.enable()
     t_window_end = time.time()
@@ -491,30 +512,6 @@ def main(argv=None, rehearsal=None) -> int:
         traced = reduce.load_trace(trace_dir, chips)
         traced.update(rounds=n)
     held = memory_peak(devices, chips)
-
-    # ---- free the program, then follow the reference ---------------------
-    leaves, shapes, batches, algo, feed = reference_inputs(cell, rounds.spans.fed, sampler, tree)
-    state_step = int(session.state.step)
-    rounds.close()
-    del rounds, session, sampler, tree
-    gc.collect()
-    jax.clear_caches()
-    from benchmark import compare
-
-    t_ref = time.time()
-    ref = reference_trace(cell, leaves, shapes, batches, algo, p0)
-    prog = compare.Followed(early[:FOLLOWED], p0, followed["p1"], followed["p3"],
-                            followed["bank1"], lr)
-    read = compare.readings(prog, ref, leaves)
-    read["gaps"]["feed"] = feed
-    correct, rows = compare.judge(read["gaps"], compare.load_limits(cell["name"]))
-    if state_step != FOLLOWED + warm + attempted + (traced["rounds"] if traced else 0):
-        correct = False  # a round that did not advance the state
-    reference_s = time.time() - t_ref
-    print(json.dumps({"info": "gaps", "workload": cell["name"], "seed": args.seed,
-                      **read, "reference_s": reference_s}), flush=True)
-
-    # ---- the result -------------------------------------------------------
     if args.trace:
         ctx = {
             "cell": cell, "traced": traced, "peaks": peaks, "chips": chips,
@@ -532,9 +529,53 @@ def main(argv=None, rehearsal=None) -> int:
             "config": {"n_params": int(p0.size), **cell["config_file"].get("flops_kwargs", {})},
         }
         metrics = reduce.per_layer(cell_metrics(cell, "per_layer"), ctx)
+        # the reduced numbers are all that is kept of the trace
+        traced = {k: traced[k] for k in ("busy_s", "window_s", "breakdown", "rounds")}
+        del ctx
+        gc.collect()
+        host["trace_reduced"] = host_memory()
     else:
         metrics = {"round_s": {"value": round_s, "unit": "s"},
                    "setup_s": {"value": setup_s, "unit": "s"}}
+
+    # ---- free the program, then follow the reference ---------------------
+    leaves, shapes, batches, algo, feed = reference_inputs(cell, rounds.spans.fed, sampler, tree)
+    state_step = int(session.state.step)
+    rounds.close()
+    del rounds, session, sampler, tree
+    gc.collect()
+    jax.clear_caches()
+    from benchmark import compare
+
+    # the parameters after the last round are read for each leaf's change
+    # alone: taken now, they need not be held while the reference runs
+    prog = compare.Followed(early[:FOLLOWED], p0, followed.pop("p1"), None,
+                            followed.pop("bank1"), lr,
+                            change=compare.changes(followed.pop("p3"), p0, leaves))
+    gc.collect()
+    host["before_reference"] = host_memory()
+    t_ref = time.time()
+
+    def after_round(i):
+        host[f"reference_round_{i}"] = host_memory()
+
+    ref = reference_trace(cell, leaves, shapes, batches, algo, p0, on_round=after_round,
+                          first=False)
+    del batches
+    read = compare.readings(prog, ref, leaves)
+    host["after_readings"] = host_memory()
+    # the program's copies and the reference's trace have no reader left
+    del prog, ref, followed, p0
+    read["gaps"]["feed"] = feed
+    correct, rows = compare.judge(read["gaps"], compare.load_limits(cell["name"]))
+    if state_step != FOLLOWED + warm + attempted + (traced["rounds"] if traced else 0):
+        correct = False  # a round that did not advance the state
+    reference_s = time.time() - t_ref
+    host_peak = max(max(m.values()) for m in host.values())
+    print(json.dumps({"info": "gaps", "workload": cell["name"], "seed": args.seed,
+                      **read, "reference_s": reference_s, "host_memory": host}), flush=True)
+
+    # ---- the result -------------------------------------------------------
     result = {
         "correct": bool(correct), "attempted": attempted, "failed": failed,
         "metrics": metrics,
@@ -543,6 +584,7 @@ def main(argv=None, rehearsal=None) -> int:
     if traced:
         result["breakdown"] = traced["breakdown"]
     result["reference_s"] = reference_s
+    result["host_peak_bytes"] = host_peak
     result["compared"] = {name: {"gap": gap, "limit": lim} for name, gap, lim in rows}
     result["compared"]["worst_leaf"] = read["worst"]
     for name, gap, lim in rows:

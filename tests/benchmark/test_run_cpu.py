@@ -100,7 +100,8 @@ def test_rehearsal_drives_the_window_and_is_correct():
     # tiny preset bf16 noise is another size, so the sound run is in float32
     result = _rehearse("gpt2_uncompressed", 3000000019, ["--compute_dtype", "float32"])
     assert list(result)[:5] == ["correct", "attempted", "failed", "metrics", "device"]
-    assert list(result)[-1] == "compared"
+    assert list(result)[-2:] == ["host_peak_bytes", "compared"]
+    assert result["host_peak_bytes"] > 0
     assert result["correct"] is True and result["failed"] == 0 and result["attempted"] > 0
     assert set(result["metrics"]) == {"round_s", "setup_s"}
     assert all(m["value"] > 0 for m in result["metrics"].values())
